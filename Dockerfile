@@ -63,8 +63,9 @@ FROM python:3.12-slim
 COPY --from=build /usr/local/lib/python3.12/site-packages /usr/local/lib/python3.12/site-packages
 COPY --from=build /src/policy_server_tpu /app/policy_server_tpu
 COPY --from=build /src/build /app/build
-# csrc must ship too: ops/fastenc.py compares the .so's mtime against the
-# source before loading it (missing source would disable the native path)
+# csrc must ship too: each native library is named by a hash of its
+# source text and compile flags (utils/nativebuild.py), so the loader needs
+# the source to know which .so is the one built from it
 COPY --from=build /src/csrc /app/csrc
 
 WORKDIR /app
@@ -73,7 +74,10 @@ USER 65533:65533
 
 EXPOSE 3000 8081
 
+# persistent XLA compilation cache: placed from outside, by the variable
+# JAX itself reads (runtime/compile_cache.py sets no directory then)
+ENV JAX_COMPILATION_CACHE_DIR=/data/xla-cache
+
 ENTRYPOINT ["python", "-m", "policy_server_tpu"]
 CMD ["--policies", "/config/policies.yml", \
-     "--policies-download-dir", "/data/policies", \
-     "--compilation-cache-dir", "/data/xla-cache"]
+     "--policies-download-dir", "/data/policies"]

@@ -6,7 +6,7 @@ IMG ?= policy-server-tpu:latest
 .PHONY: all test unit-tests integration-tests bench chaos check docs \
         docs-check fastenc httpfront natives sanitize soak-smoke soak \
         image dev-stack dev-stack-down dryrun-multichip multichip \
-        restart-drill phase-report shards-ab clean
+        restart-drill phase-report shards-ab chip-smoke clean
 
 all: natives test check sanitize soak-smoke multichip restart-drill phase-report
 
@@ -20,9 +20,20 @@ unit-tests:
 integration-tests:
 	python -m pytest tests/test_server.py tests/test_server_mesh.py tests/test_tls.py -q
 
-# the 5 BASELINE configs + HTTP-path percentiles (one JSON line each)
+# the 5 BASELINE configs + HTTP-path percentiles (one JSON line each).
+# NOT a chip entry point: its parent process builds device environments
+# and then starts children, and a chip belongs to one process. A CPU
+# count of work only (JAX_PLATFORMS=cpu); ROADMAP S1 replaces it.
 bench:
-	python bench.py
+	JAX_PLATFORMS=cpu python bench.py
+
+# the quickest proof that the served path runs on the accelerator
+# (chip_smoke.py: HTTPS -> native front-end -> batcher -> device, flagship
+# 32-policy set, byte-compared against an oracle server; fails without a
+# chip). `make chip-smoke CHIP_SMOKE_ARGS="--chips 4"` on a four-chip
+# host; `CHIP_SMOKE_ARGS="--platform cpu --requests 256"` rehearses here.
+chip-smoke:
+	python chip_smoke.py $(CHIP_SMOKE_ARGS)
 
 # property-based differential fuzzing (device vs IR-oracle vs wasm)
 fuzz:
@@ -91,9 +102,10 @@ shards-ab:
 check:
 	python -m tools.graftcheck
 
-# native host encoder (ops/fastenc.py compiles on demand into build/)
+# native host encoder (ops/fastenc.py compiles on demand into build/,
+# named by a hash of source + flags; a failed build raises)
 fastenc:
-	python -c "import sys; from policy_server_tpu.ops import fastenc; p = fastenc._build_library(); print(p); sys.exit(0 if p else 1)"
+	python -c "from policy_server_tpu.ops import fastenc; print(fastenc._build_library())"
 
 # native HTTP front-end (runtime/native_frontend.py compiles on demand).
 # TLS termination needs no OpenSSL headers — httpfront.cpp dlopens
@@ -101,11 +113,10 @@ fastenc:
 # the build still succeeds and the server falls back LOUDLY to aiohttp
 # TLS, so this target also prints whether native TLS is live.
 httpfront:
-	python -c "import sys; from policy_server_tpu.runtime import native_frontend; p = native_frontend._build_library(); print(p); print('native TLS:', 'available' if native_frontend.tls_available() else 'UNAVAILABLE (libssl did not resolve; aiohttp TLS fallback)'); sys.exit(0 if p else 1)"
+	python -c "from policy_server_tpu.runtime import native_frontend; print(native_frontend._build_library()); print('native TLS:', 'available' if native_frontend.tls_available() else 'UNAVAILABLE (libssl did not resolve; aiohttp TLS fallback)')"
 
-# both native extensions, loudly: the runtime soft-fails to Python
-# fallbacks, so these targets exit nonzero on a failed build — CI sees
-# the breakage instead of silently shipping the fallback
+# both native extensions: a failed build exits nonzero here, as it fails
+# the boot of a server that asked for them
 natives: fastenc httpfront
 
 # sanitizer lane (round 21, tools/sanitize_lane.py): rebuild all three

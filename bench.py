@@ -8,7 +8,13 @@ unchanged:
 One JSON line per benchmark, the HEADLINE line LAST (config 4, the
 32-policy firehose — the driver's recorded metric). Subprocess entry
 points (``--config5-child``, ``--native-client``) also route through
-here so child invocations stay `python bench.py ...`."""
+here so child invocations stay `python bench.py ...`.
+
+NOT a chip entry point: the parent process builds device environments and
+then starts children, and a chip belongs to one process (the children that
+are CPU by design assign ``JAX_PLATFORMS=cpu`` and say ``platform`` in
+their JSON). Its numbers are CPU counts of work; ``chip_smoke.py`` is the
+program that runs on the accelerator until ROADMAP S1 lands."""
 
 from __future__ import annotations
 

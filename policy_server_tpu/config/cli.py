@@ -185,20 +185,6 @@ def _flag_specs() -> list[tuple[str, str | None, dict[str, Any]]]:
                    "their mask lanes). Purely structural — bit-exact vs "
                    "the unoptimized program and the host oracle. 'off' "
                    "restores the naive per-policy lowering")),
-        ("--kernel", "KUBEWARDEN_KERNEL",
-         dict(default="xla", metavar="KERNEL", choices=["xla", "pallas"],
-              help="Device kernel form for the fused predicate program: "
-                   "'xla' (default) lowers through XLA; 'pallas' streams "
-                   "packed rows through a fused gather→predicate→reduce "
-                   "Pallas kernel in VMEM-resident (row × policy) tiles "
-                   "for schema buckets that turn hot (per-bucket opt-in "
-                   "by dispatch count). The real Mosaic lowering is "
-                   "gated behind a LOUD capability probe; where it "
-                   "cannot compile (CPU dev boxes) the kernel runs in "
-                   "interpret mode — bit-exact, slow, warned once. "
-                   "Armed buckets use the packed transport (the "
-                   "kernel fuses the unpack; columnar delta planes "
-                   "keep the XLA path)")),
         ("--breaker-failure-threshold", "KUBEWARDEN_BREAKER_FAILURE_THRESHOLD",
          dict(type=int, default=5, metavar="N",
               help="Device circuit breaker: dispatch faults / watchdog "
@@ -445,8 +431,9 @@ def _flag_specs() -> list[tuple[str, str | None, dict[str, Any]]]:
                    "a failed fetch degrades loudly to last-good instead "
                    "of fail-closing. Corrupt or torn entries are "
                    "quarantined by the boot fsck pass, never fatal. "
-                   "Pair with --compilation-cache-dir inside it so "
-                   "compiled programs survive too. Unset = amnesiac "
+                   "Compiled programs survive in the persistent XLA "
+                   "cache (JAX_COMPILATION_CACHE_DIR, else "
+                   "<checkout>/.jax_cache). Unset = amnesiac "
                    "restarts (every boot refetches and re-LISTs)")),
         ("--state-audit-spill-seconds", "KUBEWARDEN_STATE_AUDIT_SPILL_SECONDS",
          dict(type=float, default=30.0, metavar="SECONDS",
@@ -543,18 +530,12 @@ def _flag_specs() -> list[tuple[str, str | None, dict[str, Any]]]:
         ("--no-warmup", "KUBEWARDEN_NO_WARMUP",
          dict(action="store_true",
               help="Skip AOT compilation of the policy program at boot")),
-        ("--compilation-cache-dir", "KUBEWARDEN_COMPILATION_CACHE_DIR",
-         dict(default=None, metavar="DIR",
-              help="Persistent XLA compilation cache directory: compiled "
-                   "policy programs survive restarts (the TPU analog of the "
-                   "reference's policies-download store reuse)")),
         ("--http-workers", "KUBEWARDEN_HTTP_WORKERS",
          dict(type=int, default=1, metavar="N",
               help="HTTP frontend processes sharing the API port via "
                    "SO_REUSEPORT, forwarding to the evaluation process "
                    "over a unix socket (1 = serve in-process; raises the "
-                   "~1.3k req/s per-event-loop framing ceiling, see "
-                   "PROFILE.md)")),
+                   "~1.3k req/s per-event-loop framing ceiling)")),
         ("--frontend", "KUBEWARDEN_FRONTEND",
          dict(default="python", metavar="IMPL", choices=["python", "native"],
               help="HTTP framing implementation for the evaluation POST "
@@ -563,15 +544,15 @@ def _flag_specs() -> list[tuple[str, str | None, dict[str, Any]]]:
                    "(csrc/httpfront.cpp) that parses AdmissionReviews "
                    "straight into packed batch rows and serializes "
                    "verdicts natively — breaking the ~1.3k rps/process "
-                   "Python framing ceiling (PROFILE.md); 'python' keeps "
-                   "aiohttp framing, the always-available fallback and "
-                   "differential correctness oracle. With 'native', the "
+                   "Python framing ceiling; 'python' keeps "
+                   "aiohttp framing, the differential correctness oracle. "
+                   "With 'native', the "
                    "API port serves ONLY the evaluation POSTs — "
                    "/audit/reports, /metrics, and the /policies/* admin "
                    "surface stay on the readiness port, and the pprof "
-                   "endpoints require --frontend python; a native build "
-                   "that fails to load falls back to 'python' with a "
-                   "loud warning. Under --http-workers, the "
+                   "endpoints require --frontend python; a native library "
+                   "that fails to build or load is a boot error, never a "
+                   "quiet Python front-end. Under --http-workers, the "
                    "policy_server_native_* /metrics families count the "
                    "main process's loop only (worker processes export "
                    "no metrics, matching the python prefork mode)")),

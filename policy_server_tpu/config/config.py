@@ -215,11 +215,6 @@ class Config:
     # cross-policy CSE + constant folding + dead-field/mask pruning
     # before lowering; False restores the naive per-policy lowering
     predicate_opt: bool = True
-    # device kernel form: 'xla' (fused jit program) or 'pallas' (fused
-    # gather→predicate→reduce Pallas kernel for hot schema buckets;
-    # real Mosaic lowering behind a loud capability probe, interpret
-    # mode elsewhere)
-    kernel: str = "xla"
     # zero-downtime policy lifecycle (lifecycle.py): 'auto' promotes a
     # canaried candidate epoch automatically, 'manual' stages it for an
     # explicit POST /policies/promote, 'off' restores the frozen-at-boot
@@ -358,7 +353,6 @@ class Config:
     # thread-per-shard MPMD dispatcher (parallel/policy_sharded.py)
     mesh_dispatch: str = "fused"
     warmup_at_boot: bool = True
-    compilation_cache_dir: str | None = None
     # prefork HTTP frontend (runtime/frontend.py): worker processes
     # sharing the API port via SO_REUSEPORT; 1 = in-process serving
     http_workers: int = 1
@@ -624,7 +618,6 @@ class Config:
             columnar=args.columnar == "on",
             donate_buffers=args.donate_buffers == "on",
             predicate_opt=args.predicate_opt == "on",
-            kernel=args.kernel,
             degraded_mode=args.degraded_mode,
             policy_reload_mode=args.policy_reload_mode,
             reload_canary_requests=int(args.reload_canary_requests),
@@ -676,7 +669,6 @@ class Config:
             mesh=MeshSpec.parse(args.mesh),
             mesh_dispatch=args.mesh_dispatch,
             warmup_at_boot=not args.no_warmup,
-            compilation_cache_dir=args.compilation_cache_dir,
             http_workers=int(args.http_workers),
             frontend=args.frontend,
             context_refresh_seconds=float(args.context_refresh_seconds),

@@ -13,11 +13,14 @@ Activation, either:
 
       FAILPOINTS="device.fetch=sleep:5;fetch.http=raise:boom*3"
 
-  grammar per entry: ``site=action[:param][*count]`` —
+  grammar per entry: ``site=action[:param][*count][@scope]`` —
   ``raise[:message]`` raises :class:`FailpointError`, ``sleep:seconds``
   blocks, ``off`` clears the site. ``*count`` disarms the action after
   it fired ``count`` times (the retry-then-succeed shape chaos tests
-  need).
+  need). ``@scope`` arms it only for threads carrying that ambient
+  scope (see below): ``device.fetch=raise@default`` faults the default
+  tenant's SERVING dispatches of a server process while its boot
+  warm-up, which runs unscoped, goes through.
 
 * programmatic (tests): ``set_failpoint("site", fn, count=None)``
   installs any callable — an Event-gated hang, a custom exception —
@@ -320,8 +323,9 @@ def configure(spec: str) -> None:
         if action.strip().lower() == "off":
             clear(site)
             continue
+        action, _, scope_name = action.partition("@")
         fn, count = _parse_action(action)
-        set_failpoint(site, fn, count)
+        set_failpoint(site, fn, count, scope=scope_name.strip() or None)
 
 
 def configure_from_env() -> None:

@@ -71,7 +71,6 @@ def lower_expr(
     features: Features,
     table: InternTable,
     cse: dict | None = None,
-    scalar_inset: bool = False,
 ) -> Any:
     """Lower a typechecked boolean IR expression to a ``(B,)`` bool array.
 
@@ -88,14 +87,7 @@ def lower_expr(
 
     A leaf whose validity mask the schema elided (``FeatureSpec.masked``
     False — the optimizer proved every use False at the zero-fill) lowers
-    mask-free: the mask key is simply absent from ``features``.
-
-    ``scalar_inset`` lowers ``InSet`` membership as an OR chain of
-    SCALAR equality compares instead of the vectorized any-equals
-    against an array constant table — Pallas kernel bodies cannot
-    capture array constants, and scalars inline as literals. Identical
-    semantics; the default XLA lowering keeps the vectorized form (one
-    op instead of O(N) for large settings-driven sets)."""
+    mask-free: the mask key is simply absent from ``features``."""
 
     def value_of(e: Expr, stack: ir.DomainStack) -> tuple[Lowered, Lowered | None]:
         """→ (values, validity-mask or None-if-always-valid)."""
@@ -225,19 +217,10 @@ def lower_expr(
                 vals, np_dtype = sorted(e.values), np.int32
             else:
                 vals, np_dtype = sorted(e.values), np.bool_
-            if scalar_inset:
-                # Pallas kernel body: an array constant table would be
-                # a captured const, which pallas_call rejects — lower
-                # membership as an OR chain of scalar compares instead
-                # (identical semantics; scalars inline as literals)
-                hits = ov.values == jnp.asarray(np_dtype(vals[0]))
-                for v in vals[1:]:
-                    hits = hits | (ov.values == jnp.asarray(np_dtype(v)))
-            else:
-                consts = np.asarray(vals, dtype=np_dtype)
-                hits = jnp.any(
-                    ov.values[..., None] == jnp.asarray(consts), axis=-1
-                )
+            consts = np.asarray(vals, dtype=np_dtype)
+            hits = jnp.any(
+                ov.values[..., None] == jnp.asarray(consts), axis=-1
+            )
             out = Lowered(hits, ov.naxes)
             if om is not None:
                 mv, hv, n = _align(om, out)
@@ -347,20 +330,16 @@ def compile_program(
     def fn(
         features: Features,
         cse: dict | None = None,
-        scalar_inset: bool = False,
     ) -> tuple[Any, Any]:
         batch = jnp.shape(jnp.asarray(features[BATCH_KEY]))
         # the stack keeps FULL rule length: folded-constant conditions
         # lower as scalar broadcasts (free after XLA constant folding),
         # so rule indices never shift and no index-map array constant is
-        # needed (array consts cannot be captured by Pallas kernels)
+        # needed
         violated = jnp.stack(
             [
                 jnp.broadcast_to(
-                    lower_expr(
-                        c, features, table, cse=cse,
-                        scalar_inset=scalar_inset,
-                    ),
+                    lower_expr(c, features, table, cse=cse),
                     batch,
                 )
                 for c in conds
@@ -386,7 +365,6 @@ def compile_constant(
     def fn(
         features: Features,
         cse: dict | None = None,
-        scalar_inset: bool = False,
     ) -> tuple[Any, Any]:
         batch = jnp.shape(jnp.asarray(features[BATCH_KEY]))
         return (

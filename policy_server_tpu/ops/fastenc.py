@@ -7,19 +7,16 @@ numpy buffers, and returns the ID/pred strings via an arena that Python
 interns with its memoized tables. The whole encode runs with the GIL
 released, so the batcher can encode on parallel threads.
 
-Build model: compiled on demand with g++ into ``build/fastenc-<py>.so`` and
-cached; any failure (no compiler, unsupported platform) degrades silently to
-the pure-Python trie — behavior is identical, only slower (differential
-tests enforce bit-exactness, tests/test_fastenc.py)."""
+Build model: compiled on demand with g++ into ``build/`` under a name that
+hashes its source and flags (utils/nativebuild.py). A failed build or load
+raises — the pure-Python trie (ops/codec.py) stays as the differential
+reference the tests hold this encoder to (tests/test_fastenc.py), not as a
+silent serving fallback."""
 
 from __future__ import annotations
 
 import ctypes
 import json
-import os
-import subprocess
-import sys
-import sysconfig
 import threading
 from pathlib import Path
 from typing import Any
@@ -34,59 +31,41 @@ from policy_server_tpu.ops.codec import (
     mask_key_for,
 )
 from policy_server_tpu.utils.interning import InternTable
+from policy_server_tpu.utils.nativebuild import (
+    REPO_ROOT,
+    NativeBuildError,
+    build_shared_library,
+)
 
-_REPO_ROOT = Path(__file__).resolve().parent.parent.parent
-_SRC = _REPO_ROOT / "csrc" / "fastenc.cpp"
+_SRC = REPO_ROOT / "csrc" / "fastenc.cpp"
 
 _KIND = {"value": 0, "present": 1, "pred": 2}
 _DTYPE = {"id": 0, "f32": 1, "bool": 2, "i32": 3}
 
 _lib_lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
-_lib_failed = False
+_lib_error: str | None = None  # guarded-by: _lib_lock
 
 
-def _build_library() -> Path | None:
-    out_dir = _REPO_ROOT / "build"
-    out_dir.mkdir(exist_ok=True)
-    tag = sysconfig.get_config_var("SOABI") or f"py{sys.version_info[0]}{sys.version_info[1]}"
-    # POLICY_SERVER_NATIVE_SAN=asan (tools/sanitize_lane.py): sanitized
-    # variant under a distinct name, production cache untouched
-    san = os.environ.get("POLICY_SERVER_NATIVE_SAN", "") == "asan"
-    out = out_dir / f"fastenc-{tag}{'-san' if san else ''}.so"
-    if out.exists() and out.stat().st_mtime >= _SRC.stat().st_mtime:
-        return out
-    opt = (
-        ["-O1", "-g", "-fsanitize=address,undefined",
-         "-fno-sanitize-recover=all"]
-        if san
-        else ["-O2"]
-    )
-    cmd = [
-        "g++", *opt, "-shared", "-fPIC", "-std=c++17",
-        str(_SRC), "-o", str(out),
-    ]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    except Exception:
-        return None
-    return out
+def _build_library() -> Path:
+    return build_shared_library(_SRC, timeout=120)
 
 
-def _load() -> ctypes.CDLL | None:
-    global _lib, _lib_failed
+def _load() -> ctypes.CDLL:
+    """The loaded library; raises NativeBuildError when it cannot be built
+    or loaded (the failure is remembered: one compile attempt per
+    process)."""
+    global _lib, _lib_error
     with _lib_lock:
-        if _lib is not None or _lib_failed:
+        if _lib is not None:
             return _lib
-        path = _build_library()
-        if path is None:
-            _lib_failed = True
-            return None
+        if _lib_error is not None:
+            raise NativeBuildError(_lib_error)
         try:
-            lib = ctypes.CDLL(str(path))
-        except OSError:
-            _lib_failed = True
-            return None
+            lib = ctypes.CDLL(str(_build_library()))
+        except (NativeBuildError, OSError) as e:
+            _lib_error = f"native encoder unavailable: {e}"
+            raise NativeBuildError(_lib_error) from e
         lib.fastenc_create.restype = ctypes.c_void_p
         lib.fastenc_create.argtypes = [ctypes.c_char_p, ctypes.c_int64]
         lib.fastenc_destroy.argtypes = [ctypes.c_void_p]
@@ -112,7 +91,11 @@ def _load() -> ctypes.CDLL | None:
 
 
 def native_available() -> bool:
-    return _load() is not None
+    try:
+        _load()
+    except NativeBuildError:
+        return False
+    return True
 
 
 # ---------------------------------------------------------------------------
@@ -195,10 +178,7 @@ class NativeEncoder:
     RECORDS_CAP = 1 << 16
 
     def __init__(self, schema: FeatureSchema):
-        lib = _load()
-        if lib is None:
-            raise RuntimeError("native encoder unavailable")
-        self._lib = lib
+        self._lib = lib = _load()
         desc, self._specs, self._pred_keys = _describe_schema(schema)
         raw = desc.encode()
         self._handle = lib.fastenc_create(raw, len(raw))
@@ -369,13 +349,10 @@ class NativeEncoder:
             arr.flat[rec[m, 1]] = rvals[m].astype(arr.dtype, copy=False)
 
 
-def attach_native(schema: FeatureSchema) -> bool:
+def attach_native(schema: FeatureSchema) -> None:
     """Give a FeatureSchema a native encoder (used by the evaluation
-    environment at boot). Returns False when the native path is
-    unavailable."""
-    try:
-        schema.native = NativeEncoder(schema)
-        return True
-    except (RuntimeError, OSError):
-        schema.native = None
-        return False
+    environment at boot). Raises NativeBuildError when the library cannot
+    be built or loaded: the jax backend asks for the native encoder, and
+    a server that silently encodes in Python instead is a different,
+    slower server."""
+    schema.native = NativeEncoder(schema)

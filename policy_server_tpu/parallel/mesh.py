@@ -25,8 +25,7 @@ TPU-native design replaces that with sharding over a ``jax.sharding.Mesh``:
 
 Multi-host: ``jax.distributed.initialize`` + the same mesh spanning all
 processes' devices (ICI within a slice, DCN across slices) — see
-``initialize_distributed``; on the CPU backend the cross-process
-collectives need the gloo implementation, selected there before init.
+``initialize_distributed``.
 """
 
 from __future__ import annotations
@@ -37,12 +36,7 @@ from typing import Any, Callable, Mapping, Sequence
 
 import jax
 import numpy as np
-from jax import lax
-
-try:  # jax ≥ 0.6 promoted shard_map to the top-level namespace
-    from jax import shard_map
-except ImportError:  # older jax: pre-promotion location, same signature
-    from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from policy_server_tpu.config.config import MeshSpec
@@ -57,69 +51,16 @@ def initialize_distributed(
     process_id: int | None = None,
 ) -> None:
     """Multi-host bring-up (jax.distributed over DCN). No-op when
-    single-process args are absent.
-
-    On the CPU backend XLA's default collectives cannot cross process
-    boundaries ("Multiprocess computations aren't implemented on the CPU
-    backend"); the gloo implementation can — select it before init so
-    the 2-process localhost smoke (and any CPU-backed multi-host
-    deployment) forms a working global mesh. TPU/GPU backends ignore the
-    option, and jax versions without it simply keep their default."""
+    single-process args are absent. The CPU backend's cross-process
+    collectives are gloo by default, so the 2-process localhost smoke
+    needs no selection here."""
     if coordinator_address is None:
         return
-    prev_collectives = None
-    set_collectives = False
-    if _is_cpu_platform():
-        try:
-            prev_collectives = jax.config._read(
-                "jax_cpu_collectives_implementation"
-            )
-        except Exception:  # pragma: no cover - jax-version dependent
-            prev_collectives = "none"
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-            set_collectives = True
-        except Exception:  # pragma: no cover - jax-version dependent
-            pass
-    try:
-        jax.distributed.initialize(
-            coordinator_address=coordinator_address,
-            num_processes=num_processes,
-            process_id=process_id,
-        )
-    except BaseException:
-        # the gloo selection is only valid with a live distributed
-        # client — leaking it after a failed bring-up would break the
-        # NEXT (single-process) CPU backend initialization in this
-        # process with "make_gloo_tcp_collectives(... NoneType)"
-        if set_collectives:
-            try:
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", prev_collectives
-                )
-            except Exception:  # pragma: no cover
-                pass
-        raise
-
-
-def _is_cpu_platform() -> bool:
-    """True unless a non-CPU platform is EXPLICITLY configured — read
-    from config/env without forcing backend initialization. An empty
-    configuration counts as CPU: jax defaults to the CPU backend when no
-    accelerator plugin resolves, and that default-CPU multi-host
-    deployment is exactly the one that needs gloo collectives (the
-    option is harmless on accelerator platforms — it only shapes the
-    CPU client, which has a live distributed client by then)."""
-    import os
-
-    configured = None
-    try:
-        configured = jax.config.jax_platforms
-    except Exception:  # pragma: no cover - jax-version dependent
-        configured = None
-    configured = configured or os.environ.get("JAX_PLATFORMS", "")
-    s = str(configured).lower().strip()
-    return not s or "cpu" in s
+    jax.distributed.initialize(
+        coordinator_address=coordinator_address,
+        num_processes=num_processes,
+        process_id=process_id,
+    )
 
 
 def resolve_axes(spec: MeshSpec, devices: Sequence[Any] | None = None) -> dict[str, int]:
@@ -256,8 +197,7 @@ def shard_features(
     features: Mapping[str, np.ndarray], mesh: Mesh
 ) -> dict[str, jax.Array]:
     """Host → device transfer with the batch axis pre-sharded (one
-    device_put of the whole tree; transfers are the serving bottleneck on
-    remote transports). Multi-host meshes assemble the global array from
+    device_put of the whole tree). Multi-host meshes assemble the global array from
     each process's LOCAL rows — every host ships only its own shard over
     its own PCIe/DCN link (the per-host frontends feed host-local
     batches)."""

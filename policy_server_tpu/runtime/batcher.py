@@ -114,7 +114,7 @@ class _Pending:
     # this loop-bound future so event-loop callers await it directly —
     # and a whole batch delivers with ONE call_soon_threadsafe per loop
     # instead of one wakeup per request (the fan-out dominated the
-    # serving profile, PROFILE.md round-3 follow-up)
+    # round-3 serving profile)
     aio_loop: Any = None
     aio_future: Any = None
     # propagated request deadline (absolute perf_counter time): stamped at
@@ -326,7 +326,7 @@ class MicroBatcher:
         # 'oracle' (default) = bit-exact host verdicts, 'monitor' =
         # accept-all monitor-mode verdicts, 'reject' = in-band 503s
         self.degraded_mode = degraded_mode
-        # Deadline-aware routing (VERDICT r4 #2): beyond the static
+        # Deadline-aware routing: beyond the static
         # fast-path count, a batch is answered host-side whenever the
         # MEASURED device round-trip estimate would blow the oldest
         # item's latency budget and the host estimate would not. The
@@ -1723,7 +1723,7 @@ class MicroBatcher:
         # THESE futures, never the dispatch thread. With a policy timeout
         # configured, the call runs on the device pool under the dispatch
         # watchdog (below): device execution — compile stall on a cold
-        # (schema × batch) bucket, transport hang on a remote device — is
+        # (schema × batch) bucket, a hung device call — is
         # bounded by the per-request deadline just like queue wait and host
         # hooks, matching the reference's mid-execution epoch interrupt
         # (src/lib.rs:176-190, tests/integration_test.rs:417).
@@ -1732,7 +1732,7 @@ class MicroBatcher:
         # 1. occupancy: a small batch means the queue was shallow when it
         #    formed — the requests are latency-critical, not throughput
         #    traffic — so answer on the host;
-        # 2. budget (VERDICT r4 #2): for larger batches, compare the
+        # 2. budget: for larger batches, compare the
         #    MEASURED device round-trip estimate against the oldest
         #    item's remaining latency budget; when the device would blow
         #    the budget and the host estimate would not, route host-side.
@@ -1816,7 +1816,7 @@ class MicroBatcher:
             # and device pools with a future-wake at each boundary —
             # the round-18 flight recorder measured those pool
             # crossings as the single largest host cost (``handoff``,
-            # ~82 µs/row on the 2-core box, PROFILE r18). Cross-batch
+            # ~82 µs/row on a 2-core CPU box). Cross-batch
             # overlap is preserved by the pool width: batch N+1's
             # encode runs on a second pipeline worker while batch N's
             # fetch blocks on the first. Both halves stay under the
@@ -2014,8 +2014,8 @@ class MicroBatcher:
     def _metric_of(self, p: "_Pending", tmpl) -> Any:
         """Memoized metric dataclass for the fragment lane: a small
         label-tuple key + dict get replaces per-row frozen-dataclass
-        construction (part of the measured ``deliver`` cost, PROFILE
-        r18). Fragment verdicts carry no patch, so mutated is always
+        construction (part of the measured ``deliver`` cost). Fragment
+        verdicts carry no patch, so mutated is always
         False and error_code is the template's code. Bounded at 4096
         entries — real traffic's label diversity is tiny; a hostile
         high-cardinality stream falls back to plain construction."""

@@ -1,7 +1,7 @@
 """Prefork HTTP frontend — scaling past the one-event-loop framing wall.
 
-PROFILE.md measures the Python asyncio HTTP layer saturating ≈1.3k
-requests/s per process while the device path idles at 11k+/s. The
+The Python asyncio HTTP layer saturated ≈1.3k requests/s per process on
+the 2-core CPU box this was built on, far below the batcher behind it. The
 reference's answer to frontend limits is replicas behind a Service
 (README.md:21-26); this module is the in-box equivalent:
 
@@ -592,15 +592,18 @@ async def worker_main(
         # the worker as a THIN owner of a native event loop: HTTP framing
         # + AdmissionReview parsing run GIL-free (csrc/httpfront.cpp);
         # this asyncio loop only forwards parsed frames over the bridge
+        from policy_server_tpu.api.handlers import MAX_BODY_BYTES
+        from policy_server_tpu.runtime import native_frontend as nf
+
+        if not nf.native_available():
+            # asked for by flag: an error (the respawn breaker then
+            # reports the slot), never a quiet aiohttp worker
+            raise RuntimeError(
+                "--frontend native: csrc/httpfront.cpp failed to build "
+                f"or load: {nf.load_error()}"
+            )
         sock = None
         try:
-            from policy_server_tpu.api.handlers import MAX_BODY_BYTES
-            from policy_server_tpu.runtime import native_frontend as nf
-
-            if not nf.native_available():
-                raise RuntimeError(
-                    "csrc/httpfront.cpp failed to build or load"
-                )
             sock = nf.make_listen_socket(addr, port)
             front = nf.NativeFrontend(
                 sock,

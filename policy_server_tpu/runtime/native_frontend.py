@@ -19,11 +19,11 @@ per-row oracle; graftcheck RS01/RS02 pin the classification and the
 emitter's key order to models/admission.py.
 
 Build model mirrors ops/fastenc.py: compiled on demand with g++ into
-``build/httpfront-<py>.so`` and cached; any failure (no compiler,
-unsupported platform) must degrade loudly-but-gracefully — the server
-falls back to the Python (aiohttp) frontend, which stays the correctness
-oracle for the differential framing corpus
-(tests/test_native_frontend.py).
+``build/`` under a name that hashes its source and flags
+(utils/nativebuild.py). ``--frontend native`` asks for this library, so a
+failed build or load is a boot error there; the Python (aiohttp)
+frontend stays the correctness oracle for the differential framing
+corpus (tests/test_native_frontend.py), not a silent fallback.
 
 Two sinks consume parsed records:
 
@@ -40,12 +40,8 @@ from __future__ import annotations
 import ctypes
 import json
 import math
-import os
 import socket
 import struct
-import subprocess
-import sys
-import sysconfig
 import threading
 import time
 from pathlib import Path
@@ -55,9 +51,13 @@ from policy_server_tpu import failpoints
 from policy_server_tpu.models import FragVerdict
 from policy_server_tpu.telemetry import flightrec
 from policy_server_tpu.telemetry.tracing import logger
+from policy_server_tpu.utils.nativebuild import (
+    REPO_ROOT,
+    NativeBuildError,
+    build_shared_library,
+)
 
-_REPO_ROOT = Path(__file__).resolve().parent.parent.parent
-_SRC = _REPO_ROOT / "csrc" / "httpfront.cpp"
+_SRC = REPO_ROOT / "csrc" / "httpfront.cpp"
 
 # default request-body cap for DIRECT construction (tests, embedding).
 # The server and prefork workers pass api.handlers.MAX_BODY_BYTES
@@ -110,49 +110,25 @@ _STAT_SLOTS = 24
 _lib_lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _pylib: ctypes.PyDLL | None = None
-_lib_failed = False
+# why the library is unavailable (None: not tried yet, or loaded)
+_lib_error: str | None = None  # guarded-by: _lib_lock
 
 
-def _build_library() -> Path | None:
-    out_dir = _REPO_ROOT / "build"
-    out_dir.mkdir(exist_ok=True)
-    tag = sysconfig.get_config_var("SOABI") or (
-        f"py{sys.version_info[0]}{sys.version_info[1]}"
-    )
-    # POLICY_SERVER_NATIVE_SAN=asan (tools/sanitize_lane.py) builds an
-    # ASan+UBSan-instrumented variant under a distinct name so the
-    # sanitize lane never poisons the production build cache
-    san = os.environ.get("POLICY_SERVER_NATIVE_SAN", "") == "asan"
-    out = out_dir / f"httpfront-{tag}{'-san' if san else ''}.so"
-    if out.exists() and out.stat().st_mtime >= _SRC.stat().st_mtime:
-        return out
-    opt = (
-        ["-O1", "-g", "-fsanitize=address,undefined",
-         "-fno-sanitize-recover=all"]
-        if san
-        else ["-O2"]
-    )
-    cmd = [
-        "g++", *opt, "-shared", "-fPIC", "-std=c++17", "-pthread",
-        str(_SRC), "-o", str(out), "-ldl",
-    ]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=180)
-    except Exception:
-        return None
-    return out
+def _build_library() -> Path:
+    return build_shared_library(_SRC, ["-pthread"], libs=["-ldl"])
 
 
 def _load() -> ctypes.CDLL | None:
-    global _lib, _pylib, _lib_failed
+    """The loaded library, or None when it cannot be built or loaded
+    (:func:`load_error` says why; one attempt per process). Whoever ASKED
+    for the native front-end turns None into an error
+    (server._start_native_frontend)."""
+    global _lib, _pylib, _lib_error
     with _lib_lock:
-        if _lib is not None or _lib_failed:
+        if _lib is not None or _lib_error is not None:
             return _lib
-        path = _build_library()
-        if path is None:
-            _lib_failed = True
-            return None
         try:
+            path = _build_library()
             lib = ctypes.CDLL(str(path))
             # completion calls are pure memory ops (lock-free stack push,
             # no syscalls): binding them through PyDLL keeps the GIL held
@@ -160,8 +136,8 @@ def _load() -> ctypes.CDLL | None:
             # bounce per request — under 4 concurrent delivery threads
             # that bounce dominated the serving profile
             pylib = ctypes.PyDLL(str(path))
-        except OSError:
-            _lib_failed = True
+        except (NativeBuildError, OSError) as e:
+            _lib_error = str(e)
             return None
         lib.httpfront_create.restype = ctypes.c_void_p
         lib.httpfront_create.argtypes = [
@@ -230,6 +206,13 @@ def _load() -> ctypes.CDLL | None:
 
 def native_available() -> bool:
     return _load() is not None
+
+
+def load_error() -> str:
+    """Why :func:`native_available` is False."""
+    _load()
+    with _lib_lock:
+        return _lib_error or ""
 
 
 def tls_available() -> bool:

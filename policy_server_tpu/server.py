@@ -145,15 +145,14 @@ class PolicyServer:
                     "404 — hit the endpoint repeatedly or set "
                     "--http-workers 1 when profiling"
                 )
-        if config.compilation_cache_dir:
-            # persistent XLA compilation cache: warmed policy programs
-            # survive restarts (SURVEY.md §5 checkpoint/resume row)
-            import jax
+        # persistent XLA compilation cache, placed BEFORE the first compile
+        # (JAX_COMPILATION_CACHE_DIR wins, else <checkout>/.jax_cache), and
+        # the compile counter the boot report and /metrics read
+        from policy_server_tpu.runtime import compile_cache
 
-            jax.config.update(
-                "jax_compilation_cache_dir", config.compilation_cache_dir
-            )
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        cache_info = compile_cache.configure()
+        compiles = compile_cache.counter()
+        compiles_at_entry = compiles.snapshot()
         if config.distributed_coordinator:
             # Multi-host bring-up BEFORE any device enumeration: the mesh
             # built below must span every process's devices (SURVEY.md §7.2
@@ -206,9 +205,8 @@ class PolicyServer:
                 "policy_ids": sorted(config.policies),
                 "backend": config.evaluation_backend,
                 "predicate_opt": config.predicate_opt,
-                "kernel": config.kernel,
                 "columnar": config.columnar,
-                "jax": _jax_version(),
+                "jax": _versions()["jax"],
             })
             manifest = statestore.last_good_manifest("default")
             boot_report.update(
@@ -325,9 +323,8 @@ class PolicyServer:
             # columnar device transport + input-buffer donation (round 12)
             columnar=config.columnar,
             donate_buffers=config.donate_buffers,
-            # predicate-program optimizer + device kernel form (round 15)
+            # predicate-program optimizer (round 15)
             predicate_opt=config.predicate_opt,
-            kernel=config.kernel,
         )
         environment = _build_environment(config, builder_kwargs)
 
@@ -847,7 +844,7 @@ class PolicyServer:
             )
             # Two-tier dedup + verdict cache (round 6): hit rate is the
             # cache's whole value proposition, so it must be visible on a
-            # running server (VERDICT r5 weak #4)
+            # running server
             dedup = getattr(environment, "dedup_stats", None) or {}
             yield (
                 metrics_names.DEDUP_BLOB_HITS, "counter",
@@ -887,7 +884,7 @@ class PolicyServer:
                 "fragments (zero per-row materialization)",
                 dedup.get("fragment_hits", 0),
             )
-            # Host-pipeline decomposition (PROFILE.md round 6): where the
+            # Host-pipeline decomposition (round 6): where the
             # per-row host time goes on the native dispatch path
             profile = getattr(environment, "host_profile", None) or {}
             yield (
@@ -1328,14 +1325,12 @@ class PolicyServer:
                 "under the aiohttp terminator or plaintext",
                 1 if _tlsmgr is not None else 0,
             )
-            # Predicate-program optimizer + Pallas kernel path (round
-            # 15). Optimizer facts are static per serving epoch (the
-            # pass re-runs for every reload candidate); gauges follow
-            # the epoch pointer. All zero with --predicate-opt off /
-            # --kernel xla (families still export so dashboard panels
-            # resolve everywhere).
+            # Predicate-program optimizer (round 15). Optimizer facts
+            # are static per serving epoch (the pass re-runs for every
+            # reload candidate); gauges follow the epoch pointer. All
+            # zero with --predicate-opt off (families still export so
+            # dashboard panels resolve everywhere).
             ostats = getattr(environment, "optimizer_stats", None) or {}
-            pstats = getattr(environment, "pallas_stats", None) or {}
             yield (
                 metrics_names.PREDICATE_SUBTREES_SHARED, "gauge",
                 "Distinct predicate subtrees shared across policies by "
@@ -1366,26 +1361,6 @@ class PolicyServer:
                 "Packed-row bytes saved per row, summed over schema "
                 "buckets, vs the unoptimized layout",
                 ostats.get("row_bytes_saved", 0),
-            )
-            yield (
-                metrics_names.PALLAS_DISPATCHES, "counter",
-                "Device dispatches served by the fused Pallas "
-                "gather→predicate→reduce kernel (--kernel pallas, hot "
-                "buckets)",
-                pstats.get("dispatches", 0),
-            )
-            yield (
-                metrics_names.PALLAS_BUCKETS_ARMED, "gauge",
-                "Schema buckets currently armed for the Pallas kernel "
-                "(per-bucket opt-in by dispatch count)",
-                pstats.get("buckets_armed", 0),
-            )
-            yield (
-                metrics_names.PALLAS_INTERPRET_MODE, "gauge",
-                "1 when the Pallas kernel runs in interpret mode (the "
-                "Mosaic capability probe failed — bit-exact, slow, "
-                "loudly warned)",
-                pstats.get("interpret_mode", 0),
             )
             # Multi-tenant serving (round 16): tenant-labelled
             # admission / fair-dispatch / lifecycle families. Sample
@@ -1759,6 +1734,44 @@ class PolicyServer:
                 "warm boot (column fingerprint + payload hash matched)",
                 mstats.get("cells_restored", 0),
             )
+            # What the program runs on (the boot report's device facts,
+            # one info-style gauge) and what the compiler did.
+            _info_labels = (
+                "platform", "device_kind", "device_count", "mesh",
+                "output_devices", "jax", "jaxlib", "libtpu",
+            )
+            yield (
+                metrics_names.DEVICE_INFO, "gauge",
+                "The devices the serving program runs on, as JAX reports "
+                "them (value 1; the facts are the labels)",
+                [(tuple(str(boot.get(n, "")) for n in _info_labels), 1)],
+                _info_labels,
+            )
+            _compiled = compiles.snapshot()
+            yield (
+                metrics_names.XLA_PROGRAMS_COMPILED, "counter",
+                "Programs the XLA backend compiled in this process "
+                "(persistent-cache misses)",
+                _compiled["compiled"],
+            )
+            yield (
+                metrics_names.XLA_COMPILE_CACHE_HITS, "counter",
+                "Programs loaded from the persistent compilation cache "
+                "instead of compiled",
+                _compiled["cache_hits"],
+            )
+            yield (
+                metrics_names.PLANE_PROGRAM_COMPILES, "counter",
+                "Columnar plane structures traced (each one XLA program; "
+                "0 per interval in steady state)",
+                getattr(environment, "plane_program_compiles", 0) or 0,
+            )
+            yield (
+                metrics_names.PLANE_PROGRAMS_PENDING, "gauge",
+                "Columnar plane programs still compiling off the serving "
+                "path (their batches ship the dense form meanwhile)",
+                getattr(environment, "plane_programs_pending", 0) or 0,
+            )
             # Flight recorder (round 18, telemetry/flightrec.py): event
             # volume, row-sampling volume, and the tail-exemplar table —
             # the slowest rows of the current window, labelled by their
@@ -1826,7 +1839,27 @@ class PolicyServer:
                 tls_context, "_reloadable", None
             )
 
-        # -- boot report (round 17): how warm this boot actually was ------
+        # -- boot report: what this boot runs on and how warm it was -------
+        # The device facts are read from the devices the environment
+        # placed its program on; the compile counts are this boot's own
+        # (in-process reboots share one counter). Logged on EVERY boot and
+        # stamped on /metrics (policy_server_device_info) — the chip smoke
+        # and every benchmark line read it from there.
+        compiled = compiles.snapshot()
+        programs, cache_hits, compile_seconds = (
+            compiled[k] - compiles_at_entry[k]
+            for k in ("compiled", "cache_hits", "seconds")
+        )
+        boot_report.update(
+            _device_report(environment),
+            compile_cache_dir=cache_info["dir"],
+            compile_cache_populated_on_entry=cache_info[
+                "populated_on_entry"
+            ],
+            programs_compiled=programs,
+            compile_cache_hits=cache_hits,
+            compile_seconds=round(compile_seconds, 3),
+        )
         # "warm" = the state store carried a last-good manifest forward;
         # the drill additionally checks artifacts_from_cache/fetches to
         # prove the zero-network property.
@@ -1849,13 +1882,11 @@ class PolicyServer:
             except ImportError:
                 pass
             statestore.record_boot_report(boot_report)
-            logger.info(
-                "boot report", extra={"span_fields": dict(boot_report)}
-            )
         else:
             boot_report["time_to_ready_seconds"] = round(
                 _time.monotonic() - boot_t0, 3
             )
+        logger.info("boot report", extra={"span_fields": dict(boot_report)})
 
         return cls(config, state, tls_context)
 
@@ -1948,26 +1979,29 @@ class PolicyServer:
     def _start_native_frontend(self) -> bool:
         """Bind the GIL-free C++ HTTP front-end on the API port (it then
         OWNS the evaluation POST surface; pprof and /audit/reports GETs
-        live on the readiness port). Returns False — with ONE loud line —
-        on any build/load/bind failure, and the caller serves through the
-        always-available Python frontend instead (the round-7 soft-dep
-        pattern: degraded, never broken). With TLS configured, the
+        live on the readiness port). ``--frontend native`` asked for the
+        library, so a failed build or load RAISES: a server that quietly
+        frames in Python instead answers the same 200s and is not the
+        server that was asked for. A bind or TLS-setup failure past that
+        point returns False — with ONE loud line — and the caller serves
+        through the Python frontend. With TLS configured, the
         handshake terminates ON the native epoll loops (round 20):
         certs.py's last-good identity builds the SSL_CTX, hot-rotation
         swaps it for NEW connections while established ones drain on
         the old, and a missing/unlinkable libssl falls back LOUDLY to
         the aiohttp TLS terminator — degraded in throughput, identical
         in trust surface."""
+        from policy_server_tpu.api.handlers import MAX_BODY_BYTES
+        from policy_server_tpu.runtime import native_frontend as nf
+
+        if not nf.native_available():
+            raise RuntimeError(
+                "--frontend native: csrc/httpfront.cpp failed to build or "
+                f"load: {nf.load_error()}"
+            )
         sock = None
         tls_manager = None
         try:
-            from policy_server_tpu.api.handlers import MAX_BODY_BYTES
-            from policy_server_tpu.runtime import native_frontend as nf
-
-            if not nf.native_available():
-                raise RuntimeError(
-                    "csrc/httpfront.cpp failed to build or load"
-                )
             # one body cap across every process that can accept the API
             # socket — a drift here would make 413s nondeterministic
             # behind SO_REUSEPORT
@@ -2379,16 +2413,53 @@ def _daemonize(config: Config) -> None:
     os.dup2(err.fileno(), sys.stderr.fileno())
 
 
-def _jax_version() -> str:
-    """The jax version string for the compile fingerprint (a version
-    bump invalidates the persistent XLA cache's hit expectations); ""
-    when the backend is not importable (oracle-only deployments)."""
-    try:
+def _versions() -> dict[str, str]:
+    """Installed jax / jaxlib / libtpu versions ("" when absent: libtpu on
+    a CPU-only installation). The jax version also keys the compile
+    fingerprint — a bump invalidates the persistent XLA cache's hit
+    expectations."""
+    from importlib import metadata
+
+    out = {}
+    for dist in ("jax", "jaxlib", "libtpu"):
+        try:
+            out[dist] = metadata.version(dist)
+        except metadata.PackageNotFoundError:
+            out[dist] = ""
+    return out
+
+
+def _device_report(environment) -> dict:
+    """What the serving program runs on, as JAX reports it for the devices
+    the environment placed its program on: the attached mesh's devices, or
+    JAX's default device for a single-device program. A mesh covers every
+    visible device (parallel/mesh.py resolve_axes), so ``device_count`` is
+    also ``len(jax.devices())``; ``output_devices`` is what a warm-up
+    output's ``sharding.device_set`` spans, so a program that put
+    everything on one device cannot report otherwise. The oracle backend
+    runs no device program and says so."""
+    report = {"platform": "host-oracle", "device_kind": "", "device_count": 0,
+              "mesh": "", "output_devices": 0}
+    if getattr(environment, "backend", "jax") == "jax":
         import jax
 
-        return str(jax.__version__)
-    except ImportError:
-        return ""
+        mesh = environment.mesh
+        devices = (
+            list(mesh.devices.flat) if mesh is not None else jax.devices()[:1]
+        )
+        report = {
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices),
+            # "data:4,policy:1"; "" for a single-device program
+            "mesh": ",".join(
+                f"{axis}:{size}" for axis, size in sorted(mesh.shape.items())
+            ) if mesh is not None else "",
+            # devices a warm-up output's sharding spans (0: no warm-up)
+            "output_devices": getattr(environment, "warmup_output_devices", 0),
+        }
+    report.update(_versions())
+    return report
 
 
 def _bound_port(runner: web.AppRunner) -> int | None:

@@ -134,19 +134,15 @@ NATIVE_CONN_CAP_REJECTS = "policy_server_native_connection_cap_rejections"
 SOAK_WINDOW_RPS = "policy_server_soak_window_rps"
 SOAK_WINDOW_P99_MS = "policy_server_soak_window_p99_ms"
 SOAK_WINDOW_SHED_RATE = "policy_server_soak_window_shed_rate"
-# round 15 — predicate-program optimizer (ops/optimizer.py) + Pallas
-# fused kernel path (ops/pallas_kernels.py). Names follow
-# policy_server_predicate_<OPTIMIZER_STAT_KEY> /
-# policy_server_pallas_<PALLAS_STAT_KEY> — graftcheck's OB07 enforces
-# the stats-dict ↔ constant ↔ dashboard mapping stays total.
+# round 15 — predicate-program optimizer (ops/optimizer.py). Names
+# follow policy_server_predicate_<OPTIMIZER_STAT_KEY> — graftcheck's
+# OB07 enforces the stats-dict ↔ constant ↔ dashboard mapping stays
+# total.
 PREDICATE_SUBTREES_SHARED = "policy_server_predicate_subtrees_shared"
 PREDICATE_POLICIES_FOLDED = "policy_server_predicate_policies_folded"
 PREDICATE_RULES_FOLDED = "policy_server_predicate_rules_folded"
 PREDICATE_FIELDS_PRUNED = "policy_server_predicate_fields_pruned"
 PREDICATE_ROW_BYTES_SAVED = "policy_server_predicate_row_bytes_saved"
-PALLAS_DISPATCHES = "policy_server_pallas_dispatches"
-PALLAS_BUCKETS_ARMED = "policy_server_pallas_buckets_armed"
-PALLAS_INTERPRET_MODE = "policy_server_pallas_interpret_mode"
 # round 16 — multi-tenant serving (tenancy.py + runtime/scheduler.py):
 # tenant-labelled admission/quota/fair-dispatch/lifecycle families.
 # These are the first LABELLED runtime-stats families: the yield's
@@ -278,6 +274,19 @@ MATRIX_LOOKUP_HITS = "policy_server_audit_matrix_lookup_hits"
 MATRIX_LOOKUP_MISSES = "policy_server_audit_matrix_lookup_misses"
 MATRIX_SPILLS = "policy_server_audit_matrix_spills"
 MATRIX_CELLS_RESTORED = "policy_server_audit_matrix_cells_restored"
+
+# What the program runs on and what the compiler did (chip bring-up): one
+# info-style gauge (value 1) whose labels are the boot report's device
+# facts as JAX reports them — the chip smoke and every benchmark line
+# stamp their output from it — plus the process's XLA compile counts
+# (runtime/compile_cache.py: real compiles vs persistent-cache hits) and
+# the columnar plane structures traced / still compiling off the serving
+# path (evaluation/environment.py).
+DEVICE_INFO = "policy_server_device_info"
+XLA_PROGRAMS_COMPILED = "policy_server_xla_programs_compiled"
+XLA_COMPILE_CACHE_HITS = "policy_server_xla_compile_cache_hits"
+PLANE_PROGRAM_COMPILES = "policy_server_plane_program_compiles"
+PLANE_PROGRAMS_PENDING = "policy_server_plane_programs_pending"
 
 # Prometheus requires a fixed label set per metric family; optional reference
 # labels (resource_namespace, error_code) encode absence as "".

@@ -11,8 +11,9 @@ NativeUnsupported and the caller falls back to the Python engine — and
 ``PSTPU_NO_NATIVE_WASM=1`` disables the native path entirely.
 
 Build model mirrors ops/fastenc.py: compiled on demand with g++ into
-``build/wasmint-<py>.so`` and cached; any build failure degrades to the
-Python interpreter silently (it is the reference implementation).
+``build/`` under a name that hashes its source and flags
+(utils/nativebuild.py); a build failure degrades to the Python
+interpreter (the reference implementation) with one logged warning.
 
 Reference parity: the reference embeds wasmtime's cranelift JIT
 (src/evaluation/precompiled_policy.rs:46-64); this is the build's native
@@ -23,11 +24,9 @@ differential oracle (tests/test_native_wasm.py runs both).
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import struct
-import subprocess
-import sys
-import sysconfig
 import threading
 from pathlib import Path
 
@@ -39,9 +38,13 @@ from policy_server_tpu.wasm.interp import (
     WasmFuelExhausted,
     WasmTrap,
 )
+from policy_server_tpu.utils.nativebuild import (
+    REPO_ROOT,
+    NativeBuildError,
+    build_shared_library,
+)
 
-_REPO_ROOT = Path(__file__).resolve().parents[2]
-_SRC = _REPO_ROOT / "csrc" / "wasmint.cpp"
+_SRC = REPO_ROOT / "csrc" / "wasmint.cpp"
 
 _BLOCK = 0x02
 _LOOP = 0x03
@@ -77,31 +80,8 @@ _HOSTCB = ctypes.CFUNCTYPE(
 )
 
 
-def _build_library() -> Path | None:
-    out_dir = _REPO_ROOT / "build"
-    out_dir.mkdir(exist_ok=True)
-    tag = sysconfig.get_config_var("SOABI") or f"py{sys.version_info[0]}{sys.version_info[1]}"
-    # POLICY_SERVER_NATIVE_SAN=asan (tools/sanitize_lane.py): sanitized
-    # variant under a distinct name, production cache untouched
-    san = os.environ.get("POLICY_SERVER_NATIVE_SAN", "") == "asan"
-    out = out_dir / f"wasmint-{tag}{'-san' if san else ''}.so"
-    if out.exists() and out.stat().st_mtime >= _SRC.stat().st_mtime:
-        return out
-    opt = (
-        ["-O1", "-g", "-fsanitize=address,undefined",
-         "-fno-sanitize-recover=all"]
-        if san
-        else ["-O2"]
-    )
-    try:
-        subprocess.run(
-            ["g++", *opt, "-shared", "-fPIC", "-std=c++17",
-             str(_SRC), "-o", str(out)],
-            check=True, capture_output=True, timeout=180,
-        )
-    except Exception:  # noqa: BLE001 — no compiler/feature degrade
-        return None
-    return out
+def _build_library() -> Path:
+    return build_shared_library(_SRC)
 
 
 def _load() -> ctypes.CDLL | None:
@@ -114,13 +94,15 @@ def _load() -> ctypes.CDLL | None:
         if os.environ.get("PSTPU_NO_NATIVE_WASM") == "1":
             _lib_failed = True
             return None
-        path = _build_library()
-        if path is None:
-            _lib_failed = True
-            return None
         try:
-            lib = ctypes.CDLL(str(path))
-        except OSError:
+            lib = ctypes.CDLL(str(_build_library()))
+        except (NativeBuildError, OSError) as e:
+            # nothing asks for this core by flag, so the Python
+            # interpreter takes over — but never silently
+            logging.getLogger("kubewarden-policy-server").warning(
+                "native wasm core unavailable, wasm policies run on the "
+                "Python interpreter: %s", e,
+            )
             _lib_failed = True
             return None
         lib.wasmint_module_new.restype = ctypes.c_void_p

@@ -6,6 +6,3 @@ OPTIMIZER_STAT_KEYS = (
     "covered_stat",
     "phantom_stat",
 )
-PALLAS_STAT_KEYS = (
-    "ghost_kernel_stat",
-)

@@ -186,7 +186,12 @@ class TestColumnarParity:
         mat[:, 9] = np.arange(4)
         mat[:, 12] = -1
         delta: dict = {}
-        EvaluationEnvironment._delta_plane(delta, "i32", mat, 0.75)
+        EvaluationEnvironment._ship_plane(
+            delta, "i32", mat,
+            EvaluationEnvironment._select_delta_cols(
+                np.flatnonzero(mat.any(axis=0)), mat.shape[1]
+            ),
+        )
         cols = delta["i32_cols"]
         vals = delta["i32"]
         assert len(cols) == 4  # 3 live columns bucketed to 4
@@ -195,6 +200,61 @@ class TestColumnarParity:
         rebuilt = np.zeros_like(mat)
         rebuilt[:, cols] = vals
         assert np.array_equal(rebuilt, mat)
+
+    def test_column_sets_settle_and_compile_off_the_serving_path(self):
+        """The shipped column set of a schema is a union that only grows:
+        after the first pass over a corpus, ANY re-batching of the same
+        rows ships an already-compiled structure — no program is traced
+        inside a dispatch once warm-up ran (the dense form serves while
+        the settled set compiles off the serving path), verdicts stay
+        bit-exact throughout, and warm-up itself teaches the sets
+        nothing."""
+        env = EvaluationEnvironmentBuilder(
+            backend="jax", verdict_cache_size=0
+        ).build(_parsed())
+        oracle_env = EvaluationEnvironmentBuilder(backend="oracle").build(
+            _parsed()
+        )
+        try:
+            corpus = _items(_requests(48))
+            want = _dicts(oracle_env.validate_batch(corpus))
+            env.warmup((1, 2, 4, 8, 16))
+            assert env._plane_columns == {}
+            warm = env.plane_program_compiles
+            # pass 1: odd-sized batches; every dispatch must find a
+            # compiled program (its own, or the dense form)
+            got = []
+            for lo, hi in ((0, 16), (16, 19), (19, 24), (24, 25), (25, 41),
+                           (41, 48)):
+                before = env.plane_program_compiles
+                got += _dicts(env.validate_batch(corpus[lo:hi]))
+                with env._profile_lock:
+                    pending = env._plane_jobs_pending
+                assert pending or env.plane_program_compiles == before
+            assert got == want
+            deadline = time.monotonic() + 120
+            while env.plane_programs_pending:
+                assert time.monotonic() < deadline
+                time.sleep(0.05)
+            assert env.plane_program_compiles > warm
+            # pass 2: the same rows, batched differently — nothing new
+            settled = env.plane_program_compiles
+            shipped = env.host_profile["wire_bytes_shipped"]
+            packed = env.host_profile["wire_bytes_packed_equiv"]
+            got = []
+            for lo, hi in ((0, 7), (7, 23), (23, 24), (24, 40), (40, 48)):
+                got += _dicts(env.validate_batch(corpus[lo:hi]))
+            assert got == want
+            assert env.plane_programs_pending == 0
+            assert env.plane_program_compiles == settled
+            # ...and it ships the settled sparse columns, not the dense form
+            hp = env.host_profile
+            assert (hp["wire_bytes_shipped"] - shipped) < 0.5 * (
+                hp["wire_bytes_packed_equiv"] - packed
+            )
+        finally:
+            env.close()
+            oracle_env.close()
 
 
 class TestSubmitMany:

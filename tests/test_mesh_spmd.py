@@ -323,13 +323,17 @@ class TestMeshWarmup:
         env.attach_mesh(make_mesh(MeshSpec.parse("data:4,policy:2")))
         try:
             assert env.warmup_dispatches == 2 * len(env.schemas)
-            # run_batch does not tick dispatched_chunks (that counter is
-            # the serving pipeline's); the columnar plane counters prove
-            # both structures actually dispatched: 2 per schema, each a
-            # full bucket of wire rows
+            # the plane-program counter proves both structures compiled:
+            # 2 per schema; only the all-elided one is a batch through
+            # the serving funnel (the dense one runs from a template, so
+            # warm-up teaches the column sets nothing), and its output
+            # spans every device of the mesh
             before = env.host_profile["wire_rows"]
             env.warmup((4,))
+            assert env.plane_program_compiles == 2 * len(env.schemas)
             warm_rows = env.host_profile["wire_rows"] - before
-            assert warm_rows == 2 * len(env.schemas) * env.bucket_for(4)
+            assert warm_rows == len(env.schemas) * env.bucket_for(4)
+            assert env.warmup_output_devices == 8
+            assert env._plane_columns == {}
         finally:
             env.close()

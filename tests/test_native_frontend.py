@@ -10,12 +10,12 @@ bytes must match exactly. The Python (aiohttp) frontend is the correctness
 oracle; the native frontend earns its throughput by being
 indistinguishable from it.
 
-Also covered: graceful degradation when the extension cannot build/load
-(loud warning, automatic Python fallback, server still boots and serves —
-the round-7 soft-dep pattern)."""
+Also covered: an extension that cannot build/load is a boot error under
+``--frontend native``, never a quiet Python front-end."""
 
 from __future__ import annotations
 
+import asyncio
 import json
 import socket
 import time
@@ -23,6 +23,7 @@ import time
 import pytest
 import requests
 
+from policy_server_tpu.server import PolicyServer
 from test_server import ServerHandle, make_config, pod_review_body
 
 nf = pytest.importorskip(
@@ -32,8 +33,7 @@ nf = pytest.importorskip(
 
 pytestmark = pytest.mark.skipif(
     not nf.native_available(),
-    reason="httpfront.cpp failed to build (no g++?) — the server "
-    "degrades to the Python frontend, covered by test_fallback below",
+    reason="httpfront.cpp failed to build (no g++?)",
 )
 
 
@@ -488,29 +488,26 @@ def test_shed_429_carries_retry_after_natively():
 # -- graceful degradation ----------------------------------------------------
 
 
-def test_fallback_when_extension_unavailable(monkeypatch):
-    """--frontend native with a missing/broken extension must boot the
-    Python frontend with ONE loud warning and serve normally (the
-    fetch/verify soft-dep pattern from round 7)."""
+def test_unavailable_extension_is_a_boot_error(monkeypatch):
+    """--frontend native asks for the extension: when it cannot be built
+    or loaded the server refuses to start and says why — it does not
+    quietly frame in Python and answer the same 200s."""
     from policy_server_tpu.runtime import native_frontend as mod
     from policy_server_tpu.telemetry import metrics as metrics_mod
 
     metrics_mod.reset_metrics_for_tests()
     monkeypatch.setattr(mod, "_lib", None)
-    monkeypatch.setattr(mod, "_lib_failed", True)
-    handle = ServerHandle(make_config(frontend="native"))
-    try:
-        assert handle.server._native_frontend is None
-        assert handle.server.state.native_frontend is None
-        r = requests.post(
-            handle.url("/validate/pod-privileged"),
-            json=pod_review_body(True),
-            timeout=60,
-        )
-        assert r.status_code == 200
-        assert r.json()["response"]["allowed"] is False
-    finally:
-        handle.stop()
+    monkeypatch.setattr(mod, "_lib_error", "g++: injected build failure")
+    server = PolicyServer.new_from_config(make_config(frontend="native"))
+
+    async def boot():
+        try:
+            await server.start()
+        finally:
+            await server.stop()
+
+    with pytest.raises(RuntimeError, match="injected build failure"):
+        asyncio.run(boot())
 
 
 def test_prefork_workers_own_native_loops():

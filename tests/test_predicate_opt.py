@@ -1,5 +1,4 @@
-"""Predicate-program optimizer (round 15, ops/optimizer.py) + the
-Pallas fused kernel (ops/pallas_kernels.py).
+"""Predicate-program optimizer (round 15, ops/optimizer.py).
 
 Three layers of proof:
 
@@ -12,9 +11,7 @@ Three layers of proof:
    family (mutators included, so patches are covered) judged by three
    independent executors on the same corpus: opt-on device, opt-off
    device, and the host oracle interpreting the ORIGINAL IR. Byte-
-   identical AdmissionResponses required; the tri-way also runs with
-   ``--kernel pallas`` (interpret mode) single-device and on the
-   8-virtual-device (data×policy) mesh.
+   identical AdmissionResponses required.
 3. **Constant-verdict lifecycle regression** — a policy folding to a
    constant DENY drops out of the device program, but its per-policy
    audit report rows, responses, and messages must be indistinguishable
@@ -541,89 +538,6 @@ def test_mutation_patches_identical_under_opt(catalog_envs):
         out[name] = r.to_dict()
         assert r.patch is not None, name
     assert out["opt"] == out["noopt"] == out["oracle"]
-
-
-# ---------------------------------------------------------------------------
-# pallas kernel: tri-way, single-device and mesh
-# ---------------------------------------------------------------------------
-
-
-@pytest.fixture(scope="module")
-def pallas_env():
-    entries = _catalog_entries()
-    env = EvaluationEnvironmentBuilder(
-        backend="jax", predicate_opt=True, kernel="pallas"
-    ).build(entries)
-    # arm every bucket (tests must not depend on the hotness threshold)
-    env._pallas_armed.update(range(len(env.schemas)))
-    env._pallas_interpret = True
-    return env
-
-
-def test_pallas_hotness_gate_arms_after_threshold():
-    """The per-bucket opt-in is real: dispatches below the threshold
-    serve the XLA program (zero kernel dispatches), crossing it arms
-    the bucket — warmup crosses it organically, so the kernel compile
-    lands there, and buckets warmup never visits stay cold."""
-    env = build(
-        {"priv": {"module": "builtin://pod-privileged"}},
-        kernel="pallas",
-    )
-    batch = env.schemas[0].empty_batch_packed(4)
-    env._add_wasm_bits(batch, 4)
-    for _ in range(env.PALLAS_HOT_DISPATCHES - 1):
-        env.run_batch(dict(batch))
-    assert env.pallas_stats["dispatches"] == 0  # still cold: XLA served
-    assert env.pallas_stats["buckets_armed"] == 0
-    env.run_batch(dict(batch))
-    stats = env.pallas_stats
-    assert stats["buckets_armed"] == 1
-    assert stats["dispatches"] == 1
-
-
-def test_pallas_triway_single_device(catalog_envs, pallas_env):
-    items = _catalog_items(40, seed=33)
-    pallas_env.reset_verdict_cache()
-    got = [
-        r.to_dict() if not isinstance(r, Exception) else repr(r)
-        for r in pallas_env.validate_batch(items)
-    ]
-    catalog_envs["oracle"].reset_verdict_cache()
-    want = [
-        r.to_dict() if not isinstance(r, Exception) else repr(r)
-        for r in catalog_envs["oracle"].validate_batch(items)
-    ]
-    assert got == want
-    assert pallas_env.pallas_stats["dispatches"] > 0
-    assert pallas_env.pallas_stats["interpret_mode"] == 1
-
-
-def test_pallas_triway_mesh(catalog_envs):
-    """The kernel per policy shard inside the shard_map switch branches
-    (8 virtual devices, data:4 × policy:2)."""
-    from policy_server_tpu.config.config import MeshSpec
-    from policy_server_tpu.parallel import make_mesh
-
-    entries = _catalog_entries()
-    env = EvaluationEnvironmentBuilder(
-        backend="jax", predicate_opt=True, kernel="pallas"
-    ).build(entries)
-    env.attach_mesh(make_mesh(MeshSpec.parse("data:4,policy:2")))
-    assert env._mesh_block_pallas is not None
-    env._pallas_armed.update(range(len(env.schemas)))
-    env._pallas_interpret = True
-    items = _catalog_items(24, seed=44)
-    got = [
-        r.to_dict() if not isinstance(r, Exception) else repr(r)
-        for r in env.validate_batch(items)
-    ]
-    catalog_envs["oracle"].reset_verdict_cache()
-    want = [
-        r.to_dict() if not isinstance(r, Exception) else repr(r)
-        for r in catalog_envs["oracle"].validate_batch(items)
-    ]
-    assert got == want
-    assert env.pallas_stats["dispatches"] > 0
 
 
 # ---------------------------------------------------------------------------

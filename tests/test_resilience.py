@@ -854,110 +854,6 @@ def test_hot_reload_under_load_zero_drops_bit_exact():
         handle.stop()
 
 
-def test_sighup_flip_under_load_with_pallas_kernel():
-    """Round 15: the SIGHUP epoch flip under sustained load with
-    ``--kernel pallas`` armed. The fused Pallas kernel serves the live
-    path (warmup arms every bucket at boot; on this CPU box the loud
-    capability probe demotes it to interpret mode — bit-exact, slow,
-    never silent), a real reload swaps the epoch mid-traffic, the NEW
-    environment re-arms the kernel, and every verdict across the flip
-    stays bit-exact with zero non-2xx."""
-    import requests as rq
-
-    from policy_server_tpu.models.policy import parse_policy_entry as ppe
-    from test_server import ServerHandle, make_config, pod_review_body
-
-    policies = {
-        "pod-privileged": ppe(
-            "pod-privileged", {"module": "builtin://pod-privileged"}
-        ),
-    }
-    config = make_config(
-        policies=policies,
-        policy_timeout_seconds=10.0,
-        max_batch_size=4,
-        kernel="pallas",
-    )
-    handle = ServerHandle(config)
-    server = handle.server
-    env0 = server.environment
-    assert env0.kernel == "pallas"
-    # warmup armed the kernel at boot and dispatched through it
-    assert env0.pallas_stats["buckets_armed"] > 0
-    dispatches0 = env0.pallas_stats["dispatches"]
-    assert dispatches0 > 0
-
-    stop = threading.Event()
-    results: list[tuple[int, bool | None, bool]] = []
-    errors: list[Exception] = []
-
-    def traffic(worker: int) -> None:
-        i = 0
-        while not stop.is_set():
-            privileged = (i + worker) % 2 == 0
-            i += 1
-            try:
-                r = rq.post(
-                    handle.url("/validate/pod-privileged"),
-                    json=pod_review_body(privileged), timeout=60,
-                )
-                allowed = (
-                    r.json()["response"]["allowed"]
-                    if r.status_code == 200 else None
-                )
-                results.append((r.status_code, allowed, privileged))
-            except Exception as e:  # noqa: BLE001 — recorded for assert
-                errors.append(e)
-                return
-
-    threads = [
-        threading.Thread(target=traffic, args=(w,), daemon=True)
-        for w in range(2)
-    ]
-    try:
-        for t in threads:
-            t.start()
-        time.sleep(0.3)  # traffic flowing before the flip
-
-        # the SIGHUP contract under load: reload_signal() is exactly
-        # what the registered handler invokes (signal-safe: the reload
-        # runs on a daemon thread); wait for the background promotion
-        epoch_before = server.lifecycle.stats()["epoch"]
-        server.reload_signal()
-        deadline = time.monotonic() + 60
-        while time.monotonic() < deadline:
-            stats = server.lifecycle.stats()
-            if stats["epoch"] > epoch_before and not server.lifecycle.reload_in_flight():
-                break
-            time.sleep(0.1)
-        stats = server.lifecycle.stats()
-        assert stats["epoch"] > epoch_before, stats
-        assert stats["rollbacks"] == 0, stats
-
-        time.sleep(0.4)  # traffic rides the fresh epoch
-        stop.set()
-        for t in threads:
-            t.join(timeout=60)
-
-        assert not errors, f"transport failures across the flip: {errors}"
-        assert len(results) > 10, "traffic generator barely ran"
-        non_2xx = [r for r in results if r[0] != 200]
-        assert not non_2xx, f"non-2xx across the flip: {non_2xx[:5]}"
-        for status, allowed, privileged in results:
-            assert allowed == (not privileged), (status, allowed, privileged)
-
-        # the NEW epoch's environment re-armed the kernel and is
-        # serving through it
-        env1 = server.environment
-        assert env1 is not env0
-        assert env1.kernel == "pallas"
-        assert env1.pallas_stats["buckets_armed"] > 0
-        assert env1.pallas_stats["dispatches"] > 0
-    finally:
-        stop.set()
-        handle.stop()
-
-
 def test_reload_counters_reach_metrics_endpoint():
     """All reload counters + the epoch gauge are operator-visible on the
     Prometheus pull endpoint after real promotions and rejections."""

@@ -19,7 +19,7 @@ BENCH_SHIM = str(Path(__file__).resolve().parent.parent.parent / "bench.py")
 
 # every emitted (metric, value, unit) — re-printed as one compact
 # bench_summary line before the headline so a truncated tail window
-# (BENCH_r04 lost config1-3) still records every number
+# still records every number
 _EMITTED: list[tuple[str, float, str]] = []
 
 
@@ -56,8 +56,8 @@ def emit(metric: str, value: float, unit: str, vs: float, **details) -> None:
 
 
 def emit_summary() -> None:
-    """Compact recap of every line so far: the driver's tail window
-    truncated BENCH_r04 and lost config1-3 — this single line preserves
+    """Compact recap of every line so far: a driver's tail window once
+    truncated a run and lost config1-3 — this single line preserves
     every number even if only the last two lines survive."""
     print(
         json.dumps(
@@ -74,9 +74,8 @@ def emit_summary() -> None:
 
 
 def spread(walls_to_rps: list[float]) -> dict:
-    """median + min/max over N timed passes — the tunneled transport
-    drifts ±40% between identical runs (VERDICT r4 weak #3), so a point
-    value is not defensible against a same-day re-run."""
+    """median + min/max over N timed passes — a point value is not
+    defensible against a same-day re-run."""
     vals = sorted(walls_to_rps)
     return {
         "median": statistics.median(vals),
@@ -163,7 +162,7 @@ def build_rollout_stream(n_requests: int, replicas: int, seed: int):
 
 def profile_delta(after: dict, before: dict) -> dict:
     """Per-row host decomposition between two host_profile snapshots:
-    encode / dedup-bookkeeping / dispatch-wait in µs/row (PROFILE.md r6),
+    encode / dedup-bookkeeping / dispatch-wait in µs/row (round 6),
     plus the columnar wire accounting (round 12). Every number here is
     recoverable from the emitted BENCH JSON alone."""
     d = {k: after.get(k, 0) - before.get(k, 0) for k in after}

@@ -32,13 +32,11 @@ def bench_config4(n_requests: int, batch_size: int) -> None:
 
     env = EvaluationEnvironmentBuilder(backend="jax").build(flagship_policies())
 
-    # dispatch-size sweep: on a remote/tunneled device the per-chunk fetch
-    # round-trip dominates, so bigger chunks amortize it — measure instead
-    # of assuming (compiles happen here, outside the timed run). Transport
-    # throughput drifts run to run (measured ±40% across consecutive
-    # identical runs), so probe every size in TWO interleaved rounds and
-    # keep each size's best — a single ordered pass would systematically
-    # favor whichever size ran last (warmest).
+    # dispatch-size sweep: bigger chunks amortize the per-chunk fetch
+    # round-trip — measure instead of assuming (compiles happen here,
+    # outside the timed run). Probe every size in TWO interleaved rounds
+    # and keep each size's best — a single ordered pass would
+    # systematically favor whichever size ran last (warmest).
     candidates = [
         bs for bs in sorted({batch_size, 2048, 4096})
         if bs <= max(64, len(items))

@@ -27,10 +27,9 @@ Rules:
   sample suffix).
 * **OB06** — dashboard uses a label absent from the instrument's label
   schema (``_EVAL_LABELS``/``_INIT_LABELS``).
-* **OB07** — optimizer/kernel stats-dict drift (round 15): every key of
-  ``EvaluationEnvironment``'s ``OPTIMIZER_STAT_KEYS`` /
-  ``PALLAS_STAT_KEYS`` tuples must map to a metrics.py constant named
-  ``policy_server_predicate_<key>`` / ``policy_server_pallas_<key>``
+* **OB07** — optimizer stats-dict drift (round 15): every key of
+  ``EvaluationEnvironment``'s ``OPTIMIZER_STAT_KEYS`` tuple must map to
+  a metrics.py constant named ``policy_server_predicate_<key>``
   that the server exports — a stats key the observability funnel does
   not carry is invisible work (and OB03/OB04 then anchor the constant
   to a registration and a dashboard panel).
@@ -239,7 +238,7 @@ def _dashboard_exprs(dashboard: dict) -> list[str]:
 
 
 def _stat_key_tuples(environment_path: Path) -> dict[str, tuple[str, ...]]:
-    """OPTIMIZER_STAT_KEYS / PALLAS_STAT_KEYS tuples from
+    """The OPTIMIZER_STAT_KEYS tuple from
     evaluation/environment.py (module-level string-tuple assignments).
     Fixture trees without an environment module simply have no stats
     contract to enforce."""
@@ -252,9 +251,7 @@ def _stat_key_tuples(environment_path: Path) -> dict[str, tuple[str, ...]]:
             isinstance(node, ast.Assign)
             and len(node.targets) == 1
             and isinstance(node.targets[0], ast.Name)
-            and node.targets[0].id in (
-                "OPTIMIZER_STAT_KEYS", "PALLAS_STAT_KEYS"
-            )
+            and node.targets[0].id == "OPTIMIZER_STAT_KEYS"
             and isinstance(node.value, ast.Tuple)
         ):
             out[node.targets[0].id] = tuple(
@@ -351,13 +348,12 @@ def check(
     yields, yfindings = _runtime_yields(spath, consts, server_path)
     findings.extend(yfindings)
 
-    # OB07: every optimizer/kernel stats-dict key maps to a metrics.py
-    # constant (policy_server_predicate_<key> / policy_server_pallas_
-    # <key>) — OB03/OB04 then anchor that constant to a registration and
-    # a dashboard panel, so the whole funnel is transitively total
+    # OB07: every optimizer stats-dict key maps to a metrics.py
+    # constant (policy_server_predicate_<key>) — OB03/OB04 then anchor
+    # that constant to a registration and a dashboard panel, so the
+    # whole funnel is transitively total
     _STAT_PREFIX = {
         "OPTIMIZER_STAT_KEYS": "policy_server_predicate_",
-        "PALLAS_STAT_KEYS": "policy_server_pallas_",
     }
     const_values = set(consts.values())
     for tuple_name, keys in sorted(
@@ -376,7 +372,7 @@ def check(
                         f"stats key '{key}' of {tuple_name} has no "
                         f"metrics.py constant '{family}' — the "
                         "observability funnel does not carry this "
-                        "optimizer/kernel stat",
+                        "optimizer stat",
                     )
                 )
 

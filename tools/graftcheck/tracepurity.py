@@ -39,13 +39,11 @@ from pathlib import Path
 
 from tools.graftcheck.base import Finding, iter_py_files, resolve_callee
 
-# pallas_call (round 15): a Pallas kernel body is traced like any jit
-# root — TP01-04 apply to kernel code the same way
-_JIT_WRAPPERS = {"jit", "pmap", "shard_map", "pallas_call"}
-# probe_mosaic_support: the boot-time Pallas capability probe blocks on
-# its own trivial kernel by design (same exemption rationale as warmup)
+_JIT_WRAPPERS = {"jit", "pmap", "shard_map"}
+# _compile_columns: the off-path plane compiler blocks on the program it
+# just compiled by design (same exemption rationale as warmup)
 _SYNC_CHOKE_POINTS = {
-    "_device_fetch", "_device_call", "warmup", "probe_mosaic_support",
+    "_device_fetch", "_device_call", "warmup", "_compile_columns",
 }
 _BANNED_PREFIXES = (
     "time.time",

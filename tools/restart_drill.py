@@ -226,6 +226,9 @@ class _ServerProc:
         self.log_path = tmp / log_name
         self._log = open(self.log_path, "wb")
         env = dict(os.environ)
+        # a private compile cache by design: the drill measures ITS cold
+        # boot against ITS warm boots, whatever the checkout's cache holds
+        env["JAX_COMPILATION_CACHE_DIR"] = str(state_dir / "xla-cache")
         env.update(extra_env or {})
         self.spawned_at = time.monotonic()
         self.proc = subprocess.Popen(
@@ -234,7 +237,6 @@ class _ServerProc:
                 "--policies", str(policies),
                 "--policies-download-dir", str(download_dir),
                 "--state-dir", str(state_dir),
-                "--compilation-cache-dir", str(state_dir / "xla-cache"),
                 "--addr", "127.0.0.1",
                 "--port", str(self.api_port),
                 "--readiness-probe-port", str(self.ready_port),

@@ -12,13 +12,20 @@ from __future__ import annotations
 
 import argparse
 import os
+import shutil
 import sys
+import tempfile
 
 
 def main(argv: list[str] | None = None) -> int:
     # accelerator-less boxes (CI, dev laptops) soak on the virtual CPU
     # backend; a real TPU host can export JAX_PLATFORMS itself
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # a private, cold compile cache by design (set before jax is
+    # imported): the restart storm compares this run's cold boot with its
+    # own warm reboots, whatever the checkout's cache holds
+    cache_dir = tempfile.mkdtemp(prefix="soak-xla-cache-")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
 
     ap = argparse.ArgumentParser(prog="tools.soak", description=__doc__)
     ap.add_argument("--preset", choices=["smoke", "full", "custom"],
@@ -65,7 +72,10 @@ def main(argv: list[str] | None = None) -> int:
         settings = SoakSettings.full(**over)
     else:
         settings = SoakSettings(**over)
-    return SoakEngine(settings).run()
+    try:
+        return SoakEngine(settings).run()
+    finally:
+        shutil.rmtree(cache_dir, ignore_errors=True)
 
 
 if __name__ == "__main__":

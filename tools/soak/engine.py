@@ -264,14 +264,11 @@ class SoakEngine:
         s = self.settings
         return Config(
             # durable state (round 17): the restart storm's warm boots
-            # ride the state store + the persistent XLA compile cache;
+            # ride the state store + the persistent XLA compile cache
+            # (placed by `python -m tools.soak` for its own process);
             # the spill cadence is shortened so a mid-soak restart
             # resumes a fresh audit inventory
             state_dir=str(state_dir) if state_dir is not None else None,
-            compilation_cache_dir=(
-                str(state_dir / "xla-cache")
-                if state_dir is not None else None
-            ),
             state_audit_spill_seconds=5.0,
             tenants_path=(
                 str(tenants_path) if tenants_path is not None else None
