@@ -5,7 +5,7 @@ Reference parity: src/api/handlers.rs —
 * POST ``/validate_raw/{policy_id}`` → validate_raw_handler (143-174)
 * POST ``/audit/{policy_id}``        → audit_handler (69-90)
 * GET  ``/readiness``                → readiness_handler (176-178)
-* GET  ``/debug/pprof/cpu|heap``     → pprof handlers (193-254)
+* GET  ``/debug/pprof/cpu|heap|trace`` → pprof handlers (193-254)
 * error mapping: PolicyNotFound → 404, everything else → 500
   "Something went wrong" (321-342); malformed JSON body → 422 ApiError
   (JsonExtractor, 30-39).
@@ -509,8 +509,11 @@ async def timeline_handler(request: web.Request) -> web.Response:
     Chrome/Perfetto trace JSON — batch phase tracks, native-frontend
     burst aggregates, sampled-row tracks, plus the current tail
     exemplars and ring accounting under ``otherData``. Load the body in
-    https://ui.perfetto.dev or chrome://tracing. 404 when
-    --flight-recorder off. Served on the readiness port (always the
+    https://ui.perfetto.dev or chrome://tracing. ``?since_ns=&until_ns=``
+    (both optional, CLOCK_MONOTONIC ns, the clock of the events' ``ts``)
+    return only the events that overlap that interval, so that reading
+    a few seconds of a busy server does not render the whole ring. 404
+    when --flight-recorder off. Served on the readiness port (always the
     main process, cluster-internal like /metrics) and on the
     python-frontend API port."""
     from policy_server_tpu.telemetry import flightrec
@@ -518,9 +521,16 @@ async def timeline_handler(request: web.Request) -> web.Response:
     rec = flightrec.recorder()
     if rec is None:
         return api_error(404, "the flight recorder is disabled")
-    # snapshot + JSON render walk the whole ring: off the event loop
+    try:
+        since_ns, until_ns = (
+            int(request.query[key]) if key in request.query else None
+            for key in ("since_ns", "until_ns")
+        )
+    except ValueError:
+        return json_body_error("invalid 'since_ns'/'until_ns' query parameter")
+    # snapshot + JSON render walk the ring: off the event loop
     body = await asyncio.get_running_loop().run_in_executor(
-        None, rec.chrome_trace_json
+        None, rec.chrome_trace_json, since_ns, until_ns
     )
     return web.Response(body=body, content_type="application/json")
 
@@ -566,6 +576,41 @@ async def pprof_heap_handler(request: web.Request) -> web.Response:
     return web.Response(body=body, content_type="application/json")
 
 
+async def pprof_trace_handler(request: web.Request) -> web.Response:
+    """GET /debug/pprof/trace?seconds= : a JAX/XLA trace of this process
+    (device events and the ``ps:launch`` anchors, profiling.py
+    take_device_trace) as an ``.xplane.pb``; single-flight, off the event
+    loop. Its host events carry ``perf_counter_ns``, the clock of
+    ``/debug/timeline``."""
+    try:
+        seconds = float(
+            request.query.get("seconds", profiling.DEFAULT_TRACE_SECONDS)
+        )
+    except ValueError:
+        return json_body_error("invalid 'seconds' query parameter")
+    if not 0 < seconds <= profiling.MAX_TRACE_SECONDS:
+        return json_body_error(
+            f"'seconds' must be over 0 and at most "
+            f"{profiling.MAX_TRACE_SECONDS:g}"
+        )
+    try:
+        body = await asyncio.get_running_loop().run_in_executor(
+            None, profiling.take_device_trace, seconds
+        )
+    except profiling.ProfileInProgress as e:
+        return api_error(409, str(e))
+    except Exception as e:  # noqa: BLE001
+        logger.error("pprof error: %s", e)
+        return something_went_wrong()
+    return web.Response(
+        body=body,
+        content_type="application/octet-stream",
+        headers={
+            "Content-Disposition": 'attachment; filename="trace.xplane.pb"'
+        },
+    )
+
+
 def build_router(state: ApiServerState) -> web.Application:
     """The API application (reference router wiring, src/lib.rs:205-225)."""
     app = web.Application(client_max_size=MAX_BODY_BYTES)
@@ -594,6 +639,7 @@ def build_router(state: ApiServerState) -> web.Application:
     if state.enable_pprof:
         app.router.add_get("/debug/pprof/cpu", pprof_cpu_handler)
         app.router.add_get("/debug/pprof/heap", pprof_heap_handler)
+        app.router.add_get("/debug/pprof/trace", pprof_trace_handler)
     # flight-recorder timeline (round 18): also on the API port for the
     # python frontend (the native frontend serves only the evaluation
     # POSTs; the readiness-port copy below is the always-there surface)
@@ -628,4 +674,8 @@ def build_readiness_router(state: ApiServerState) -> web.Application:
     # one with the batcher/device phases, and the readiness port is
     # always served by the main process — the canonical surface
     app.router.add_get("/debug/timeline", timeline_handler)
+    if state.enable_pprof:
+        # the device trace beside the timeline it lines up with: the
+        # main process holds the chip, under either frontend
+        app.router.add_get("/debug/pprof/trace", pprof_trace_handler)
     return app

@@ -7,6 +7,7 @@ Reference parity: src/profiling.rs —
   optional JAX device trace: TPU "CPU time" lives in XLA, so the device
   trace (jax.profiler, viewable in TensorBoard/Perfetto) is the TPU-native
   equivalent of the sampling profiler.
+  ``take_device_trace`` (GET /debug/pprof/trace) takes one.
 * heap profile (profiling.rs:160-174, jemalloc_pprof): here
   ``tracemalloc`` host snapshot + per-device HBM stats from
   ``jax.Device.memory_stats()`` — the memory that actually matters on TPU.
@@ -17,10 +18,12 @@ from __future__ import annotations
 import collections
 import json
 import sys
+import tempfile
 import threading
 import time
 import tracemalloc
 from dataclasses import dataclass
+from pathlib import Path
 
 import jax
 
@@ -117,10 +120,38 @@ def heap_profile() -> bytes:
     return json.dumps(doc, indent=2).encode()
 
 
-def start_device_trace(log_dir: str) -> None:
-    """Begin a JAX/XLA device trace (TensorBoard/Perfetto format)."""
-    jax.profiler.start_trace(log_dir)
+DEFAULT_TRACE_SECONDS = 3.0
+MAX_TRACE_SECONDS = 60.0
+
+# single-flight like the CPU profile: the profiler holds one session
+_trace_lock = threading.Lock()
 
 
-def stop_device_trace() -> None:
-    jax.profiler.stop_trace()
+def take_device_trace(seconds: float) -> bytes:
+    """A JAX/XLA trace of this process for ``seconds``, as the bytes of
+    its ``.xplane.pb`` (TensorBoard's profile plugin, or
+    ``jax.profiler.ProfileData.from_serialized_xspace``). Device events
+    plus the host's TraceMe events and no Python tracer (tens of MB a
+    second, and it slows the host being looked at): the benchmark
+    launcher's settings. Each launch of the fused program is in it as a
+    ``ps:launch`` host event carrying its batch id and a
+    ``perf_counter_ns`` reading, which lines the trace up with
+    ``/debug/timeline``. Single-flight."""
+    if not _trace_lock.acquire(blocking=False):
+        raise ProfileInProgress("a device trace is already being taken")
+    try:
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 1
+        with tempfile.TemporaryDirectory(prefix="policy-server-trace-") as d:
+            jax.profiler.start_trace(d, profiler_options=options)
+            try:
+                time.sleep(seconds)
+            finally:
+                jax.profiler.stop_trace()
+            found = sorted(Path(d).glob("**/*.xplane.pb"))
+            if not found:
+                raise RuntimeError("the profiler wrote no .xplane.pb")
+            return found[-1].read_bytes()
+    finally:
+        _trace_lock.release()

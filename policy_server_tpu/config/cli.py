@@ -110,7 +110,16 @@ def _flag_specs() -> list[tuple[str, str | None, dict[str, Any]]]:
         ("--enable-metrics", "KUBEWARDEN_ENABLE_METRICS",
          dict(action="store_true", help="Enable OTLP metrics")),
         ("--enable-pprof", "KUBEWARDEN_ENABLE_PPROF",
-         dict(action="store_true", help="Enable profiling endpoints")),
+         dict(action="store_true",
+              help="Enable profiling endpoints: GET /debug/pprof/cpu and "
+                   "/debug/pprof/heap on the python-frontend API port, and "
+                   "GET /debug/pprof/trace?seconds=N (default 3, at most "
+                   "60) there and on the readiness port: a jax.profiler "
+                   "trace of the serving process as an .xplane.pb, one at "
+                   "a time. Each launch of the fused program is in it as "
+                   "a 'ps:launch' host event carrying its flight-recorder "
+                   "batch id and a perf_counter_ns reading, the clock of "
+                   "/debug/timeline, so the two line up")),
         ("--log-level", "KUBEWARDEN_LOG_LEVEL",
          dict(default="info", metavar="LOG_LEVEL",
               choices=["trace", "debug", "info", "warn", "error"],
@@ -450,11 +459,14 @@ def _flag_specs() -> list[tuple[str, str | None, dict[str, Any]]]:
                    "(stamped in the C++ frontend and carried across the "
                    "SPSC ring), batcher admission/queue-wait/formation, "
                    "encode, dispatch, device execute, fetch, deliver, "
-                   "native verdict serialize — at <2% overhead (one "
+                   "native verdict serialize, and the CPython collector's "
+                   "full passes — at <2% overhead (one "
                    "clock read per phase boundary per BATCH; per-row "
                    "events only on sampled rows). Read surfaces: GET "
                    "/debug/timeline (Chrome/Perfetto trace JSON, on the "
-                   "readiness port and the python-frontend API port), "
+                   "readiness port and the python-frontend API port; "
+                   "?since_ns=&until_ns= on CLOCK_MONOTONIC keep only "
+                   "the events that overlap that interval), "
                    "per-phase latency histograms + tail exemplars on "
                    "/metrics and OTLP, and the phase-attribution report "
                    "(make phase-report). 'off' disables the recorder "

@@ -27,6 +27,7 @@ counted (SURVEY.md §7.4 escape hatch).
 from __future__ import annotations
 
 import base64
+import functools
 import json
 import threading
 import time
@@ -143,6 +144,27 @@ class fragment_responses:
 
 def _fragments_enabled() -> bool:
     return getattr(_frag_scope, "on", False)
+
+
+# The names XLA knows the fused programs by (``jit_<name>``, which a
+# device trace shows as ``jit__forward(...)`` / ``jit__forward_planes(...)``).
+# They are an interface: the benchmark finds the programs' device time by
+# the patterns of benchmarks/layer_metrics/predicate_roofline.json, so they
+# are fixed here, not taken from whatever the methods are called.
+FUSED_PROGRAM_NAME = "_forward"
+FUSED_PLANES_PROGRAM_NAME = "_forward_planes"
+
+
+def _named_program(fn: Callable, name: str) -> Callable:
+    """``fn`` under a fixed ``__name__``: what ``jax.jit`` names the
+    program it compiles from it."""
+
+    @functools.wraps(fn)
+    def program(*args: Any) -> Any:
+        return fn(*args)
+
+    program.__name__ = program.__qualname__ = name
+    return program
 
 
 def _silence_donation_decline_warning() -> None:
@@ -664,7 +686,9 @@ class EvaluationEnvironment:
                 for bp in g.members.values()
             )
         }
-        self._fused = jax.jit(self._forward)
+        self._fused = jax.jit(
+            _named_program(self._forward, FUSED_PROGRAM_NAME)
+        )
         # Columnar serving transport (round 12, ROADMAP item 3): the wide
         # packed batch splits into bit-packed / uint16 / int32 PLANES and
         # only all-nonzero ("delta") columns ship — all-zero planes and
@@ -679,11 +703,7 @@ class EvaluationEnvironment:
         self.donate_buffers = bool(donate_buffers)
         if self.donate_buffers and self.columnar:
             _silence_donation_decline_warning()
-        self._fused_planes = jax.jit(
-            self._forward_planes,
-            static_argnums=(0,),
-            donate_argnums=(1,) if self.donate_buffers else (),
-        )
+        self._fused_planes = self._jit_planes()
         # (spec, structure, shapes) combos whose program is compiled —
         # the serving path dispatches only these (see _plane_dispatch);
         # also sizes the resident zero-constant accounting (the first
@@ -750,6 +770,7 @@ class EvaluationEnvironment:
         self._profile_lock = threading.Lock()
         self._host_profile: dict[str, int] = {  # guarded-by: _profile_lock
             "encode_ns": 0,          # _payload_blob + native encode_batch
+            "encode_cpu_ns": 0,      # the encoding thread's CPU time of it
             "encode_rows": 0,        # rows that went through the encoder
             "bookkeeping_ns": 0,     # dedup tiers + slot/LRU bookkeeping
             "bookkeeping_rows": 0,
@@ -902,12 +923,21 @@ class EvaluationEnvironment:
                 out_specs=(data_spec, data_spec),
                 check_vma=False,
             )
-        self._fused = mesh_mod.jit_data_parallel(self._forward, mesh)
+        self._fused = mesh_mod.jit_data_parallel(
+            _named_program(self._forward, FUSED_PROGRAM_NAME), mesh
+        )
         # rebuild the columnar root: its traces must capture the mesh
         # (plane reconstruction places resident zero constants with the
         # mesh's NamedSharding)
-        self._fused_planes = jax.jit(
-            self._forward_planes,
+        self._fused_planes = self._jit_planes()
+
+    def _jit_planes(self) -> Callable:
+        """The columnar jit root (rebuilt when a mesh attaches: its traces
+        must capture the mesh)."""
+        return jax.jit(
+            _named_program(
+                self._forward_planes, FUSED_PLANES_PROGRAM_NAME
+            ),
             static_argnums=(0,),
             donate_argnums=(1,) if self.donate_buffers else (),
         )
@@ -2003,7 +2033,9 @@ class EvaluationEnvironment:
             delta = mesh_mod.shard_delta_planes(delta, self._mesh)
         return self._fused_planes(spec, delta)
 
-    def _plane_dispatch(self, schema_idx: int, features: Mapping[str, Any]) -> Any:
+    def _plane_dispatch(
+        self, schema_idx: int, features: Mapping[str, Any], rows: int = 0
+    ) -> Any:
         """Columnar device dispatch: select the planes to ship, account
         wire bytes / delta columns / donation, and launch the donated
         columnar program (async — caller fetches through _device_fetch).
@@ -2064,7 +2096,7 @@ class EvaluationEnvironment:
             hp["delta_cols_total"] += layout.total32
             if self.donate_buffers:
                 hp["donated_dispatches"] += 1
-        return self._device_call(self._launch_planes, spec, delta)
+        return self._device_call(self._launch_planes, spec, delta, rows=rows)
 
     def _compile_columns_async(
         self, schema_idx: int, narrow: bool, version: int
@@ -2137,7 +2169,9 @@ class EvaluationEnvironment:
             with self._profile_lock:
                 self._plane_jobs_pending -= 1
 
-    def _dispatch_features(self, features: Mapping[str, Any]) -> Any:
+    def _dispatch_features(
+        self, features: Mapping[str, Any], rows: int = 0
+    ) -> Any:
         """The one device-dispatch funnel for full batches: columnar when
         enabled and the features are a wide packed buffer — including
         mesh-sharded programs (round 14: delta planes ship batch-sharded,
@@ -2148,21 +2182,34 @@ class EvaluationEnvironment:
         schema_idx = self._schema_index_for(features)
         if self.columnar and self._columnar_mesh_ok():
             if schema_idx is not None:
-                return self._plane_dispatch(schema_idx, features)
+                return self._plane_dispatch(schema_idx, features, rows)
         features = self._transport(features)
         if self._mesh is not None:
             from policy_server_tpu.parallel import mesh as mesh_mod
 
             features = mesh_mod.shard_features(features, self._mesh)
-        return self._device_call(self._fused, features)
+        return self._device_call(self._fused, features, rows=rows)
 
-    def _device_call(self, fn: Callable, *args: Any) -> Any:
+    def _device_call(self, fn: Callable, *args: Any, rows: int = 0) -> Any:
         """Run a synchronous device-path call (the jit dispatch itself),
         feeding dispatch-time raises — driver errors, RESOURCE_EXHAUSTED
         thrown at the call rather than at fetch — to the breaker before
-        re-raising. Fetch-time raises feed it in _device_fetch."""
+        re-raising. Fetch-time raises feed it in _device_fetch.
+
+        The call is held under the package's one profiler annotation,
+        ``ps:launch`` (flightrec.LAUNCH_ANNOTATION): with a jax.profiler
+        trace running it puts this thread's ambient batch id, the rows
+        shipped and a ``perf_counter_ns`` reading into the trace, which
+        is all a reader needs to put the flight recorder's ring on the
+        device trace's clock and to give each execution of the fused
+        program its batch. Without a trace it is one inactive TraceMe."""
         try:
-            return fn(*args)
+            with jax.profiler.TraceAnnotation(
+                flightrec.LAUNCH_ANNOTATION,
+                batch=flightrec.current_batch(), rows=rows,
+                perf_counter_ns=time.perf_counter_ns(),
+            ):
+                return fn(*args)
         except Exception:
             if self.breaker is not None:
                 self.breaker.record_failure()
@@ -2981,7 +3028,11 @@ class EvaluationEnvironment:
 
         def encode(chunk: list[int]):
             failpoints.fire("encode.batch")
+            # the CPU clock is read inside the wall clock's interval, so
+            # encode_cpu_ns never exceeds encode_ns; the difference is
+            # time this thread was off a core (GIL wait, descheduled)
             t0 = time.perf_counter_ns()
+            c0 = time.thread_time_ns()
             if blobs is None:
                 bl = [
                     self._payload_blob(targets[i], items[i][1]) for i in chunk
@@ -2991,8 +3042,12 @@ class EvaluationEnvironment:
             out = schema.native.encode_batch(
                 bl, self.bucket_for(len(bl)), self.table
             )
+            c1 = time.thread_time_ns()
             t1 = time.perf_counter_ns()
-            self._profile_add(encode_ns=t1 - t0, encode_rows=len(chunk))
+            self._profile_add(
+                encode_ns=t1 - t0, encode_cpu_ns=c1 - c0,
+                encode_rows=len(chunk),
+            )
             if _rec is not None:
                 _rec.record_phase(
                     flightrec.PH_ENCODE, t0, t1, rows=len(chunk),
@@ -3351,7 +3406,19 @@ class EvaluationEnvironment:
             stash = self._add_wasm_bits(
                 features, features[PACKED_KEY].shape[0], wasm_rows
             )
-            dev_out = self._dispatch_features(features)  # async dispatch
+            t_launch = time.perf_counter_ns() if _rec is not None else 0
+            dev_out = self._dispatch_features(  # async dispatch
+                features, rows=n_dispatched
+            )
+            if _rec is not None:
+                # plane selection, the jit call's host-to-device copies
+                # and the enqueue: on the chip, milliseconds a batch
+                # between encode's end and the program's start, and with
+                # encode most of the device's idle time (PERF.md, PR 27)
+                _rec.record_phase(
+                    flightrec.PH_LAUNCH, t_launch, time.perf_counter_ns(),
+                    rows=n_dispatched, batch=_bid,
+                )
             self._profile_add(
                 dispatched_rows=n_dispatched, dispatched_chunks=1
             )

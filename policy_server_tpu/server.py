@@ -893,6 +893,13 @@ class PolicyServer:
                 profile.get("encode_ns", 0) / 1e9,
             )
             yield (
+                metrics_names.HOST_ENCODE_CPU_SECONDS, "counter",
+                "CPU time of the encoding thread over the same intervals "
+                "(wall minus this: off a core, waiting for the GIL or "
+                "descheduled)",
+                profile.get("encode_cpu_ns", 0) / 1e9,
+            )
+            yield (
                 metrics_names.HOST_ENCODE_ROWS, "counter",
                 "Rows through the native encoder (blob-tier hits skip it)",
                 profile.get("encode_rows", 0),
@@ -1794,6 +1801,25 @@ class PolicyServer:
                 "(--recorder-row-sample-rate stride)",
                 frec.rows_sampled() if frec is not None else 0,
             )
+            gc_stats = (
+                frec.gc_stats() if frec is not None
+                else {"pause_ns": [0] * _frec.GC_GENERATIONS,
+                      "passes": [0] * _frec.GC_GENERATIONS}
+            )
+            yield (
+                metrics_names.GC_PAUSE_SECONDS, "counter",
+                "Time the CPython collector's passes held the "
+                "interpreter, by generation (flight recorder gc hook)",
+                [((str(g),), ns / 1e9)
+                 for g, ns in enumerate(gc_stats["pause_ns"])],
+                ("generation",),
+            )
+            yield (
+                metrics_names.GC_PASSES, "counter",
+                "Passes of the CPython collector, by generation",
+                [((str(g),), n) for g, n in enumerate(gc_stats["passes"])],
+                ("generation",),
+            )
             yield (
                 metrics_names.TAIL_EXEMPLAR_LATENCY_SECONDS, "gauge",
                 "Tail exemplars: the slowest rows of the current "
@@ -2061,8 +2087,9 @@ class PolicyServer:
         if self.config.enable_pprof:
             logger.warning(
                 "--enable-pprof with --frontend native: the native "
-                "frontend serves only the evaluation POST surface; hit "
-                "the pprof endpoints with --frontend python"
+                "frontend serves only the evaluation POST surface; "
+                "/debug/pprof/trace is on the readiness port, the cpu and "
+                "heap endpoints need --frontend python"
             )
         logger.info(
             "native HTTP frontend started",

@@ -31,6 +31,18 @@ guesswork. This module is the instrument that measures it:
   RESIDUAL (unattributed µs/row) becomes a first-class, regression-
   gated number (tools/bench/phasereport.py, ``make phase-report``,
   ``BENCH_phase_attribution.json``).
+* the ring shares a clock with the device trace through ONE profiler
+  annotation, ``ps:launch`` (``LAUNCH_ANNOTATION``, held by
+  evaluation/environment.py ``_device_call`` around each launch of the
+  fused program): it carries the batch id and a ``perf_counter_ns``
+  reading into the ``.xplane.pb``, so a reader (benchmarks/host_spans.py)
+  maps every ring interval onto the trace's clock and lays each idle gap
+  of the device to the host phase that filled it. Everything else stays
+  a ring interval: no second span system.
+* the collector is a phase too (``gc``): a ``gc.callbacks`` hook, installed
+  with the recorder, stamps every generation-2 pass and any pass over
+  1 ms with ``batch=-1`` and counts every pass and its pause per
+  generation (``gc_stats``).
 
 Overhead contract: ≤2% on the batcher serving path (A/B recorded on the
 ``batcher_serving_path`` bench line and unit-tested in
@@ -46,6 +58,7 @@ package, and every histogram family has a dashboard panel.
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import threading
@@ -70,12 +83,22 @@ PH_HANDOFF = "handoff"                    # pool pickup + GIL wake latency
 PH_PREPARE = "prepare"                    # target resolution + payload blobs
 PH_ENCODE = "encode"                      # native batch encode
 PH_BLOB_DEDUP = "blob_dedup"              # pre-encode blob-tier dedup pass
+PH_LAUNCH = "launch"                      # plane selection + H2D + program enqueue
 PH_DEVICE_EXECUTE = "device_execute"      # device_get on the drain pool
 PH_FETCH = "fetch"                        # materialize blocked on the drain future
 PH_MATERIALIZE = "materialize"            # outputs → AdmissionResponse rows
 PH_BOOKKEEPING = "bookkeeping"            # row dedup tiers + slot/LRU bookkeeping
 PH_DELIVER = "deliver"                    # phase-3 post-process + completion fan-out
 PH_NATIVE_SERIALIZE = "native_serialize"  # verdict bulk fill to the native frontend
+PH_GC = "gc"                              # one collector pass (GIL held; batch -1)
+
+# the one profiler annotation of the package: the anchor that ties the
+# ring's clock and batch ids to a jax.profiler trace (module docstring)
+LAUNCH_ANNOTATION = "ps:launch"
+# collector passes the ring keeps: every full (generation 2) pass, and a
+# younger one only when it held the interpreter this long
+GC_STAMP_MIN_NS = 1_000_000
+GC_GENERATIONS = 3
 
 PHASES = (
     PH_NATIVE_ACCEPT,
@@ -89,12 +112,14 @@ PHASES = (
     PH_PREPARE,
     PH_ENCODE,
     PH_BLOB_DEDUP,
+    PH_LAUNCH,
     PH_DEVICE_EXECUTE,
     PH_FETCH,
     PH_MATERIALIZE,
     PH_BOOKKEEPING,
     PH_DELIVER,
     PH_NATIVE_SERIALIZE,
+    PH_GC,
 )
 
 _PHASE_INDEX = {name: i for i, name in enumerate(PHASES)}
@@ -105,7 +130,7 @@ _PHASE_INDEX = {name: i for i, name in enumerate(PHASES)}
 # it runs on a drain-pool thread UNDER the fetch wait, so counting both
 # would double-attribute the device wall.
 _DISPATCH_NESTED = (
-    PH_HANDOFF, PH_PREPARE, PH_ENCODE, PH_BLOB_DEDUP, PH_FETCH,
+    PH_HANDOFF, PH_PREPARE, PH_ENCODE, PH_BLOB_DEDUP, PH_LAUNCH, PH_FETCH,
     PH_MATERIALIZE, PH_BOOKKEEPING,
 )
 
@@ -197,6 +222,11 @@ class FlightRecorder:
         # retained exemplar skip the lock entirely (stale reads are
         # benign — at worst one extra lock acquisition)
         self._ex_floor = 0.0  # graftcheck: lockfree — monotone hint, exact value re-checked under _ex_lock
+        # -- collector hook (on_gc): written only inside gc callbacks,
+        # which the interpreter runs one at a time with the GIL held
+        self._gc_began = 0
+        self._gc_pause_ns = [0] * GC_GENERATIONS
+        self._gc_passes = [0] * GC_GENERATIONS
 
     # -- write path --------------------------------------------------------
 
@@ -235,6 +265,32 @@ class FlightRecorder:
             0, _KIND_MIX, int(hit_rows), 0, int(total_rows), int(batch),
             None,
         )
+
+    def on_gc(self, phase: str, info: dict) -> None:
+        """The ``gc.callbacks`` hook (:func:`install` adds and removes
+        it). It runs around EVERY collection, generation 0 included, so
+        it does one clock read and two adds, and only a full pass or one
+        over ``GC_STAMP_MIN_NS`` goes on to write a ring interval."""
+        now = time.perf_counter_ns()
+        if phase == "start":
+            self._gc_began = now
+            return
+        began, gen = self._gc_began, info["generation"]
+        if not began:
+            return  # installed mid-pass: no start stamp to pair with
+        self._gc_began = 0
+        self._gc_passes[gen] += 1
+        self._gc_pause_ns[gen] += now - began
+        if gen == GC_GENERATIONS - 1 or now - began >= GC_STAMP_MIN_NS:
+            self.record_phase(PH_GC, began, now, rows=0, batch=-1)
+
+    def gc_stats(self) -> dict[str, list[int]]:
+        """Collector passes seen since install and the time each
+        generation's passes held the interpreter, by generation."""
+        return {
+            "passes": list(self._gc_passes),
+            "pause_ns": list(self._gc_pause_ns),
+        }
 
     def _write(
         self, phase_i: int, kind: int, start_ns: int, end_ns: int,
@@ -391,10 +447,14 @@ class FlightRecorder:
     def rows_sampled(self) -> int:
         return self._rows_sampled_n
 
-    def snapshot(self) -> list[dict]:
+    def snapshot(
+        self, since_ns: int | None = None, until_ns: int | None = None
+    ) -> list[dict]:
         """Consistent copy of the ring's live events, oldest first. Slots
         overwritten while copying are dropped (seq re-check), never
-        misread."""
+        misread. ``since_ns`` / ``until_ns`` (CLOCK_MONOTONIC ns) keep
+        only the intervals that overlap ``[since_ns, until_ns]``, chosen
+        on the arrays before any event is rendered."""
         seq1 = self._seq.copy()
         start = self._start.copy()
         end = self._end.copy()
@@ -405,6 +465,10 @@ class FlightRecorder:
         uids = list(self._uids)
         seq2 = self._seq.copy()
         valid = (seq1 >= 0) & (seq1 == seq2)
+        if since_ns is not None:
+            valid &= end >= since_ns
+        if until_ns is not None:
+            valid &= start <= until_ns
         order = np.argsort(seq1[valid], kind="stable")
         idx = np.nonzero(valid)[0][order]
         return [
@@ -463,10 +527,13 @@ class FlightRecorder:
 
     # -- Chrome/Perfetto trace export --------------------------------------
 
-    def chrome_trace(self) -> dict:
-        """The ring as a Chrome trace JSON object (load it in Perfetto or
-        chrome://tracing). Batch events land on pid 1 with one track per
-        in-flight batch lane (environment phases share their batch's
+    def chrome_trace(
+        self, since_ns: int | None = None, until_ns: int | None = None
+    ) -> dict:
+        """The ring (or the part of it that overlaps ``[since_ns,
+        until_ns]``, see :meth:`snapshot`) as a Chrome trace JSON object
+        (load it in Perfetto or chrome://tracing). Batch events land on
+        pid 1 with one track per in-flight batch lane (environment phases share their batch's
         track, so encode/fetch nest visually under the dispatch slice);
         native burst events get their own track; sampled rows land on
         pid 2, one track per hash lane."""
@@ -474,7 +541,7 @@ class FlightRecorder:
         names = {
             (1, 0): "native frontend (burst aggregates)",
         }
-        for ev in self.snapshot():
+        for ev in self.snapshot(since_ns, until_ns):
             if ev["kind"] == "mix":
                 continue  # bookkeeping marker, not a timeline interval
             if ev["kind"] == "batch":
@@ -530,8 +597,10 @@ class FlightRecorder:
             "exemplars": self.exemplars(),
         }
 
-    def chrome_trace_json(self) -> bytes:
-        return json.dumps(self.chrome_trace()).encode()
+    def chrome_trace_json(
+        self, since_ns: int | None = None, until_ns: int | None = None
+    ) -> bytes:
+        return json.dumps(self.chrome_trace(since_ns, until_ns)).encode()
 
     # -- phase attribution -------------------------------------------------
 
@@ -659,10 +728,15 @@ _scope = threading.local()
 
 
 def install(rec: FlightRecorder | None) -> FlightRecorder | None:
-    """Install (or clear, with None) the process-wide recorder. Called by
-    the server bootstrap; tests install their own and clear after."""
+    """Install (or clear, with None) the process-wide recorder, and with
+    it the collector hook (``FlightRecorder.on_gc``). Called by the
+    server bootstrap; tests install their own and clear after."""
     global _recorder
+    if _recorder is not None and _recorder.on_gc in gc.callbacks:
+        gc.callbacks.remove(_recorder.on_gc)
     _recorder = rec
+    if rec is not None:
+        gc.callbacks.append(rec.on_gc)
     return rec
 
 

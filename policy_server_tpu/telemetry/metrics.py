@@ -27,6 +27,8 @@ import threading
 from dataclasses import dataclass
 from typing import Any, Mapping
 
+from policy_server_tpu.telemetry.flightrec import PH_GC
+
 try:  # baked into the environment, but keep the import soft for vendoring
     import prometheus_client
     from prometheus_client import CollectorRegistry
@@ -105,6 +107,10 @@ NATIVE_INFLIGHT = "policy_server_native_inflight_requests"
 QUEUE_WAIT_SECONDS = "policy_server_queue_wait_seconds_total"
 HOST_ENCODE_SECONDS = "policy_server_host_encode_seconds_total"
 HOST_ENCODE_ROWS = "policy_server_host_encode_rows_total"
+# CPU time of the encoding thread over the same intervals as
+# HOST_ENCODE_SECONDS (wall): the difference is time that thread was off
+# a core, waiting for the GIL or descheduled
+HOST_ENCODE_CPU_SECONDS = "policy_server_host_encode_cpu_seconds_total"
 HOST_BOOKKEEPING_SECONDS = "policy_server_host_bookkeeping_seconds_total"
 DISPATCH_WAIT_SECONDS = "policy_server_dispatch_wait_seconds_total"
 DISPATCHED_ROWS = "policy_server_dispatched_rows_total"
@@ -204,6 +210,11 @@ PHASE_LATENCY_SECONDS = "policy_server_phase_latency_seconds"
 TAIL_EXEMPLAR_LATENCY_SECONDS = "policy_server_tail_exemplar_latency_seconds"
 FLIGHT_RECORDER_EVENTS = "policy_server_flight_recorder_events"
 FLIGHT_RECORDER_ROWS_SAMPLED = "policy_server_flight_recorder_rows_sampled"
+# the recorder's collector hook (flightrec.FlightRecorder.on_gc): passes
+# of the CPython collector and the time they held the interpreter, by
+# generation; the full passes are also ``gc`` intervals of the ring
+GC_PAUSE_SECONDS = "policy_server_gc_pause_seconds_total"
+GC_PASSES = "policy_server_gc_passes_total"
 # round 20 — native TLS termination (csrc/httpfront.cpp memory-BIO
 # handshakes + runtime/native_frontend.NativeTlsManager + certs.py
 # last-good identity machinery): cert-expiry horizon, handshake
@@ -484,7 +495,14 @@ class MetricsRegistry:
             )
             # phase-name cardinality is the closed flightrec.PHASES set;
             # children cache like _prom_children (GIL-atomic dict ops)
-            self._phase_children: dict[str, Any] = {}  # graftcheck: lockfree — GIL-atomic dict ops; racing builders store identical children
+            self._phase_children: dict[str, Any] = {  # graftcheck: lockfree — GIL-atomic dict ops; racing builders store identical children
+                # the collector's child exists from the start: its
+                # observations come from inside a gc callback, which can
+                # fire while this thread holds the histogram's own lock
+                # (labels(), a scrape's copy), where a first labels()
+                # call would wait for that lock forever
+                PH_GC: self._prom_phase.labels(phase=PH_GC),
+            }
         else:  # pragma: no cover
             self.registry = None
 

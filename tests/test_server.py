@@ -297,6 +297,88 @@ def test_debug_timeline_serves_live_trace(server):
     assert "policy_server_tail_exemplar_latency_seconds" in m
 
 
+def test_debug_timeline_returns_only_the_interval_asked_for(server):
+    """?since_ns=&until_ns= (PR 27): only events that overlap the
+    interval, on the clock of the events' own ``ts``."""
+    doc = build_admission_review_dict()
+    requests.post(server.url("/validate/pod-privileged"), json=doc, timeout=10)
+    t_mid = time.perf_counter_ns()
+    time.sleep(0.01)
+    requests.post(server.url("/validate/pod-privileged"), json=doc, timeout=10)
+    url = server.readiness_url("/debug/timeline")
+
+    def slices(query: str) -> list:
+        r = requests.get(url + query, timeout=10)
+        assert r.status_code == 200
+        return [e for e in r.json()["traceEvents"] if e["ph"] == "X"]
+
+    everything = slices("")
+    late = slices(f"?since_ns={t_mid}")
+    early = slices(f"?until_ns={t_mid}")
+    assert late and early
+    assert len(late) < len(everything) and len(early) < len(everything)
+    assert all((e["ts"] + e["dur"]) * 1e3 >= t_mid - 1 for e in late)
+    assert all(e["ts"] * 1e3 <= t_mid + 1 for e in early)
+    assert slices(f"?since_ns={t_mid}&until_ns={t_mid - 10**9}") == []
+    assert "batch" in late[0]["args"]
+    r = requests.get(url + "?since_ns=yesterday", timeout=10)
+    assert r.status_code == 422
+
+
+def test_collector_and_encode_cpu_families_ride_metrics(server):
+    """PR 27: the recorder's gc hook and encode's CPU clock on /metrics."""
+    import gc
+
+    def families() -> dict:
+        text = requests.get(server.readiness_url("/metrics"), timeout=10).text
+        return {
+            line.rsplit(" ", 1)[0]: float(line.rsplit(" ", 1)[1])
+            for line in text.splitlines()
+            if line.startswith(("policy_server_gc_", "policy_server_host_encode_"))
+        }
+
+    before = families()
+    gc.collect()
+    after = families()
+    for g in "012":
+        assert f'policy_server_gc_passes_total{{generation="{g}"}}' in after
+        assert f'policy_server_gc_pause_seconds_total{{generation="{g}"}}' in after
+    full = '{generation="2"}'
+    assert after["policy_server_gc_passes_total" + full] >= \
+        before["policy_server_gc_passes_total" + full] + 1
+    assert after["policy_server_gc_pause_seconds_total" + full] > \
+        before["policy_server_gc_pause_seconds_total" + full]
+    assert after["policy_server_host_encode_cpu_seconds_total"] <= \
+        after["policy_server_host_encode_seconds_total"] + 1e-6
+
+
+def test_pprof_trace_returns_an_xplane(server):
+    """GET /debug/pprof/trace (PR 27): the process's jax.profiler trace as
+    an .xplane.pb, on the API port and, beside /debug/timeline, on the
+    readiness port; single-flight; seconds bounded."""
+    from jax.profiler import ProfileData
+
+    for url in (server.url("/debug/pprof/trace"),
+                server.readiness_url("/debug/pprof/trace")):
+        r = requests.get(url + "?seconds=0.2", timeout=60)
+        assert r.status_code == 200
+        planes = [p.name for p in
+                  ProfileData.from_serialized_xspace(r.content).planes]
+        assert any(name.startswith("/host:") for name in planes), planes
+    for bad in ("?seconds=0", "?seconds=3600", "?seconds=soon"):
+        r = requests.get(server.url("/debug/pprof/trace") + bad, timeout=10)
+        assert r.status_code == 422, bad
+    from policy_server_tpu.api import profiling
+
+    assert profiling._trace_lock.acquire(blocking=False)
+    try:  # a trace is being taken: the next caller is told so, at once
+        r = requests.get(server.url("/debug/pprof/trace?seconds=0.2"),
+                         timeout=10)
+        assert r.status_code == 409
+    finally:
+        profiling._trace_lock.release()
+
+
 def test_pprof_endpoints(server):
     r = requests.get(server.url("/debug/pprof/cpu?interval=0.05"), timeout=30)
     assert r.status_code == 200 and len(r.content) > 0
