@@ -172,8 +172,10 @@ def _flag_specs() -> list[tuple[str, str | None, dict[str, Any]]]:
                    "PLANES with all-zero columns elided (steady-state "
                    "traffic ships only delta columns; elided planes are "
                    "reconstructed from device-resident zero constants). "
-                   "'off' restores the row-packed transport. Mesh-sharded "
-                   "programs always use the packed transport")),
+                   "A launch ships one packed wire buffer; the column "
+                   "indices stay on the device. 'off' restores the "
+                   "row-packed transport. Multi-process meshes always "
+                   "use the packed transport")),
         ("--donate-buffers", "KUBEWARDEN_DONATE_BUFFERS",
          dict(default="on", metavar="MODE", choices=["on", "off"],
               help="Donate columnar input buffers on dispatch "

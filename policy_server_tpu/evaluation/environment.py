@@ -29,6 +29,7 @@ from __future__ import annotations
 import base64
 import functools
 import json
+import sys
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -228,6 +229,134 @@ class _RowView:
         return default if arr is None else arr[self._row]
 
 
+# The key of the one wire buffer in a columnar launch's shipped dict.
+WIRE_KEY = "wire"
+# The wire planes in the order their regions follow each other in a wire
+# row, with the bytes a column takes there (the bool lanes pack 8 to a
+# byte): 4-byte columns first, then 2-byte ones, so both stay aligned.
+_WIRE_PLANES = (("i32", 4), ("ids", 2), ("bits", 0))
+# Which uint16 of an int32 holds its low half on this host.
+_LOW_HALF = 0 if sys.byteorder == "little" else 1
+_NO_COLUMNS = np.zeros(0, np.intp)
+
+
+def _wire_regions(shape: tuple) -> tuple:
+    """The byte spans ``(lo, hi)`` of the int32, the uint16 and the bit
+    region of a wire row of this shape, and the row's width (a multiple
+    of 4). Host and device slice a row by these and nothing else."""
+    spans, at = [], 0
+    for (k, _scattered), (_name, size) in zip(shape, _WIRE_PLANES):
+        hi = at + (k * size if size else (k + 7) // 8)
+        spans.append((at, hi))
+        at = hi
+    return (*spans, (at + 3) // 4 * 4)
+
+
+class _WireForm:
+    """One structure of a columnar launch: which columns of each plane
+    ride in the wire buffer. ``shape`` is its static identity, one
+    ``(columns shipped, scattered)`` pair per plane of _WIRE_PLANES — 0
+    columns for an elided plane, ``scattered`` False for a plane shipped
+    whole — and fixes the wire row's width and the index vectors' shapes,
+    so it keys the compiled program. ``take`` gathers every shipped
+    byte straight out of the encoder's wide row (composed once, here: a
+    launch pays ONE gather for all three planes, and every numpy call it
+    saves is a hand-off of the GIL it saves); ``cols`` are the positions
+    the columns scatter to on the device, placed there once
+    (``resident``, filled at the first launch) and not shipped with
+    every batch."""
+
+    __slots__ = ("shape", "take", "cols", "regions", "resident")
+
+    def __init__(
+        self, layout: "_WireLayout", cols: Mapping[str, np.ndarray | None]
+    ) -> None:
+        shape = []
+        first: dict[str, np.ndarray] = {}
+        self.cols: dict[str, np.ndarray] = {}
+        for name, _size in _WIRE_PLANES:
+            source = layout.source.get(name)
+            if source is None or name not in cols:
+                shape.append((0, False))
+                first[name] = _NO_COLUMNS
+                continue
+            picked = cols[name]
+            if picked is None:
+                first[name] = source
+            else:
+                first[name] = source[picked]
+                self.cols[name] = picked.astype(np.int32)
+            shape.append((int(first[name].size), picked is not None))
+        self.shape = tuple(shape)
+        self.regions = _wire_regions(self.shape)
+        # the wide row's byte of every byte of the int32 region and of
+        # the uint16 region (an id ships its low half; the high one is
+        # zero while the vocabulary fits), then of every shipped lane
+        self.take = np.concatenate([
+            (first["i32"][:, None] + np.arange(4)).ravel(),
+            (first["ids"][:, None] + np.arange(2) + 2 * _LOW_HALF).ravel(),
+            first["bits"],
+        ])
+        self.resident: Any = None
+
+    @property
+    def width(self) -> int:
+        return self.regions[-1]
+
+    def wire(self, buf: np.ndarray) -> np.ndarray:
+        """The wire buffer of one wide packed batch: one C-contiguous
+        ``uint8[batch, width]`` array."""
+        _r32, _r16, (lo8, hi8), width = self.regions
+        picked = np.take(buf, self.take, axis=1)
+        wire = np.zeros((buf.shape[0], width), np.uint8)
+        wire[:, :lo8] = picked[:, :lo8]
+        if hi8 > lo8:
+            # packbits reads any non-zero byte as a set bit
+            wire[:, lo8:hi8] = np.packbits(
+                picked[:, lo8:], axis=1, bitorder="little"
+            )
+        return wire
+
+
+class _WireLayout:
+    """Where each wire plane's columns sit in one schema's wide packed
+    row, for one id width (static): the int32 tail plane and the uint16
+    id plane (narrow only) read the row's 32-bit region, the bool lanes
+    its byte region. Holds the two forms no traffic has to teach: the
+    all-elided one and the DENSE one (every plane whole)."""
+
+    __slots__ = (
+        "width", "total8", "span32", "col32", "source", "elided", "dense",
+    )
+
+    def __init__(
+        self, layout: Any, split: tuple[list[int], list[int]] | None
+    ) -> None:
+        self.width = layout.width
+        self.total8 = layout.total8
+        self.span32 = slice(
+            layout.off32_bytes, layout.off32_bytes + 4 * layout.total32
+        )
+        if split is None:  # full-width ids: every 32-bit column is int32
+            col32 = {"i32": np.arange(layout.total32)}
+        else:
+            id_cols, other_cols = split
+            col32 = {
+                "i32": np.asarray(other_cols, np.intp),
+                "ids": np.asarray(id_cols, np.intp),
+            }
+        # plane → the 32-bit region's column of each of its columns
+        self.col32 = {k: v for k, v in col32.items() if v.size}
+        # plane → the wide row's (first) byte of each of its columns
+        self.source: dict[str, np.ndarray] = {
+            name: layout.off32_bytes + 4 * cols
+            for name, cols in self.col32.items()
+        }
+        self.source["bits"] = np.arange(layout.total8)
+        self.elided = _WireForm(self, {})
+        self.dense = _WireForm(self, dict.fromkeys(self.source))
+
+
 class _PlaneColumns:
     """The columns one schema's columnar batches ship, per wire plane: the
     union of every column seen non-zero so far, padded by the one
@@ -239,28 +368,55 @@ class _PlaneColumns:
     batch's live columns is exact: the extra columns carry zeros onto
     zeros."""
 
-    __slots__ = ("seen", "cols", "version", "scheduled")
+    __slots__ = (
+        "layout", "seen", "unseen", "cols", "form", "version", "scheduled",
+    )
 
-    def __init__(self, widths: Mapping[str, tuple[int, Any]]) -> None:
+    def __init__(self, layout: _WireLayout) -> None:
+        self.layout = layout
         self.seen = {
-            name: np.zeros(n, np.bool_) for name, (n, _dt) in widths.items()
+            name: np.zeros(source.size, np.bool_)
+            for name, source in layout.source.items()
         }
+        # the bytes of the wide row no batch has had non-zero yet, 0xFF
+        # each, read as uint32 like the row itself (_live_words): a
+        # batch with no bit in them (the settled case) grows nothing
+        self.unseen = np.full(layout.width // 4, 0xFFFFFFFF, np.uint32)
         # plane → shipped column vector, or None for the whole plane; a
         # plane absent here has shipped nothing yet and stays elided
         self.cols: dict[str, np.ndarray | None] = {}
+        self.form = layout.elided
         self.version = 0
         # newest version handed to the off-path compiler
         self.scheduled = 0
 
-    def admit(self, live: Mapping[str, np.ndarray], select: Callable) -> None:
-        """Fold one batch's live-column masks into the union."""
-        for name, mask in live.items():
-            seen = self.seen[name]
+    def admit(self, words: np.ndarray, select: Callable) -> None:
+        """Fold one batch's live words (_live_words) into the union."""
+        layout = self.layout
+        live = words.view(np.uint8) != 0
+        self.unseen.view(np.uint8)[live] = 0
+        live32 = live[layout.span32].reshape(-1, 4).any(axis=1)
+        version = self.version
+        for name, seen in self.seen.items():
+            mask = (
+                live[: layout.total8] if name == "bits"
+                else live32[layout.col32[name]]
+            )
             if not (mask & ~seen).any():
                 continue
             seen |= mask
             self.cols[name] = select(np.flatnonzero(seen), seen.size)
             self.version += 1
+        if self.version != version:
+            self.form = _WireForm(layout, self.cols)
+
+
+def _live_words(buf: np.ndarray) -> np.ndarray:
+    """The OR of a wide packed batch's rows, four bytes to a word (a wide
+    row is a multiple of 4 wide): one pass over the batch, and what is
+    left to look at afterwards is small enough that numpy keeps the
+    GIL."""
+    return np.bitwise_or.reduce(buf.view(np.uint32), axis=0)
 
 
 def pre_eval_hooks_of(target: "BoundPolicy | BoundGroup") -> list:
@@ -691,24 +847,24 @@ class EvaluationEnvironment:
         )
         # Columnar serving transport (round 12, ROADMAP item 3): the wide
         # packed batch splits into bit-packed / uint16 / int32 PLANES and
-        # only all-nonzero ("delta") columns ship — all-zero planes and
-        # columns are reconstructed on device from resident zero
-        # constants, and the shipped buffers are DONATED so the transport
-        # never round-trips dead input buffers. ``spec`` (static arg 0)
-        # carries (schema index, batch, narrow); the delta dict's pytree
-        # structure + shapes key the jit cache per plane subset. The root
-        # itself is branch-free (TP02); structure branching lives in the
-        # _features_from_planes helper.
+        # only all-nonzero ("delta") columns ship, in ONE wire buffer a
+        # launch (_WireForm) — all-zero planes and columns are
+        # reconstructed on device from resident zero constants, the
+        # column indices stay on the device, and the shipped buffer is
+        # DONATED so the transport never round-trips dead input buffers.
+        # ``spec`` (static arg 0) carries (schema index, batch, narrow,
+        # the form's shape) and keys the jit cache per plane subset. The
+        # root itself is branch-free (TP02); structure branching lives in
+        # the _features_from_planes helper.
         self.columnar = bool(columnar) and backend == "jax"
         self.donate_buffers = bool(donate_buffers)
         if self.donate_buffers and self.columnar:
             _silence_donation_decline_warning()
         self._fused_planes = self._jit_planes()
-        # (spec, structure, shapes) combos whose program is compiled —
-        # the serving path dispatches only these (see _plane_dispatch);
-        # also sizes the resident zero-constant accounting (the first
-        # run of a combo materializes its skipped planes as device
-        # constants)
+        # specs whose program is compiled — the serving path dispatches
+        # only these (see _plane_dispatch); also sizes the resident
+        # zero-constant accounting (the first run of a spec materializes
+        # its skipped planes as device constants)
         self._plane_combos: set = set()  # guarded-by: _profile_lock
         # monotonic count of plane-structure combos traced so far, each
         # one XLA compile: at warm-up, off the serving path
@@ -720,6 +876,8 @@ class EvaluationEnvironment:
         # that only grows, so a traffic mix settles on ONE structure per
         # batch bucket instead of one per batch content
         self._plane_columns: dict[tuple, _PlaneColumns] = {}  # guarded-by: _profile_lock
+        # per (schema, narrow): where the wire planes sit in a wide row
+        self._wire_layouts: dict[tuple, _WireLayout] = {}
         # batch buckets warm-up compiled: the sizes a settled structure
         # is compiled for, off the serving path
         self._warm_batches: set[int] = set()  # guarded-by: _profile_lock
@@ -779,6 +937,7 @@ class EvaluationEnvironment:
             "dispatched_chunks": 0,
             # -- columnar transport (round 12) ----------------------------
             "wire_bytes_shipped": 0,     # bytes actually transferred
+            "launch_h2d_arrays": 0,      # host arrays launches handed over
             "wire_bytes_packed_equiv": 0,  # what the packed transport
             "wire_rows": 0,                # form would have shipped
             "delta_cols_shipped": 0,   # 32-bit columns shipped (delta)
@@ -928,8 +1087,14 @@ class EvaluationEnvironment:
         )
         # rebuild the columnar root: its traces must capture the mesh
         # (plane reconstruction places resident zero constants with the
-        # mesh's NamedSharding)
+        # mesh's NamedSharding). Nothing of the old root is compiled in
+        # the new one, and the forms' resident index vectors were placed
+        # for the old topology: start the column sets over.
         self._fused_planes = self._jit_planes()
+        with self._profile_lock:
+            self._plane_combos.clear()
+            self._plane_columns.clear()
+            self._wire_layouts.clear()
 
     def _jit_planes(self) -> Callable:
         """The columnar jit root (rebuilt when a mesh attaches: its traces
@@ -1530,13 +1695,21 @@ class EvaluationEnvironment:
         features = self._unpack_features(features)
         return self._eval_features(features)
 
-    def _forward_planes(self, spec: tuple, delta: Mapping[str, Any]):
+    def _forward_planes(
+        self,
+        spec: tuple,
+        shipped: Mapping[str, Any],
+        resident: Mapping[str, Any],
+    ):
         """Columnar jit root: ``spec`` is static (schema index, batch,
-        narrow); ``delta`` holds only the shipped planes/columns. The
-        body is deliberately branch-free — plane reconstruction (which
-        branches on the delta STRUCTURE at trace time) lives in the
-        helper."""
-        features = self._features_from_planes(spec, delta)
+        narrow, the wire form's shape); ``shipped`` is what this launch
+        copied to the device (the wire buffer, donated); ``resident`` the
+        column-index vectors that live there across launches, an
+        argument of their own because a donated one would be dead at the
+        second launch. The body is deliberately branch-free — plane
+        reconstruction (which branches on the form's STRUCTURE at trace
+        time) lives in the helper."""
+        features = self._features_from_planes(spec, shipped, resident)
         return self._eval_features(features)
 
     def _resident_zeros(self, shape: tuple, dtype: Any) -> Any:
@@ -1556,54 +1729,54 @@ class EvaluationEnvironment:
         return z
 
     def _features_from_planes(
-        self, spec: tuple, delta: Mapping[str, Any]
+        self,
+        spec: tuple,
+        shipped: Mapping[str, Any],
+        resident: Mapping[str, Any],
     ) -> dict[str, Any]:
-        """Reconstruct the per-key feature dict from columnar delta
-        planes. Planes/columns absent from ``delta`` were all-zero on the
+        """Reconstruct the per-key feature dict from the wire buffer: per
+        row the shipped int32 columns, the shipped uint16 id columns and
+        the shipped bool lanes bit-packed 8:1 (_wire_regions), sliced and
+        bitcast here as ``ops.codec.unpack_rows`` does for the row-packed
+        transport. Planes/columns the form elides were all-zero on the
         host: they come back as device-generated zero constants (resident
         across dispatches — XLA materializes them once per compiled
         program), so steady-state traffic ships only the columns that
-        actually carry data. Delta 32-bit columns scatter into the zero
-        base by their shipped column-index vector; padded index slots
-        repeat a real column with identical values, so duplicate scatter
-        writes are value-identical (deterministic)."""
-        schema_idx, batch, narrow = spec
-        schema = self.schemas[schema_idx]
-        layout = schema.packed_layout()
+        actually carry data. Shipped columns scatter into the zero base
+        by their resident column-index vector; padded index slots repeat
+        a real column with identical values, so duplicate scatter writes
+        are value-identical (deterministic). A plane shipped whole needs
+        no scatter."""
+        schema_idx, batch, narrow, shape = spec
+        layout = self.schemas[schema_idx].packed_layout()
         zeros = self._resident_zeros
         out: dict[str, Any] = {BATCH_KEY: zeros((batch,), jnp.bool_)}
+        wire = shipped.get(WIRE_KEY)
+        (k32, _), (k16, _), (k8, _) = shape
+        r32, r16, r8, _width = _wire_regions(shape)
 
-        def plane(name: str, n_cols: int, zero_dtype):
-            full = delta.get(name + "_full")
-            if full is not None:
-                return jnp.asarray(full)
-            vals = delta.get(name)
-            base = zeros((batch, n_cols), zero_dtype)
-            if vals is None:
-                return base
-            cols = jnp.asarray(delta[name + "_cols"])
-            return base.at[:, cols].set(jnp.asarray(vals))
+        def region(span: tuple, size: int, dtype: Any) -> Any:
+            raw = jax.lax.slice_in_dim(wire, *span, axis=1)
+            return jax.lax.bitcast_convert_type(
+                raw.reshape(batch, -1, size), dtype
+            )
 
-        # -- byte region: bit-packed 8:1 on the wire, delta'd at LANE
-        #    (bool column) granularity — only lanes with any nonzero
-        #    value ship, bit-packed, and scatter into a resident zero
-        #    lane matrix on device -----------------------------------
+        def plane(name: str, vals: Any, n_cols: int) -> Any:
+            cols = resident.get(name)
+            if cols is None:
+                return vals
+            return zeros((batch, n_cols), vals.dtype).at[:, cols].set(vals)
+
+        # -- byte region: delta'd at LANE (bool column) granularity —
+        #    only lanes with any nonzero value ship, bit-packed, and
+        #    scatter into a resident zero lane matrix on device ----------
         lanes = None
-        shifts = jnp.arange(8, dtype=jnp.uint8)
-        if "bits_full" in delta:
-            bits = jnp.asarray(delta["bits_full"])
+        if k8:
+            bits = jax.lax.slice_in_dim(wire, *r8, axis=1)
+            shifts = jnp.arange(8, dtype=jnp.uint8)
             expanded = (bits[:, :, None] >> shifts) & jnp.uint8(1)
-            lanes = expanded.reshape(batch, layout.bits_bytes * 8)
-        elif "bits" in delta:
-            bits = jnp.asarray(delta["bits"])
-            cols = jnp.asarray(delta["bits_cols"])
-            k = delta["bits_cols"].shape[0]
-            expanded = (bits[:, :, None] >> shifts) & jnp.uint8(1)
-            shipped_lanes = expanded.reshape(batch, -1)[:, :k]
-            lanes = (
-                zeros((batch, layout.total8), jnp.uint8)
-                .at[:, cols]
-                .set(shipped_lanes)
+            lanes = plane(
+                "bits", expanded.reshape(batch, -1)[:, :k8], layout.total8
             )
         for e in layout.entries8:
             if e.key == BATCH_KEY:
@@ -1619,9 +1792,17 @@ class EvaluationEnvironment:
         n_id = layout.u16_count if narrow else 0
         n_other = layout.total32 - n_id
         if n_id:
-            ids = plane("ids", n_id, jnp.uint16).astype(jnp.int32)
+            ids = (
+                plane("ids", region(r16, 2, jnp.uint16), n_id)
+                if k16
+                else zeros((batch, n_id), jnp.uint16)
+            ).astype(jnp.int32)
         if n_other:
-            other = plane("i32", n_other, jnp.int32)
+            other = (
+                plane("i32", region(r32, 4, jnp.int32), n_other)
+                if k32
+                else zeros((batch, n_other), jnp.int32)
+            )
         id_off = other_off = 0
         for e in layout.entries32:
             if narrow and e.is_id:
@@ -1640,7 +1821,7 @@ class EvaluationEnvironment:
             out[e.key] = block
         # -- side channel: host-computed wasm member verdict bits ---------
         if self._wasm_member_order:
-            wb = delta.get(WASM_BITS_KEY)
+            wb = shipped.get(WASM_BITS_KEY)
             out[WASM_BITS_KEY] = (
                 zeros((batch, len(self._wasm_member_order)), jnp.bool_)
                 if wb is None
@@ -1879,164 +2060,90 @@ class EvaluationEnvironment:
             [live, np.full(kb - k, live[-1], dtype=live.dtype)]
         )
 
-    @staticmethod
-    def _ship_plane(
-        delta: dict, name: str, mat: np.ndarray, cols: np.ndarray | None
-    ) -> None:
-        """Add one wire plane to the delta dict: whole (``cols`` None) or
-        the given columns plus their index vector. The byte plane ships
-        bit-packed 8:1."""
-        if cols is not None:
-            delta[name + "_cols"] = cols.astype(np.int32)
-            mat = mat[:, cols]
-            key = name
-        else:
-            key = name + "_full"
-        if name == "bits":
-            delta[key] = np.packbits(mat != 0, axis=1, bitorder="little")
-        else:
-            delta[key] = np.ascontiguousarray(mat)
-
-    def _plane_widths(
-        self, schema_idx: int, narrow: bool
-    ) -> dict[str, tuple[int, Any]]:
-        """Wire plane → (column count, host dtype) for one schema: the
-        byte region's bool lanes, the uint16 id plane (narrow only) and
-        the int32 tail plane."""
-        layout = self.schemas[schema_idx].packed_layout()
-        widths: dict[str, tuple[int, Any]] = {"bits": (layout.total8, np.uint8)}
-        n_id = layout.u16_count if narrow else 0
-        if n_id:
-            widths["ids"] = (n_id, np.uint16)
-        if layout.total32 - n_id:
-            widths["i32"] = (layout.total32 - n_id, np.int32)
-        return widths
-
     def _narrow(self, schema_idx: int) -> bool:
         """Intern-id lanes ship as uint16 while the vocabulary fits."""
         layout = self.schemas[schema_idx].packed_layout()
         return layout.u16_count > 0 and len(self.table) <= 65536
 
-    def _wire_planes(
-        self, schema_idx: int, buf: np.ndarray, narrow: bool
-    ) -> dict[str, np.ndarray]:
-        """Wide packed batch → its wire planes as whole host matrices.
-        Pure numpy; one vectorized pass per plane."""
-        schema = self.schemas[schema_idx]
-        layout = schema.packed_layout()
-        planes = {"bits": buf[:, : layout.total8]}
-        if layout.total32:
-            region32 = np.ascontiguousarray(
-                buf[
-                    :,
-                    layout.off32_bytes : layout.off32_bytes
-                    + layout.total32 * 4,
-                ]
-            ).view(np.int32)
-            if narrow:
-                id_cols, other_cols = schema._transport_col_split()
-                planes["ids"] = region32[:, id_cols].astype(np.uint16)
-                if other_cols:
-                    planes["i32"] = region32[:, other_cols]
-            else:
-                planes["i32"] = region32
-        return planes
+    def _wire_layout(self, schema_idx: int, narrow: bool) -> _WireLayout:
+        """The (cached) map from one schema's wide rows to wire planes."""
+        key = (schema_idx, narrow)
+        layout = self._wire_layouts.get(key)
+        if layout is None:
+            schema = self.schemas[schema_idx]
+            # a race builds it twice, equal: setdefault keeps one
+            layout = self._wire_layouts.setdefault(key, _WireLayout(
+                schema.packed_layout(),
+                schema._transport_col_split() if narrow else None,
+            ))
+        return layout
 
-    def _ship_planes(
-        self,
-        planes: Mapping[str, np.ndarray],
-        cols: Mapping[str, np.ndarray | None],
-        wasm_bits: Any,
-    ) -> dict[str, np.ndarray]:
-        """The delta dict for one dispatch: each plane in ``cols`` shipped
-        as selected, the others elided. Wasm member bits ALWAYS ship when
-        present (tiny: batch × the member count): eliding the all-zero
-        case would flap the jit structure between wasm-present and
-        wasm-absent programs per batch."""
-        delta: dict[str, np.ndarray] = {}
-        for name, selected in cols.items():
-            self._ship_plane(delta, name, planes[name], selected)
-        if wasm_bits is not None:
-            delta[WASM_BITS_KEY] = np.asarray(wasm_bits)
-        return delta
+    def _plane_template(self, batch: int, form: _WireForm) -> dict[str, Any]:
+        """An all-zero shipped dict with the exact structure, shapes and
+        dtypes a real batch of this form has — what warm-up and the
+        off-path compiler run a program with."""
+        shipped: dict = {}
+        self._add_wasm_bits(shipped, batch)
+        if form.width:
+            shipped[WIRE_KEY] = np.zeros((batch, form.width), np.uint8)
+        return shipped
 
-    def _plane_template(
-        self, spec: tuple, cols: Mapping[str, np.ndarray | None]
-    ) -> dict[str, np.ndarray]:
-        """An all-zero delta dict with the exact structure, shapes and
-        dtypes a real batch of ``spec`` shipping ``cols`` has — what
-        warm-up and the off-path compiler run a program with."""
-        schema_idx, batch, narrow = spec
-        planes = {
-            name: np.zeros((batch, n), dt)
-            for name, (n, dt) in self._plane_widths(schema_idx, narrow).items()
-        }
-        stub: dict = {}
-        self._add_wasm_bits(stub, batch)
-        return self._ship_planes(planes, cols, stub.get(WASM_BITS_KEY))
-
-    @staticmethod
-    def _plane_combo(spec: tuple, delta: Mapping[str, Any]) -> tuple:
-        """The jit-cache identity of a columnar dispatch. Shapes are in
-        the key: a new power-of-two column bucket with the same key set
-        is a NEW compiled program."""
-        return (spec, tuple(sorted((k, a.shape) for k, a in delta.items())))
-
-    def _note_plane_program(self, spec: tuple, delta: Mapping[str, Any]) -> None:
-        """Record that the program of this (spec, structure) is being
-        compiled: it is dispatchable from now on, counts one compile, and
-        its resident zero constants are accounted."""
-        combo = self._plane_combo(spec, delta)
-        layout = self.schemas[spec[0]].packed_layout()
-        batch = spec[1]
-        with self._profile_lock:
-            if combo in self._plane_combos:
-                return
-            self._plane_combos.add(combo)
-            self._plane_compiles += 1
-            # planes reconstructed on device are resident zero constants
-            # of this compiled program: the elided byte-columns plus
-            # every unshipped 32-bit column. The byte region counts in
-            # DEVICE lane units (one uint8 lane per bool column), not
-            # packed wire bytes: the device materializes (batch, total8)
-            # lanes and everything not scattered from the shipped subset
-            # is constant zero
-            if "bits_full" in delta:
-                elided_lanes = 0
-            elif "bits_cols" in delta:
-                elided_lanes = layout.total8 - delta["bits_cols"].shape[0]
-            else:
-                elided_lanes = layout.total8
-            self._host_profile["resident_const_bytes"] += batch * (
-                max(0, elided_lanes)
-                + 4 * max(0, layout.total32 - self._cols_shipped(delta))
-            )
-
-    @staticmethod
-    def _cols_shipped(delta: Mapping[str, Any]) -> int:
-        return sum(
-            a.shape[1]
-            for k, a in delta.items()
-            if k in ("ids", "i32", "ids_full", "i32_full")
+    def _note_plane_program(self, spec: tuple) -> None:  # holds: _profile_lock
+        """Record (under _profile_lock) that the program of this spec is
+        being compiled: it is dispatchable from now on, counts one
+        compile, and its resident zero constants are accounted. The spec
+        is the program's whole jit-cache identity: the form's shape in it
+        fixes the wire row's width and the index vectors' shapes (a new
+        power-of-two column bucket is a NEW compiled program), and an
+        environment's wasm side channel never changes."""
+        if spec in self._plane_combos:
+            return
+        self._plane_combos.add(spec)
+        self._plane_compiles += 1
+        # planes reconstructed on device are resident zero constants of
+        # this compiled program: the elided byte-columns plus every
+        # unshipped 32-bit column. The byte region counts in DEVICE lane
+        # units (one uint8 lane per bool column), not packed wire bytes:
+        # the device materializes (batch, total8) lanes and everything
+        # not scattered from the shipped subset is constant zero
+        schema_idx, batch, _narrow, ((k32, _), (k16, _), (k8, _)) = spec
+        layout = self.schemas[schema_idx].packed_layout()
+        self._host_profile["resident_const_bytes"] += batch * (
+            layout.total8 - k8 + 4 * (layout.total32 - k32 - k16)
         )
 
-    def _launch_planes(self, spec: tuple, delta: Mapping[str, Any]) -> Any:
-        """Place the delta planes and launch the columnar program (async).
-        Mesh dispatch: batch-carrying planes shard over the data axis up
-        front (one device_put of the tree), column-index vectors
-        replicate — wire bytes per data shard are shipped /
-        data-axis-size (batches are bucketed to divide the axis, so the
-        split is exact)."""
-        if self._mesh is not None:
+    def _launch_planes(
+        self, spec: tuple, form: _WireForm, shipped: Mapping[str, Any]
+    ) -> Any:
+        """Launch the columnar program (async) on what a batch ships: ONE
+        host array, the wire buffer (plus the wasm side channel where an
+        environment has one), so one host-to-device copy — on a mesh one
+        put sharded over the data axis, a copy per device (batches are
+        bucketed to divide the axis, so the split is exact). The form's
+        column-index vectors go to the device the first time it launches
+        (replicated over a mesh) and stay: warm-up, the off-path compiler
+        and the serving path all come through here, so all hand the
+        program arguments of one kind."""
+        mesh = self._mesh
+        resident = form.resident
+        if mesh is not None:
             from policy_server_tpu.parallel import mesh as mesh_mod
-
-            delta = mesh_mod.shard_delta_planes(delta, self._mesh)
-        return self._fused_planes(spec, delta)
+        if resident is None:  # a race places them twice, equal
+            resident = form.resident = jax.device_put(
+                form.cols,
+                None if mesh is None else mesh_mod.replicated_sharding(mesh),
+            )
+            self._profile_add(
+                wire_bytes_shipped=sum(c.nbytes for c in form.cols.values())
+            )
+        if mesh is not None:
+            shipped = mesh_mod.shard_delta_planes(shipped, mesh)
+        return self._fused_planes(spec, shipped, resident)
 
     def _plane_dispatch(
         self, schema_idx: int, features: Mapping[str, Any], rows: int = 0
     ) -> Any:
-        """Columnar device dispatch: select the planes to ship, account
+        """Columnar device dispatch: build the one wire buffer, account
         wire bytes / delta columns / donation, and launch the donated
         columnar program (async — caller fetches through _device_fetch).
 
@@ -2049,54 +2156,61 @@ class EvaluationEnvironment:
         the set compiles off the serving path for every warm batch bucket
         (_compile_columns). Only a batch size warm-up never saw compiles
         inside the dispatch, watchdog-bounded like any cold bucket."""
-        buf = np.asarray(features[PACKED_KEY])
-        layout = self.schemas[schema_idx].packed_layout()
+        buf = np.ascontiguousarray(features[PACKED_KEY])
+        playout = self.schemas[schema_idx].packed_layout()
         batch = buf.shape[0]
         narrow = self._narrow(schema_idx)
-        planes = self._wire_planes(schema_idx, buf, narrow)
-        spec = (schema_idx, batch, narrow)
+        layout = self._wire_layout(schema_idx, narrow)
+        shipped: dict[str, Any] = {}
         wasm_bits = features.get(WASM_BITS_KEY)
-        live = {name: mat.any(axis=0) for name, mat in planes.items()}
-        cols: Mapping[str, np.ndarray | None] = {}
-        version = 0
-        if any(mask.any() for mask in live.values()):
-            with self._profile_lock:
+        if wasm_bits is not None:
+            # ALWAYS ships when present (tiny: batch × the member count):
+            # eliding the all-zero case would flap the jit structure
+            # between wasm-present and wasm-absent programs per batch
+            shipped[WASM_BITS_KEY] = np.asarray(wasm_bits)
+        side_bytes = sum(a.nbytes for a in shipped.values())
+        # the liveness check is what keeps a superset exact: a byte no
+        # batch had non-zero before grows the set before this one ships
+        live = _live_words(buf)
+        any_live = live.any()
+        form, version, schedule = layout.elided, 0, False
+        with self._profile_lock:
+            if any_live:
                 settled = self._plane_columns.get((schema_idx, narrow))
                 if settled is None:
                     settled = self._plane_columns[(schema_idx, narrow)] = (
-                        _PlaneColumns(self._plane_widths(schema_idx, narrow))
+                        _PlaneColumns(layout)
                     )
-                settled.admit(live, self._select_delta_cols)
-                cols, version = dict(settled.cols), settled.version
-        delta = self._ship_planes(planes, cols, wasm_bits)
-        with self._profile_lock:
-            compiled = self._plane_combo(spec, delta) in self._plane_combos
-        if not compiled:
-            dense = self._ship_planes(planes, dict.fromkeys(planes), wasm_bits)
-            with self._profile_lock:
-                dense_compiled = (
-                    self._plane_combo(spec, dense) in self._plane_combos
-                )
-            if version and dense_compiled:
-                self._compile_columns_async(schema_idx, narrow, version)
-                delta = dense
-            else:
-                self._note_plane_program(spec, delta)
-        cols_shipped = self._cols_shipped(delta)
-        with self._profile_lock:
+                if (live & settled.unseen).any():
+                    settled.admit(live, self._select_delta_cols)
+                form, version = settled.form, settled.version
+            spec = (schema_idx, batch, narrow, form.shape)
+            if spec not in self._plane_combos:
+                dense = (schema_idx, batch, narrow, layout.dense.shape)
+                if version and dense in self._plane_combos:
+                    schedule = True
+                    form, spec = layout.dense, dense
+                else:
+                    self._note_plane_program(spec)
             hp = self._host_profile
-            hp["wire_bytes_shipped"] += sum(
-                int(a.nbytes) for a in delta.values()
-            )
+            hp["wire_bytes_shipped"] += batch * form.width + side_bytes
             hp["wire_bytes_packed_equiv"] += batch * (
-                layout.transport16_width if narrow else layout.transport_width
+                playout.transport16_width if narrow
+                else playout.transport_width
             )
             hp["wire_rows"] += batch
-            hp["delta_cols_shipped"] += cols_shipped
-            hp["delta_cols_total"] += layout.total32
+            hp["delta_cols_shipped"] += form.shape[0][0] + form.shape[1][0]
+            hp["delta_cols_total"] += playout.total32
+            hp["launch_h2d_arrays"] += len(shipped) + bool(form.width)
             if self.donate_buffers:
                 hp["donated_dispatches"] += 1
-        return self._device_call(self._launch_planes, spec, delta, rows=rows)
+        if schedule:
+            self._compile_columns_async(schema_idx, narrow, version)
+        if form.width:
+            shipped[WIRE_KEY] = form.wire(buf)
+        return self._device_call(
+            self._launch_planes, spec, form, shipped, rows=rows
+        )
 
     def _compile_columns_async(
         self, schema_idx: int, narrow: bool, version: int
@@ -2140,17 +2254,18 @@ class EvaluationEnvironment:
                     settled = self._plane_columns[(schema_idx, narrow)]
                     if settled.version != version or self._closed:
                         return
-                    cols = dict(settled.cols)
-                spec = (schema_idx, batch, narrow)
-                delta = self._plane_template(spec, cols)
-                with self._profile_lock:
-                    if self._plane_combo(spec, delta) in self._plane_combos:
+                    form = settled.form
+                    spec = (schema_idx, batch, narrow, form.shape)
+                    if spec in self._plane_combos:
                         continue
                 t0 = time.perf_counter()
-                jax.block_until_ready(self._launch_planes(spec, delta))
+                jax.block_until_ready(self._launch_planes(
+                    spec, form, self._plane_template(batch, form)
+                ))
                 # dispatchable only now: until the program exists the
                 # serving path keeps shipping the dense form
-                self._note_plane_program(spec, delta)
+                with self._profile_lock:
+                    self._note_plane_program(spec)
                 logger.info(
                     "columnar plane program compiled off the serving path",
                     extra={"span_fields": {
@@ -2362,14 +2477,14 @@ class EvaluationEnvironment:
                     # a batch of ones: warm-up must teach the column
                     # sets nothing.
                     narrow = self._narrow(idx)
-                    spec = (idx, b, narrow)
-                    dense = self._plane_template(
-                        spec, dict.fromkeys(self._plane_widths(idx, narrow))
-                    )
-                    self._note_plane_program(spec, dense)
-                    self._device_fetch(
-                        self._device_call(self._launch_planes, spec, dense)
-                    )
+                    dense = self._wire_layout(idx, narrow).dense
+                    spec = (idx, b, narrow, dense.shape)
+                    with self._profile_lock:
+                        self._note_plane_program(spec)
+                    self._device_fetch(self._device_call(
+                        self._launch_planes, spec, dense,
+                        self._plane_template(b, dense),
+                    ))
 
     def encode_bucketed(
         self, payload: Any

@@ -188,8 +188,8 @@ def batch_sharding(mesh: Mesh) -> NamedSharding:
 
 
 def replicated_sharding(mesh: Mesh) -> NamedSharding:
-    """Fully replicated placement (delta column-index vectors: every
-    shard scatters with the same static column set)."""
+    """Fully replicated placement (the columnar forms' resident
+    column-index vectors: every shard scatters with the same columns)."""
     return NamedSharding(mesh, P())
 
 
@@ -213,21 +213,16 @@ def shard_features(
 
 
 def shard_delta_planes(
-    delta: Mapping[str, np.ndarray], mesh: Mesh
+    shipped: Mapping[str, np.ndarray], mesh: Mesh
 ) -> dict[str, jax.Array]:
-    """Columnar delta planes → device, mesh-placed: batch-carrying planes
-    (2-D+, leading batch dim) shard over the data axis; 1-D column-index
-    vectors replicate (every shard scatters the same static columns).
-    One device_put of the whole tree, mirroring shard_features."""
-    shardings = {
-        k: (
-            batch_sharding(mesh)
-            if getattr(v, "ndim", 0) >= 2
-            else replicated_sharding(mesh)
-        )
-        for k, v in delta.items()
-    }
-    return jax.device_put(dict(delta), shardings)
+    """What a columnar launch ships → device, mesh-placed: ONE put with
+    the batch axis sharded over ``data``, so the wire buffer costs one
+    copy per device (every leaf leads with the batch dim: the wire
+    buffer, and the wasm side channel where an environment has one). The
+    column-index vectors are not here: they live on the device,
+    replicated (replicated_sharding), across launches. Single-process
+    meshes only (environment._columnar_mesh_ok)."""
+    return jax.device_put(dict(shipped), batch_sharding(mesh))
 
 
 def jit_data_parallel(

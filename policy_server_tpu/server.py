@@ -1176,8 +1176,15 @@ class PolicyServer:
             yield (
                 metrics_names.WIRE_BYTES_SHIPPED, "counter",
                 "Bytes actually shipped to the device by the columnar "
-                "transport (delta planes + column indices)",
+                "transport (a wire buffer a launch; a column set's index "
+                "vectors once, when it first launches)",
                 profile.get("wire_bytes_shipped", 0),
+            )
+            yield (
+                metrics_names.LAUNCH_H2D_ARRAYS, "counter",
+                "Host arrays the columnar launches handed to the device "
+                "(one wire buffer each; none for an all-zero batch)",
+                profile.get("launch_h2d_arrays", 0),
             )
             yield (
                 metrics_names.WIRE_BYTES_PACKED_EQUIV, "counter",

@@ -121,6 +121,10 @@ DISPATCHED_ROWS = "policy_server_dispatched_rows_total"
 BULK_SUBMITS = "policy_server_bulk_submits"
 BULK_SUBMITTED_ROWS = "policy_server_bulk_submitted_rows"
 WIRE_BYTES_SHIPPED = "policy_server_wire_bytes_shipped"
+# host arrays launches of the columnar program handed to the device (one
+# wire buffer a launch since PR 28; per launch against
+# policy_server_phase_latency_seconds_count{phase="launch"})
+LAUNCH_H2D_ARRAYS = "policy_server_launch_h2d_arrays"
 WIRE_BYTES_PACKED_EQUIV = "policy_server_wire_bytes_packed_equivalent"
 WIRE_ROWS = "policy_server_wire_rows"
 DELTA_COLS_SHIPPED = "policy_server_delta_columns_shipped"

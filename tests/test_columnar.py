@@ -20,6 +20,7 @@ import pytest
 
 from policy_server_tpu.api.service import RequestOrigin
 from policy_server_tpu.evaluation.environment import (
+    WIRE_KEY,
     EvaluationEnvironmentBuilder,
 )
 from policy_server_tpu.models import AdmissionReviewRequest, ValidateRequest
@@ -87,6 +88,49 @@ def _dicts(results):
     return [r.to_dict() for r in results]
 
 
+def _wait_compiled(env, seconds: float = 180.0):
+    """Until the off-path compiler has nothing queued or running."""
+    deadline = time.monotonic() + seconds
+    while env.plane_programs_pending:
+        assert time.monotonic() < deadline
+        time.sleep(0.05)
+
+
+def _settle(env, corpus):
+    """Serve the corpus until its column sets are settled AND compiled:
+    from then on a pass over it ships the settled forms."""
+    for _ in range(2):
+        env.reset_verdict_cache()
+        env.validate_batch(corpus)
+        _wait_compiled(env)
+
+
+def _record_launches(env, monkeypatch) -> list:
+    """Every later launch of the columnar program, in order, as (spec,
+    form, what was shipped: name → host array, whether the serving path
+    made it — not warm-up or the off-path compiler)."""
+    launched: list = []
+    launch, dispatch = env._launch_planes, env._plane_dispatch
+    on = threading.local()
+
+    def recording(spec, form, shipped):
+        launched.append(
+            (spec, form, dict(shipped), getattr(on, "serving", False))
+        )
+        return launch(spec, form, shipped)
+
+    def serving(*args, **kwargs):
+        on.serving = True
+        try:
+            return dispatch(*args, **kwargs)
+        finally:
+            on.serving = False
+
+    monkeypatch.setattr(env, "_launch_planes", recording)
+    monkeypatch.setattr(env, "_plane_dispatch", serving)
+    return launched
+
+
 class TestColumnarParity:
     def test_columnar_enabled_by_default(self, col_env):
         assert col_env.columnar
@@ -123,9 +167,15 @@ class TestColumnarParity:
         ]
         assert patches, "corpus produced no mutation patches"
 
-    def test_wire_accounting_reconciles(self, col_env, corpus):
-        """Shipped bytes are positive, strictly below the packed-form
-        equivalent, and every columnar dispatch was donated."""
+    def test_wire_accounting_reconciles(self, col_env, corpus, monkeypatch):
+        """Shipped bytes are exactly the wire buffers' ``nbytes`` plus,
+        once per column set, its index vectors' (they go to the device
+        when the set first launches and stay there, so a pass over a
+        settled corpus adds none); they are strictly below the
+        packed-form equivalent, and every columnar dispatch was
+        donated."""
+        _settle(col_env, corpus)
+        launched = _record_launches(col_env, monkeypatch)
         before = col_env.host_profile
         col_env.reset_verdict_cache()
         col_env.validate_batch(corpus)
@@ -139,12 +189,49 @@ class TestColumnarParity:
         donated = after["donated_dispatches"] - before["donated_dispatches"]
         chunks = after["dispatched_chunks"] - before["dispatched_chunks"]
         assert rows > 0 and shipped > 0
+        assert all(served for *_rest, served in launched)
+        assert shipped == sum(
+            a.nbytes for _s, _f, sent, _served in launched
+            for a in sent.values()
+        )
+        assert all(list(sent) == [WIRE_KEY] for _s, _f, sent, _ in launched)
         assert shipped < packed
-        assert donated == chunks
+        assert donated == chunks == len(launched)
         assert (
             after["delta_cols_shipped"] - before["delta_cols_shipped"]
             <= after["delta_cols_total"] - before["delta_cols_total"]
         )
+
+    def test_index_vectors_are_counted_once_per_column_set(
+        self, corpus, monkeypatch
+    ):
+        """A fresh environment's whole account: every served wire buffer,
+        plus the index vectors of each form that ever launched, once."""
+        env = EvaluationEnvironmentBuilder(
+            backend="jax", verdict_cache_size=0
+        ).build(_parsed())
+        try:
+            launched = _record_launches(env, monkeypatch)
+            env.warmup((16, 64))
+            for _ in range(3):
+                env.validate_batch(corpus)
+                _wait_compiled(env)
+            forms = {id(f): f for _spec, f, _sent, _served in launched}
+            indices = sum(
+                c.nbytes for f in forms.values() for c in f.cols.values()
+            )
+            assert indices > 0
+            assert all(f.resident is not None for f in forms.values())
+            # warm-up's and the off-path compiler's templates are not
+            # traffic: only what the serving path launched counts
+            wire = sum(
+                a.nbytes for _s, _f, sent, served in launched if served
+                for a in sent.values()
+            )
+            assert any(not served for *_rest, served in launched)
+            assert env.host_profile["wire_bytes_shipped"] == wire + indices
+        finally:
+            env.close()
 
     def test_donation_off_still_bit_exact(self, corpus):
         env = EvaluationEnvironmentBuilder(
@@ -185,15 +272,10 @@ class TestColumnarParity:
         mat[:, 3] = 7
         mat[:, 9] = np.arange(4)
         mat[:, 12] = -1
-        delta: dict = {}
-        EvaluationEnvironment._ship_plane(
-            delta, "i32", mat,
-            EvaluationEnvironment._select_delta_cols(
-                np.flatnonzero(mat.any(axis=0)), mat.shape[1]
-            ),
+        cols = EvaluationEnvironment._select_delta_cols(
+            np.flatnonzero(mat.any(axis=0)), mat.shape[1]
         )
-        cols = delta["i32_cols"]
-        vals = delta["i32"]
+        vals = mat[:, cols]
         assert len(cols) == 4  # 3 live columns bucketed to 4
         assert sorted(set(cols.tolist())) == [3, 9, 12]
         # padded slot repeats the last real column with its real values
@@ -256,6 +338,209 @@ class TestColumnarParity:
             env.close()
             oracle_env.close()
 
+
+@pytest.fixture(scope="module")
+def references(corpus):
+    """What the one-buffer form has to equal: the row-packed transport
+    and the host oracle on the module's corpus, and the row-packed
+    transport's raw outputs on an all-zero batch."""
+    row_env = EvaluationEnvironmentBuilder(
+        backend="jax", columnar=False, verdict_cache_size=0
+    ).build(_parsed())
+    oracle_env = EvaluationEnvironmentBuilder(backend="oracle").build(
+        _parsed()
+    )
+    try:
+        row = _dicts(row_env.validate_batch(corpus))
+        assert row == _dicts(oracle_env.validate_batch(corpus))
+        zero = row_env.run_batch(row_env.schemas[0].empty_batch_packed(8))
+        yield row, zero
+    finally:
+        row_env.close()
+        oracle_env.close()
+
+
+class TestOneWireBuffer:
+    """The columnar launch ships ONE packed wire buffer and its column
+    indices stay on the device (PR 28)."""
+
+    @pytest.mark.parametrize("donate", [True, False], ids=["donate", "keep"])
+    @pytest.mark.parametrize(
+        "mode", ["settled", "dense-fallback", "all-elided", "whole-plane"]
+    )
+    @pytest.mark.parametrize("narrow", [True, False], ids=["u16", "i32"])
+    def test_one_buffer_equals_row_packed_and_oracle(
+        self, narrow, mode, donate, corpus, references, monkeypatch
+    ):
+        """Every form of the wire buffer, on the narrow and the
+        full-width id plane, with donation on and off, answers what the
+        row-packed transport and the oracle answer — launched TWICE
+        through the same resident index vectors, which a donated one
+        would not survive."""
+        row, zero = references
+        env = EvaluationEnvironmentBuilder(
+            backend="jax", verdict_cache_size=0, donate_buffers=donate
+        ).build(_parsed())
+        try:
+            if not narrow:
+                monkeypatch.setattr(env, "_narrow", lambda schema_idx: False)
+            if mode == "whole-plane":
+                # every plane with a live column ships whole
+                monkeypatch.setattr(
+                    env, "_select_delta_cols", lambda live, n_cols: None
+                )
+            # every batch bucket the corpus is served in: a size warm-up
+            # never saw would compile its set inside the dispatch
+            env.warmup((8, 256))
+            launched = _record_launches(env, monkeypatch)
+            if mode == "dense-fallback":
+                # the off-path compiler never gets the grown set: its
+                # batches keep shipping the dense buffer
+                monkeypatch.setattr(
+                    env, "_compile_columns_async", lambda *a: None
+                )
+            elif mode != "all-elided":
+                _settle(env, corpus)
+            del launched[:]
+            donated = env.host_profile["donated_dispatches"]
+            for _ in range(2):
+                if mode == "all-elided":
+                    got = env.run_batch(env.schemas[0].empty_batch_packed(8))
+                    assert got.keys() == zero.keys()
+                    for key, want in zero.items():
+                        assert np.array_equal(got[key], want), key
+                else:
+                    assert _dicts(env.validate_batch(corpus)) == row
+            assert len(launched) >= 2
+            for spec, form, sent, served in launched:
+                _idx, _batch, spec_narrow, shape = spec
+                assert served and spec_narrow == narrow
+                scattered = [s for k, s in shape if k]
+                layout = env._wire_layout(spec[0], narrow)
+                if mode == "settled":
+                    assert any(scattered) and list(sent) == [WIRE_KEY]
+                    assert all(
+                        a.sharding.device_set for a in form.resident.values()
+                    )
+                elif mode == "all-elided":
+                    assert not scattered and not sent
+                else:
+                    assert scattered and not any(scattered)
+                    assert form.cols == {} and list(sent) == [WIRE_KEY]
+                if mode == "dense-fallback":
+                    assert form is layout.dense
+                elif mode == "whole-plane":
+                    assert form is not layout.dense
+                if sent:
+                    wire = sent[WIRE_KEY]
+                    assert wire.dtype == np.uint8 and wire.flags.c_contiguous
+                    assert wire.shape == (spec[1], form.width)
+                    assert form.width % 4 == 0
+            assert env.host_profile["donated_dispatches"] - donated == (
+                len(launched) if donate else 0
+            )
+        finally:
+            env.close()
+
+    def test_growth_across_a_bucket_compiles_off_the_serving_path(
+        self, monkeypatch
+    ):
+        """A column set that grows across a power-of-two bucket is a new
+        program: it compiles off the serving path, the batch that grew it
+        ships the dense buffer meanwhile (no compile inside its
+        dispatch), and once compiled the same batch ships the grown set —
+        every answer equal to the row-packed transport's."""
+        env = EvaluationEnvironmentBuilder(
+            backend="jax", verdict_cache_size=0
+        ).build(_parsed())
+        row_env = EvaluationEnvironmentBuilder(
+            backend="jax", columnar=False, verdict_cache_size=0
+        ).build(_parsed())
+        try:
+            env.warmup((8,))
+            narrow = env._narrow(0)
+            layout = env._wire_layout(0, narrow)
+            plane, at = "bits", 0
+            lanes = layout.source["bits"]
+
+            def batch_with(n_lanes: int) -> dict:
+                feats = env.schemas[0].empty_batch_packed(8)
+                # lane 0 is the batch mask: leave it alone
+                feats[next(iter(feats))][:, lanes[1 : 1 + n_lanes]] = 1
+                return feats
+
+            def same(feats) -> None:
+                got, want = env.run_batch(feats), row_env.run_batch(feats)
+                assert got.keys() == want.keys()
+                for key in want:
+                    assert np.array_equal(got[key], want[key]), key
+
+            launched = _record_launches(env, monkeypatch)
+            small, grown = batch_with(3), batch_with(5)
+            same(small)                      # teaches 3 lanes: bucket 4
+            _wait_compiled(env)
+            same(small)
+            assert launched[-1][1].shape[2] == (4, True)
+            compiles = env.plane_program_compiles
+            # hold the compiler so that "meanwhile" is observable
+            gate = threading.Event()
+            compile_columns = env._compile_columns
+            monkeypatch.setattr(
+                env, "_compile_columns",
+                lambda *a: (gate.wait(60), compile_columns(*a))[1],
+            )
+            same(grown)                      # 5 lanes: bucket 8, a new program
+            assert env.plane_programs_pending == 1
+            assert env.plane_program_compiles == compiles
+            assert launched[-1][1] is layout.dense and launched[-1][3]
+            same(grown)                      # still compiling: dense again
+            assert launched[-1][1] is layout.dense
+            gate.set()
+            _wait_compiled(env)
+            assert env.plane_program_compiles == compiles + 1  # one warm bucket
+            same(grown)
+            assert launched[-1][1].shape[2] == (8, True) and launched[-1][3]
+            same(small)                      # a superset is exact
+            assert launched[-1][1].shape[2] == (8, True)
+            assert env.plane_program_compiles == compiles + 1
+        finally:
+            env.close()
+            row_env.close()
+
+    def test_a_launch_hands_over_exactly_one_host_array(self, corpus):
+        """policy_server_launch_h2d_arrays_total (host_profile's
+        ``launch_h2d_arrays``) rises by exactly 1 a launch, whatever the
+        form, and by 0 for an all-elided batch."""
+        env = EvaluationEnvironmentBuilder(
+            backend="jax", verdict_cache_size=0
+        ).build(_parsed())
+        try:
+            env.warmup((8, 64))
+            assert env.host_profile["launch_h2d_arrays"] == 0
+
+            def launches_and_arrays(fn) -> tuple[int, int]:
+                before = env.host_profile
+                fn()
+                after = env.host_profile
+                return (
+                    after["dispatched_chunks"] - before["dispatched_chunks"],
+                    after["launch_h2d_arrays"] - before["launch_h2d_arrays"],
+                )
+
+            n, arrays = launches_and_arrays(
+                lambda: env.validate_batch(corpus)     # dense fallback
+            )
+            assert n >= 1 and arrays == n
+            _wait_compiled(env)
+            n, arrays = launches_and_arrays(
+                lambda: env.validate_batch(corpus)     # settled
+            )
+            assert n >= 1 and arrays == n
+            before = env.host_profile["launch_h2d_arrays"]
+            env.run_batch(env.schemas[0].empty_batch_packed(8))
+            assert env.host_profile["launch_h2d_arrays"] == before
+        finally:
+            env.close()
 
 class TestSubmitMany:
     @pytest.fixture()

@@ -292,20 +292,53 @@ class TestColumnarUnderMesh:
         assert mesh.axis_names == (DATA_AXIS, POLICY_AXIS)
 
     def test_shard_delta_planes_placement(self):
-        """Batch-carrying (2-D+) delta planes shard over the data axis;
-        1-D column-index vectors replicate."""
-        mesh = make_mesh(MeshSpec.parse("data:4,policy:2"))
-        delta = {
-            "i32": np.zeros((8, 6), np.int32),
-            "i32_cols": np.arange(6, dtype=np.int32),
-            "bits": np.zeros((8, 2), np.uint8),
-        }
-        placed = mesh_mod.shard_delta_planes(delta, mesh)
-        batch = mesh_mod.batch_sharding(mesh)
-        repl = mesh_mod.replicated_sharding(mesh)
-        assert placed["i32"].sharding == batch
-        assert placed["bits"].sharding == batch
-        assert placed["i32_cols"].sharding == repl
+        """What a launch ships is ONE array put once, its batch axis
+        sharded over ``data``: on data:4 a copy per device, each of its
+        own rows."""
+        mesh = make_mesh(MeshSpec.parse("data:4"), jax.devices()[:4])
+        wire = np.arange(8 * 52, dtype=np.uint8).reshape(8, 52)
+        placed = mesh_mod.shard_delta_planes({"wire": wire}, mesh)
+        assert list(placed) == ["wire"]
+        assert placed["wire"].sharding == mesh_mod.batch_sharding(mesh)
+        shards = placed["wire"].addressable_shards
+        assert len(shards) == 4
+        for shard in shards:
+            assert shard.data.shape == (2, 52)
+            assert np.array_equal(np.asarray(shard.data), wire[shard.index])
+        # the 2-D mesh replicates the rows over ``policy``
+        mesh2 = make_mesh(MeshSpec.parse("data:4,policy:2"))
+        placed = mesh_mod.shard_delta_planes({"wire": wire}, mesh2)
+        assert placed["wire"].sharding == mesh_mod.batch_sharding(mesh2)
+        assert len(placed["wire"].addressable_shards) == 8
+
+    def test_a_mesh_launch_ships_one_sharded_array(
+        self, mesh_env, corpus, monkeypatch
+    ):
+        """Under the mesh a served batch hands the device one host array
+        (the counter says so), and its column indices stay there,
+        replicated, across launches."""
+        forms: list = []
+        launch = mesh_env._launch_planes
+
+        def recording(spec, form, shipped):
+            forms.append((form, dict(shipped)))
+            return launch(spec, form, shipped)
+
+        monkeypatch.setattr(mesh_env, "_launch_planes", recording)
+        mesh_env.reset_verdict_cache()
+        before = mesh_env.host_profile
+        mesh_env.validate_batch(corpus)
+        after = mesh_env.host_profile
+        served = [(f, sent) for f, sent in forms if sent]
+        assert served
+        assert after["launch_h2d_arrays"] - before["launch_h2d_arrays"] == (
+            len(served)
+        )
+        repl = mesh_mod.replicated_sharding(mesh_env.mesh)
+        for form, sent in served:
+            assert list(sent) == ["wire"]
+            assert isinstance(sent["wire"], np.ndarray)
+            assert all(a.sharding == repl for a in form.resident.values())
 
 
 class TestMeshWarmup:

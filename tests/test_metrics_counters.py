@@ -170,6 +170,32 @@ def test_dedup_and_pipeline_counters_after_served_batch(server):
     assert "policy_server_audit_paused_sweeps_total" in m
 
 
+def test_launch_h2d_arrays_on_the_pull_endpoint(server):
+    """One host array a launch (the columnar wire buffer), counted where
+    the benchmark reads it: the family's value is the environment's own
+    count, and every launch that shipped anything added exactly one."""
+    env = server.server.environment
+    before = env.host_profile
+    r = requests.post(
+        server.url("/validate/pod-privileged"),
+        data=_review_body("u-h2d", True),  # a pod shape not served yet
+        headers={"Content-Type": "application/json"}, timeout=30,
+    )
+    assert r.status_code == 200
+    time.sleep(0.1)
+    profile = env.host_profile
+    launches = profile["dispatched_chunks"] - before["dispatched_chunks"]
+    assert launches >= 1
+    assert (
+        profile["launch_h2d_arrays"] - before["launch_h2d_arrays"] == launches
+    )
+    m = _scrape(server)
+    assert (
+        m[metrics_mod.LAUNCH_H2D_ARRAYS + "_total"]
+        == profile["launch_h2d_arrays"]
+    )
+
+
 def test_counters_survive_otlp_conversion(server):
     """The OTLP pusher converts the SAME registry (one source of truth);
     the round-6 instruments must come through as monotonic sums/gauges."""

@@ -299,3 +299,40 @@ def test_the_fused_programs_names_match_the_benchmarks_patterns(variant):
         assert not _matches("jit__renamed")
     finally:
         e.close()
+
+
+def test_the_launch_metrics_read_the_launch_phase_and_the_h2d_counter():
+    """benchmarks/layer_metrics/{launch_ms_mean,h2d_arrays_per_launch}.json
+    (data files, PR 28) against the families the server exports: the
+    launch phase's histogram gives the mean and the launches, the new
+    counter the arrays; a program without the counter (the parent) gives
+    no number and no error."""
+    from policy_server_tpu.telemetry import metrics as names
+
+    sys.path.insert(0, str(BENCH))
+    try:
+        reduce = importlib.import_module("reduce")
+    finally:
+        sys.path.remove(str(BENCH))
+    phases = "policy_server_phase_latency_seconds"
+    assert flightrec.PH_LAUNCH in flightrec.PHASES
+
+    def samples(seconds: float, launches: int, arrays: int | None):
+        lines = [
+            f'{phases}_sum{{phase="{flightrec.PH_LAUNCH}"}} {seconds}',
+            f'{phases}_count{{phase="{flightrec.PH_LAUNCH}"}} {launches}',
+            f'{phases}_sum{{phase="encode"}} 99.0',
+            f'{phases}_count{{phase="encode"}} 7',
+        ]
+        if arrays is not None:
+            lines.append(f"{names.LAUNCH_H2D_ARRAYS}_total {arrays}")
+        return reduce.parse_metrics("\n".join(lines) + "\n")
+
+    ctx = {"before": samples(1.0, 100, 90), "after": samples(1.9, 400, 390)}
+    assert reduce.read_layer_metric("launch_ms_mean", ctx) == pytest.approx(3.0)
+    assert reduce.read_layer_metric("h2d_arrays_per_launch", ctx) == (
+        pytest.approx(1.0)
+    )
+    parent = {"before": samples(1.0, 100, None), "after": samples(1.9, 400, None)}
+    assert reduce.read_layer_metric("launch_ms_mean", parent) == pytest.approx(3.0)
+    assert reduce.read_layer_metric("h2d_arrays_per_launch", parent) is None
