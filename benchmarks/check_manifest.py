@@ -34,6 +34,35 @@ def _line(value: object) -> bool:
             and all(32 <= ord(ch) < 127 for ch in value))
 
 
+def answers_from_problems(doc: dict, bench: Path) -> list[str]:
+    """A configuration's file states who may answer its requests:
+    ``guarantees.answers_from``, from a source (a file of
+    ``answer_sources/``) to the counter that file names, ``device`` among
+    them. ``correct`` holds the run to exactly that (``run.py``)."""
+    named = (doc.get("guarantees") or {}).get("answers_from")
+    if not isinstance(named, dict):
+        return ["its file states no guarantees.answers_from"]
+    bad = []
+    if "device" not in named:
+        bad.append("guarantees.answers_from must name device: every cell "
+                   "drives the device path")
+    for source, counter in named.items():
+        file = bench / "answer_sources" / f"{source}.json"
+        if not (isinstance(source, str) and NAME.match(source)
+                and file.is_file()):
+            bad.append(f"guarantees.answers_from names {source!r}, which "
+                       f"is no source of {bench.name}/answer_sources/")
+            continue
+        spec = json.loads(file.read_text(encoding="utf-8"))
+        if spec["counts"] != "requests":
+            bad.append(f"source {source} counts {spec['counts']}, not "
+                       "requests: it can answer for none")
+        if counter != spec["counter"]:
+            bad.append(f"source {source} is counted by "
+                       f"{spec['counter']}, not {counter!r}")
+    return bad
+
+
 def problems(manifest: dict, root: Path = ROOT) -> list[str]:
     """Every reason the manifest would be refused; empty when it is sound."""
     bad: list[str] = []
@@ -95,6 +124,8 @@ def problems(manifest: dict, root: Path = ROOT) -> list[str]:
             if doc.get("source") != c.get("source"):
                 bad.append(f"config {c.get('name')}: the file's source "
                            "differs from the manifest's")
+            bad += [f"config {c.get('name')}: {reason}" for reason in
+                    answers_from_problems(doc, root / paths[0])]
         files.add(file)
         reduced = c.get("reduced")
         if not isinstance(reduced, list) or len(reduced) > 16:

@@ -4,13 +4,22 @@ program but its counters' names (in the layer metrics' data files) and its
 trace; later PRs add data files, not code.
 
 A per-layer metric is a data file ``benchmarks/layer_metrics/<name>.json``
-naming a reader kind of ``READERS`` and its parameters. A reader that finds
-nothing to read returns ``None`` and the metric is left out of the line.
+naming a reader kind and its parameters: a kind of ``READERS`` here, or of
+the ``READERS`` of the module of this directory its ``"module"`` names
+(``host_spans``), so a later reader is a file of its own. A reader that
+finds nothing to read returns ``None`` and the metric is left out of the
+line.
+
+A way the program can answer a request is a data file
+``benchmarks/answer_sources/<source>.json`` naming the counter that counts
+the requests it answered; a configuration's ``guarantees.answers_from``
+names those that may answer in that deployment (``held_to_its_sources``).
 """
 
 from __future__ import annotations
 
 import fnmatch
+import importlib
 import json
 from pathlib import Path
 from typing import Any, Callable
@@ -93,6 +102,42 @@ def delta(before: Samples, after: Samples, want: str | dict) -> float | None:
     return None if a is None or b is None else b - a
 
 
+# -- who answered ---------------------------------------------------------------
+
+
+def answer_sources() -> dict[str, dict]:
+    """Every way the program can answer a request with a verdict, by name:
+    ``{"counter": ..., "counts": "requests" | "events", "what": ...}``."""
+    return {path.stem: json.loads(path.read_text(encoding="utf-8"))
+            for path in sorted((HERE / "answer_sources").glob("*.json"))}
+
+
+def answers_by_source(before: Samples, after: Samples) -> dict[str, float]:
+    """What each source's counter moved by over the window; a program
+    without the counter has no such source: 0."""
+    return {name: delta(before, after, spec["counter"]) or 0.0
+            for name, spec in answer_sources().items()}
+
+
+def held_to_its_sources(config: dict, before: Samples, after: Samples,
+                        answers: int) -> dict[str, float]:
+    """The two numbers that hold a configuration to who may answer its
+    requests, both with the limit 0. ``answered_off_device``: what the
+    sources its ``guarantees.answers_from`` does NOT name answered (for a
+    configuration that names the device alone, everything off the device).
+    ``rows_not_dispatched``: how far the sources it DOES name are from
+    having counted every answer exactly once (for such a configuration,
+    |answers - rows dispatched|)."""
+    named = config["guarantees"]["answers_from"]
+    moved = answers_by_source(before, after)
+    return {
+        "answered_off_device": sum(
+            n for source, n in moved.items() if source not in named),
+        "rows_not_dispatched": abs(answers - sum(
+            moved[source] for source in named)),
+    }
+
+
 # -- the trace ----------------------------------------------------------------
 
 
@@ -164,8 +209,9 @@ def module_seconds(trace: dict[str, Any], patterns: list[str]) -> float | None:
 
 def breakdown(trace: dict[str, Any], top: int = 10) -> dict[str, list]:
     """The device operations that took most time, and the longest idle
-    gaps. The program writes no TraceAnnotation, so a gap cannot be laid to
-    a host phase yet: each is ``host:unattributed``."""
+    gaps of every device, none laid to a host phase: what a trace alone can
+    say. ``host_spans.breakdown`` names the busiest device's gaps where the
+    program's spans allow it, and falls back to this."""
     per_op: dict[str, float] = {}
     gaps: list[float] = []
     for lines in trace["devices"].values():
@@ -244,7 +290,9 @@ READERS: dict[str, Callable[[dict, dict], float | None]] = {
 def read_layer_metric(name: str, ctx: dict) -> float | None:
     p = json.loads(
         (HERE / "layer_metrics" / f"{name}.json").read_text(encoding="utf-8"))
-    return READERS[p["reader"]](p, ctx)
+    readers = (importlib.import_module(p["module"]).READERS
+               if "module" in p else READERS)
+    return readers[p["reader"]](p, ctx)
 
 
 def peaks_of(device_kind: str) -> dict[str, float]:

@@ -55,19 +55,11 @@ sys.path.insert(0, str(HERE))
 import numpy as np  # noqa: E402
 
 import check_manifest  # noqa: E402
+import host_spans  # noqa: E402
 import reduce  # noqa: E402
 import reference  # noqa: E402
 from traffic import Traffic, uid_of  # noqa: E402
 
-# counters of the server under test that must not move over the window:
-# each is a way to answer a request correctly without the device
-MUST_STAY_ZERO = (
-    "policy_server_host_fastpath_requests",
-    "policy_server_oracle_fallbacks",
-    "policy_server_breaker_trips",
-    "policy_server_breaker_short_circuited_requests",
-    "policy_server_deadline_abandoned_batches",
-)
 COMPILE_COUNTERS = (
     "policy_server_xla_programs_compiled",
     "policy_server_plane_program_compiles",
@@ -520,6 +512,12 @@ class Rig:
             if not found:
                 raise RunFailure("the trace left no .xplane.pb")
             traced["file"] = found[-1]
+            # the flight recorder's ring for the traced interval, on the
+            # clock the launcher started the trace by
+            since = int(traced["started_at"] * 1e9)
+            traced["timeline"] = host_spans.fetch_timeline(
+                self.server.ready_port, since,
+                since + int(traced["done"]["traced_s"] * 1e9))
         rec = {
             "due": np.array([r[1] for r in records], np.float64),
             "sent": np.array([r[2] for r in records], np.float64),
@@ -589,11 +587,13 @@ def run(args: argparse.Namespace, rig: Rig) -> dict:
     say(f"reference: {len(records)} answers in "
         f"{time.monotonic() - t_ref:.1f}s")
     answered = len(records) - compared["unanswered"][0]
-    if not args.control:
-        compared["answered_off_device"] = [sum(
-            reduce.delta(before, after, c) for c in MUST_STAY_ZERO), 0]
-        compared["rows_not_dispatched"] = [abs(answered - reduce.delta(
-            before, after, "policy_server_dispatched_rows")), 0]
+    if not args.control:  # the control server claims no device, no counter
+        say(f"{answered} answers; by source: " + json.dumps({
+            source: n for source, n in reduce.answers_by_source(
+                before, after).items() if n}))
+        for name, value in reduce.held_to_its_sources(
+                config, before, after, answered).items():
+            compared[name] = [value, 0]
         compared["not_framed_natively"] = [abs(len(records) - reduce.delta(
             before, after, "policy_server_native_http_requests")), 0]
         compared["shed"] = [reduce.delta(
@@ -617,15 +617,7 @@ def run(args: argparse.Namespace, rig: Rig) -> dict:
     }
     if args.trace:
         if traced and not rig.rehearsal:
-            trace = reduce.load_trace(traced["file"])
-            busy = reduce.busy_seconds(trace)
-            ctx.update(trace=trace, traced_s=traced["done"]["traced_s"],
-                       trace_before=traced["before"],
-                       trace_after=traced["after"],
-                       peaks=reduce.peaks_of(device["kind"]))
-            device["busy_s"] = sum(busy.values()) / max(len(busy), 1)
-            device["window_s"] = traced["done"]["traced_s"]
-            result["breakdown"] = reduce.breakdown(trace)
+            result["breakdown"] = read_traced(traced, device, ctx, args.keep)
         wanted = manifest["per_layer"]
     else:
         wanted = manifest["end_to_end"]
@@ -640,18 +632,58 @@ def run(args: argparse.Namespace, rig: Rig) -> dict:
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     result.update(metrics=metrics, device=device, compared=compared)
-    if args.keep:  # every counter's move over the window, for PERF.md
-        moved = {}
-        for name, samples in after.items():
-            for labels, value in samples:
-                was = reduce.sample(before, {"name": name, "labels": labels})
-                if was is not None and value != was and "_bucket" not in name:
-                    key = name + "".join(f"[{v}]" for v in labels.values())
-                    moved[key] = value - was
-        Path(args.keep).mkdir(parents=True, exist_ok=True)
-        (Path(args.keep) / "counters.json").write_text(
-            json.dumps(moved, indent=1), encoding="utf-8")
+    if args.keep:
+        keep_json(args.keep, "counters.json", moved_counters(before, after))
     return result
+
+
+def moved_counters(before: reduce.Samples, after: reduce.Samples) -> dict:
+    """Every counter's move over the window but the histograms' buckets,
+    for PERF.md; ``before`` is indexed once (a scan a sample took minutes
+    over the 25,000 bucket samples of the per-policy histogram)."""
+    def key(name: str, labels: dict) -> str:
+        return name + "".join(f"[{v}]" for v in labels.values())
+
+    was = {key(name, labels): value for name, samples in before.items()
+           for labels, value in samples}
+    return {
+        key(name, labels): value - was[key(name, labels)]
+        for name, samples in after.items() if "_bucket" not in name
+        for labels, value in samples
+        if value != was.get(key(name, labels), value)}
+
+
+def keep_json(directory: str, name: str, doc) -> None:
+    Path(directory).mkdir(parents=True, exist_ok=True)
+    (Path(directory) / name).write_text(
+        json.dumps(doc, indent=1), encoding="utf-8")
+
+
+def read_traced(traced: dict, device: dict, ctx: dict,
+                keep: str | None = None) -> dict:
+    """Read the traced part of a window: ``device`` gains ``busy_s`` and
+    ``window_s``; ``ctx`` the trace, the counters around it, the ring's
+    events for the interval and its gaps laid to host phases
+    (``host_spans.attribute``, once); → the breakdown, the busiest device's
+    gaps named where the program has ``ps:launch`` and ``/debug/timeline``
+    and ``reduce.breakdown``'s where it has not."""
+    trace = host_spans.load_trace(traced["file"])
+    timeline = traced["timeline"]
+    spans = host_spans.attribute(trace, timeline)
+    busy = reduce.busy_seconds(trace)
+    device["busy_s"] = sum(busy.values()) / max(len(busy), 1)
+    device["window_s"] = traced_s = traced["done"]["traced_s"]
+    ctx.update(trace=trace, traced_s=traced_s,
+               trace_before=traced["before"], trace_after=traced["after"],
+               peaks=reduce.peaks_of(device["kind"]),
+               timeline=timeline, spans=spans)
+    if keep:
+        keep_json(keep, "timeline.json", timeline)
+        keep_json(keep, "spans.json", spans)
+    if spans is not None:  # the clock, the link and idle seconds by part
+        say("host spans: " + json.dumps(
+            {k: v for k, v in spans.items() if k != "gaps"}))
+    return host_spans.breakdown(trace, spans)
 
 
 def await_file(path: Path, timeout: float) -> Path:
@@ -680,7 +712,8 @@ def trace_window(server: Server, control_dir: Path, work: Path, t0: float,
     before = server.metrics()
     time.sleep(max(0.0, started["at"] + length - time.monotonic()))
     after = server.metrics()
-    return {"dir": trace_dir, "before": before, "after": after}
+    return {"dir": trace_dir, "before": before, "after": after,
+            "started_at": started["at"]}
 
 
 def parser(description: str) -> argparse.ArgumentParser:
@@ -698,8 +731,9 @@ def parser(description: str) -> argparse.ArgumentParser:
     ap.add_argument("--ready-timeout", type=float, default=900.0)
     ap.add_argument("--warm-passes", type=int, default=8)
     ap.add_argument("--keep", default=None, metavar="DIR",
-                    help="copy the server's and clients' logs (and the "
-                         "trace) here")
+                    help="keep here the server's and clients' logs, every "
+                         "counter's move over the window and, of a traced "
+                         "run, the trace, the ring's events and the gaps")
     return ap
 
 

@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import copy
 import json
+import shutil
 import sys
 from pathlib import Path
 
@@ -131,6 +132,13 @@ def test_counter_readers_take_deltas_over_the_window():
     assert reduce.read_layer_metric("framing_us_per_req", ctx) is None
     same = {"before": ctx["before"], "after": ctx["before"]}
     assert reduce.counter_ratio(encode, same) is None
+    # run.py --keep: every counter that moved, by name and labels
+    assert run.moved_counters(ctx["before"], ctx["after"]) == {
+        "policy_server_host_encode_seconds_total": 0.5,
+        "policy_server_host_encode_rows_total": 10000.0,
+        "policy_server_phase_latency_seconds_sum[materialize]":
+            pytest.approx(0.6),
+        "policy_server_phase_latency_seconds_count[materialize]": 300.0}
 
 
 # -- the manifest check ------------------------------------------------------------
@@ -142,12 +150,26 @@ def test_the_committed_manifest_is_sound():
 
 
 def _set(path: list, value):
-    def edit(m: dict) -> None:
+    def edit(m: dict, _root: Path | None = None) -> None:
         at = m
         for key in path[:-1]:
             at = at[key]
         at[path[-1]] = value
     return edit
+
+
+def _in_config_file(path: list, value):
+    """An edit to the first configuration's FILE, in a copy of the data
+    files (what a configuration guarantees is its file's to state)."""
+    def edit(m: dict, root: Path) -> None:
+        file = root / m["configs"][0]["file"]
+        doc = json.loads(file.read_text())
+        _set(path, value)(doc)
+        file.write_text(json.dumps(doc))
+    return edit
+
+
+DEVICE = {"device": "policy_server_dispatched_rows"}
 
 
 @pytest.mark.parametrize("edit, reason", [
@@ -162,17 +184,34 @@ def _set(path: list, value):
     (_set(["per_layer", 0, "workloads"], ["no-such-cell"]), "no workload"),
     (_set(["workloads", 0, "config"], "no-such-config"), "no config"),
     (_set(["workloads", 0, "chips"], 4), "ask for 4 chips"),
-    (lambda m: m["per_layer"][0].update(workloads=[c["name"] for c in
-                                                  m["workloads"]]),
+    (lambda m, _root: m["per_layer"][0].update(
+        workloads=[c["name"] for c in m["workloads"]]),
      "not all of its workloads"),  # a steady-only metric, moved by all cells
     (_set(["workloads", 0, "traffic"], "no-such-mix"), "no traffic file"),
     (_set(["run_seconds"], 52), "run_seconds"),
+    (_in_config_file(["guarantees"], {"device": "prose alone"}),
+     "states no guarantees.answers_from"),
+    (_in_config_file(["guarantees", "answers_from"],
+                     {"row_tier": "policy_server_verdict_cache_hits"}),
+     "must name device"),
+    (_in_config_file(["guarantees", "answers_from"],
+                     {**DEVICE, "psychic": "policy_server_guesses"}),
+     "is no source of"),
+    (_in_config_file(["guarantees", "answers_from"],
+                     {**DEVICE, "breaker_trip": "policy_server_breaker_trips"}),
+     "counts events, not requests"),
+    (_in_config_file(["guarantees", "answers_from"],
+                     {"device": "policy_server_requests_dispatched"}),
+     "is counted by policy_server_dispatched_rows"),
 ])
-def test_the_manifest_check_refuses(edit, reason):
+def test_the_manifest_check_refuses(edit, reason, tmp_path):
     manifest = copy.deepcopy(MANIFEST)
-    edit(manifest)
-    assert any(reason in p for p in check_manifest.problems(manifest, ROOT)), \
-        check_manifest.problems(manifest, ROOT)
+    shutil.copytree(BENCH, tmp_path / "benchmarks", ignore=shutil.ignore_patterns(
+        "tests", "__pycache__", "*.py"))
+    assert check_manifest.problems(manifest, tmp_path) == []
+    edit(manifest, tmp_path)
+    assert any(reason in p for p in check_manifest.problems(manifest, tmp_path)), \
+        check_manifest.problems(manifest, tmp_path)
 
 
 # -- traffic ---------------------------------------------------------------------------

@@ -22,6 +22,7 @@ sys.path.insert(0, str(BENCH))
 import check_manifest  # noqa: E402
 import host_spans  # noqa: E402
 import reduce  # noqa: E402
+import run  # noqa: E402
 
 TRACE = json.loads((HERE / "recorded_trace.json").read_text())
 RECORDED = json.loads((HERE / "recorded_host_spans.json").read_text())
@@ -255,18 +256,86 @@ def test_the_rule_on_a_fixture_recorded_from_a_chip_run():
     assert "launch" not in parts and parts["dispatch_self"] > 0.2
 
 
-def test_the_manifest_with_the_new_entries_is_sound():
+def test_the_manifest_holds_the_span_metrics_and_their_readers_are_found():
     manifest = check_manifest.load(ROOT)
-    names = [m["name"] for m in manifest["per_layer"]]
-    assert names[-2:] == ["encode_cpu_us_per_row", "gc_pause_s"]
-    pending = json.loads((BENCH / "pending" / "per_layer.json").read_text())
-    for m in pending:  # ready to be appended: sound, and a reader exists
-        assert m["name"] not in names
-        trial = dict(manifest, per_layer=[*manifest["per_layer"], m])
-        assert check_manifest.problems(trial, ROOT) == []
-        p = json.loads(
-            (BENCH / "layer_metrics" / f"{m['name']}.json").read_text())
-        assert p["reader"] in host_spans.READERS
+    cells = {m["name"]: m["workloads"] for m in manifest["per_layer"]}
+    assert {"encode_cpu_us_per_row", "gc_pause_s", "idle_attributed_share",
+            "dispatch_unattributed_ms_per_batch"} <= set(cells)
+    assert len(cells["idle_attributed_share"]) == len(manifest["workloads"])
+    assert cells["dispatch_unattributed_ms_per_batch"] == [
+        "flagship32.unique-steady"]
+    # a metric's data file names the module its reader kind lives in
+    trace, timeline = _hand_built()
+    ctx = {"timeline": timeline,
+           "spans": host_spans.attribute(trace, timeline)}
+    assert reduce.read_layer_metric("idle_attributed_share", ctx) == \
+        pytest.approx(100 * 4320 / 7850)
+    assert reduce.read_layer_metric(
+        "dispatch_unattributed_ms_per_batch", ctx) == pytest.approx(600e-6)
+    assert reduce.read_layer_metric("idle_attributed_share", {}) is None
+
+
+def _traced(tmp_path, timeline: dict) -> dict:
+    """What ``Rig.window`` keeps of a traced window, around a trace file
+    that ``host_spans.load_trace`` is patched to read."""
+    rows = "policy_server_dispatched_rows_total"
+    return {"file": tmp_path / "recorded.xplane.pb", "timeline": timeline,
+            "done": {"traced_s": 3.0},
+            "before": reduce.parse_metrics(f"{rows} 1000\n"),
+            "after": reduce.parse_metrics(f"{rows} 1600\n")}
+
+
+def test_a_traced_run_names_its_gaps_and_reads_the_span_metrics(
+        monkeypatch, tmp_path, capsys):
+    """``run.read_traced`` (what ``run.py --trace 1`` does with the trace
+    and the ring) on the fixture recorded from a chip run."""
+    trace, timeline = RECORDED["trace"], RECORDED["timeline"]
+    monkeypatch.setattr(host_spans, "load_trace", lambda path: trace)
+    device, ctx = {"kind": "TPU v5 lite"}, {}
+    keep = tmp_path / "kept"
+    b = run.read_traced(_traced(tmp_path, timeline), device, ctx, str(keep))
+    assert b["idle_gaps"][:3] == [
+        ["host:encode", pytest.approx(0.224908775)],
+        ["host:launch", pytest.approx(0.107730949)],
+        ["host:launch", pytest.approx(0.074129801)]]
+    assert b["device_ops"] == reduce.breakdown(trace)["device_ops"]
+    assert device["window_s"] == 3.0 and 0 < device["busy_s"] < 0.01
+    assert reduce.read_layer_metric("idle_attributed_share", ctx) > 99.9
+    assert reduce.read_layer_metric("device_idle_share", ctx) > 99.0
+    assert "host spans: " in capsys.readouterr().out
+    kept = json.loads((keep / "spans.json").read_text())
+    assert kept["link"]["linked"] == 7 and kept["gaps"]
+    assert json.loads((keep / "timeline.json").read_text()) == timeline
+
+
+@pytest.mark.parametrize("older", ["no /debug/timeline", "no ps:launch"])
+def test_a_traced_run_of_an_older_program_falls_back_and_raises_nothing(
+        older, monkeypatch, tmp_path):
+    trace, timeline = RECORDED["trace"], RECORDED["timeline"]
+    if older == "no ps:launch":
+        trace = dict(trace, launches=[])
+    else:  # what fetch_timeline gives where the route is missing
+        timeline = {"since_ns": 1, "until_ns": 2, "traceEvents": []}
+    monkeypatch.setattr(host_spans, "load_trace", lambda path: trace)
+    device, ctx = {"kind": "TPU v5 lite"}, {}
+    b = run.read_traced(_traced(tmp_path, timeline), device, ctx)
+    assert b == reduce.breakdown(trace)
+    assert ctx["spans"] is None and device["busy_s"] > 0
+    assert reduce.read_layer_metric("idle_attributed_share", ctx) is None
+    if older == "no /debug/timeline":
+        assert reduce.read_layer_metric(
+            "dispatch_unattributed_ms_per_batch", ctx) is None
+    assert reduce.read_layer_metric("device_idle_share", ctx) > 99.0
+
+
+def test_fetch_timeline_of_a_program_without_the_route_gives_no_events():
+    import socket
+
+    with socket.socket() as s:  # a port nobody listens on
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    assert host_spans.fetch_timeline(port, 5, 9) == {
+        "since_ns": 5, "until_ns": 9, "traceEvents": []}
 
 
 def test_the_new_counter_metrics_read_their_counters():
