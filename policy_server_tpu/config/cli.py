@@ -230,8 +230,10 @@ def _flag_specs() -> list[tuple[str, str | None, dict[str, Any]]]:
                    "the payload, so this is lossless; wasm-backed verdicts "
                    "are never cached). Size it to hold the live admission "
                    "template working set — the default 256Mi holds tens of "
-                   "thousands of templates. 0 disables caching AND "
-                   "in-batch row dedup")),
+                   "thousands of templates; a tier that outgrows its half "
+                   "evicts oldest-first, counted by "
+                   "policy_server_verdict_cache_evictions_total{tier}. 0 "
+                   "disables caching AND in-batch row dedup")),
         ("--policy-reload-mode", "KUBEWARDEN_POLICY_RELOAD_MODE",
          dict(default="auto", metavar="MODE",
               choices=["off", "auto", "manual"],

@@ -1605,27 +1605,16 @@ class EvaluationEnvironment:
         """Two-tier verdict-cache + in-batch dedup counters
         (bench/metrics). ``cache_*`` keys are the row tier (legacy
         names); ``blob_*`` keys are the pre-encode blob tier."""
-        if self._verdict_cache is not None:
-            stats = self._verdict_cache.stats()
-        else:
-            stats = {
-                "cache_hits": 0,
-                "cache_misses": 0,
-                "cache_entries": 0,
-                "cache_bytes": 0,
-                "cache_capacity": 0,
-            }
-        blob = (
-            self._blob_cache.stats()
-            if self._blob_cache is not None
-            else {
-                "cache_hits": 0,
-                "cache_misses": 0,
-                "cache_entries": 0,
-                "cache_bytes": 0,
-                "cache_capacity": 0,
-            }
+        off = dict.fromkeys(
+            ("cache_hits", "cache_misses", "cache_evictions",
+             "cache_entries", "cache_bytes", "cache_capacity"), 0
         )
+        stats = (
+            self._verdict_cache.stats()
+            if self._verdict_cache is not None
+            else dict(off)
+        )
+        blob = self._blob_cache.stats() if self._blob_cache is not None else off
         for k, v in blob.items():
             stats["blob_" + k] = v
         with self._fallback_lock:

@@ -106,6 +106,9 @@ class VerdictCache:
         self._lock = threading.Lock()
         self.hits = 0  # guarded-by: _lock
         self.misses = 0  # guarded-by: _lock
+        # entries pushed out by the byte bound (a re-put of a live key
+        # replaces, it does not evict)
+        self.evictions = 0  # guarded-by: _lock
 
     def get(self, key: Hashable) -> Mapping[str, Any] | None:
         with self._lock:
@@ -163,6 +166,7 @@ class VerdictCache:
         while self._bytes > self.capacity_bytes and data:
             _, (_, evicted_cost) = data.popitem(last=False)
             self._bytes -= evicted_cost
+            self.evictions += 1
 
     def put(self, key: Hashable, row: Mapping[str, Any]) -> None:
         cost = entry_cost(key, row)
@@ -215,6 +219,7 @@ class VerdictCache:
             return {
                 "cache_hits": self.hits,
                 "cache_misses": self.misses,
+                "cache_evictions": self.evictions,
                 "cache_entries": len(self._data),
                 "cache_bytes": self._bytes,
                 "cache_capacity": self.capacity_bytes,

@@ -874,6 +874,14 @@ class PolicyServer:
                 dedup.get("cache_bytes", 0) + dedup.get("blob_cache_bytes", 0),
             )
             yield (
+                metrics_names.VERDICT_CACHE_EVICTIONS, "counter",
+                "Verdict-cache entries pushed out by the byte budget, by "
+                "tier",
+                [(("blob",), dedup.get("blob_cache_evictions", 0)),
+                 (("row",), dedup.get("cache_evictions", 0))],
+                ("tier",),
+            )
+            yield (
                 metrics_names.BATCH_DEDUP_HITS, "counter",
                 "Rows answered by an identical row in the same batch",
                 dedup.get("batch_dup_hits", 0),

@@ -60,6 +60,9 @@ DEDUP_BLOB_MISSES = "policy_server_dedup_blob_misses"
 VERDICT_CACHE_HITS = "policy_server_verdict_cache_hits"
 VERDICT_CACHE_MISSES = "policy_server_verdict_cache_misses"
 VERDICT_CACHE_BYTES = "policy_server_verdict_cache_bytes"
+# entries the byte bound pushed out, by tier ("blob" | "row"): a hit rate
+# says nothing of a tier that churns, this does
+VERDICT_CACHE_EVICTIONS = "policy_server_verdict_cache_evictions_total"
 BATCH_DEDUP_HITS = "policy_server_batch_dedup_hits"
 FRAGMENT_HITS = "policy_server_fragment_hits"
 BUDGET_ROUTED_BATCHES = "policy_server_budget_routed_batches"
