@@ -228,9 +228,15 @@ def _flag_specs() -> list[tuple[str, str | None, dict[str, Any]]]:
                    "identical (policy, payload) rows are answered without "
                    "re-dispatch (policy evaluation is a pure function of "
                    "the payload, so this is lossless; wasm-backed verdicts "
-                   "are never cached). Size it to hold the live admission "
-                   "template working set — the default 256Mi holds tens of "
-                   "thousands of templates; a tier that outgrows its half "
+                   "are never cached). An entry is one (policy, payload) "
+                   "or (policy, encoded row) key over the packed output row "
+                   "the device returned, accounted at 256 bytes + key + row: "
+                   "~1.4 KB with a 1 KB key, so each tier's half of the "
+                   "default 256Mi holds ~95,000 entries "
+                   "(policy_server_verdict_cache_put_bytes_total over "
+                   "policy_server_verdict_cache_puts_total is the measured "
+                   "size). Size it to hold the live (policy, admission "
+                   "template) pairs; a tier that outgrows its half "
                    "evicts oldest-first, counted by "
                    "policy_server_verdict_cache_evictions_total{tier}. 0 "
                    "disables caching AND in-batch row dedup")),

@@ -29,6 +29,7 @@ from __future__ import annotations
 import base64
 import functools
 import json
+import operator
 import sys
 import threading
 import time
@@ -57,7 +58,11 @@ from policy_server_tpu.evaluation.precompiled import (
     ProgramCache,
 )
 from policy_server_tpu.evaluation.settings import PolicyEvaluationSettings
-from policy_server_tpu.evaluation.verdict_cache import VerdictCache, extract_row
+from policy_server_tpu.evaluation.verdict_cache import (
+    OutputLayout,
+    PackedRow,
+    VerdictCache,
+)
 from policy_server_tpu.models import (
     AdmissionResponse,
     FragTemplate,
@@ -104,20 +109,24 @@ WASM_BITS_KEY = "__wasm_bits__"
 # uid-insensitive dedup) — see verdict_cache.py for why both tiers exist.
 # Sized to working-set scale: the round-5 default of 4,096 ROWS was
 # smaller than the benchmark's own 12,500-template working set, so the
-# cross-batch cache thrashed. At the measured
-# ~3-6 KB/entry estimate, 256 MiB comfortably holds tens of thousands of
-# templates in both tiers. 0 disables caching AND in-batch row dedup.
+# cross-batch cache thrashed. An entry is one (policy, payload) or
+# (policy, encoded row) key over the device's packed output row: ~1.4 KB
+# with the flagship set's 1,064-byte row key and 80-byte output row, so
+# each tier's half of 256 MiB holds ~95,000 entries (verdict_cache.py).
+# 0 disables caching AND in-batch row dedup.
 DEFAULT_VERDICT_CACHE_SIZE = 256 * 1024 * 1024
 
 
 _donation_warning_silenced = False
 
 # -- pre-serialized cache-hit fragments (round 19) ---------------------------
-# Cached-row key under which a row dict carries its per-target
-# FragTemplate map ({cache_key_of(target): FragTemplate | False}) — the
-# materializers never read it, extract_row copies it along, and the hit
-# loops splice it instead of rebuilding AdmissionResponse rows per hit.
-FRAG_KEY = "__frag__"
+# A hit row of a fragment-eligible target answers as uid + FragTemplate;
+# the hit loops splice it instead of rebuilding AdmissionResponse rows per
+# hit. The templates live in the environment (_frag_of), one per target
+# and verdict, and not on the cached rows, which are immutable. A target's
+# memo that outgrows this many verdicts starts again (a group of many
+# members has many member-verdict combinations; traffic shows a handful).
+_FRAG_MEMO_MAX = 4096
 
 # Thread-local arming flag: FragVerdicts are only returned to callers
 # that PROVABLY handle them (the MicroBatcher's fused pipeline, which
@@ -129,7 +138,7 @@ _frag_scope = threading.local()
 
 class fragment_responses:
     """Context manager arming the cache-hit fragment fast lane on this
-    thread (see FRAG_KEY). Entered by the batcher around the fused
+    thread (see _frag_of). Entered by the batcher around the fused
     encode→device→fetch chain."""
 
     __slots__ = ("_prev",)
@@ -821,6 +830,21 @@ class EvaluationEnvironment:
         self._max_group_members = max(
             (len(g.members) for g in groups.values()), default=0
         )
+        # where each output key sits in a row of the one output array the
+        # program returns (_combine_outputs concatenates in this order):
+        # policies' allowed, policies' rule, groups' allowed, then each
+        # group's members' evaluated flags, padded to the widest group
+        n_p, n_g = len(self._policy_order), len(self._group_order)
+        out_index: dict[str, tuple[int, bool]] = {}
+        for j, pid in enumerate(self._policy_order):
+            out_index[f"p:{pid}:allowed"] = (j, False)
+            out_index[f"p:{pid}:rule"] = (n_p + j, True)
+        for gi, name in enumerate(self._group_order):
+            out_index[f"g:{name}:allowed"] = (2 * n_p + gi, False)
+            at = 2 * n_p + n_g + gi * self._max_group_members
+            for mi, mname in enumerate(groups[name].members):
+                out_index[f"g:{name}:eval:{mname}"] = (at + mi, False)
+        self._out_layout = OutputLayout(out_index, self._compact_outputs)
         # Host-executed (wasm) group members: their verdict bits enter the
         # fused program as the WASM_BITS_KEY input, one column per member
         # in this order. Standalone wasm policies are not listed — they
@@ -957,10 +981,14 @@ class EvaluationEnvironment:
         self._target_memo: dict[str, Any] = {}
         self._hooks_memo: dict[int, list] = {}
         self._blob_plain_memo: dict[int, bool] = {}
+        self._ckey_memo: dict[int, tuple[str, str]] = {}
         # fragment eligibility per target (round 19): whether a cached
         # row's response is a pure function of (target, output row) + uid
         # with identity constraints — see _frag_eligible
         self._frag_eligible_memo: dict[int, bool] = {}  # graftcheck: lockfree — GIL-atomic dict ops; racing builders store identical values
+        # per eligible target, the templates of the verdicts it has
+        # answered hits with (_frag_of); False for an ineligible target
+        self._frag_lanes: dict[int, Any] = {}  # graftcheck: lockfree — GIL-atomic dict ops; racing builders store identical values
         # rows answered as pre-serialized fragments (metrics surface)
         self._frag_hits = 0  # guarded-by: _fallback_lock
         # Pre-built output-key strings per policy/group: the per-row
@@ -1274,15 +1302,21 @@ class EvaluationEnvironment:
             self.payload_for(target, request), separators=(",", ":")
         ).encode()
 
-    @staticmethod
-    def _cache_key_of(target: "BoundPolicy | BoundGroup") -> tuple[str, str]:
+    def _cache_key_of(self, target: "BoundPolicy | BoundGroup") -> tuple[str, str]:
         """Stable per-environment identity of an evaluation target for the
         verdict cache. Top-level names are unique across policies and
         groups (policies.yml), the prefix keeps the spaces disjoint
-        regardless."""
-        if isinstance(target, BoundGroup):
-            return ("g", target.name)
-        return ("p", target.policy_id)
+        regardless. ONE tuple a target, shared by every cache key that
+        names it (a tuple a key would be 56 bytes an entry the byte bound
+        does not count)."""
+        ckey = self._ckey_memo.get(id(target))
+        if ckey is None:
+            ckey = self._ckey_memo[id(target)] = (
+                ("g", target.name)
+                if isinstance(target, BoundGroup)
+                else ("p", target.policy_id)
+            )
+        return ckey
 
     def _cacheable(self, target: "BoundPolicy | BoundGroup") -> bool:
         """Whether a target's verdict is a pure function of its payload
@@ -1368,53 +1402,89 @@ class EvaluationEnvironment:
         return ok
 
     def _frag_of(
-        self, target: "BoundPolicy | BoundGroup", row: Mapping[str, Any]
+        self, target: "BoundPolicy | BoundGroup", row: "bytes | Mapping[str, Any]"
     ) -> "FragTemplate | None":
-        """The cached row's FragTemplate for ``target`` — built lazily on
-        the FIRST hit (one materialize-equivalent pass per cached row ×
-        target, amortized over every later hit) and attached to the row
-        dict under FRAG_KEY. Dict stores are GIL-atomic and racing
-        builders produce identical templates; the attachment is not
-        counted by the eviction estimate, which is fine — it is bounded
-        to one tiny template per (row, target) pair. Returns None for
-        ineligible targets (the caller materializes normally)."""
-        frags = row.get(FRAG_KEY)
-        if frags is None:
-            frags = {}
-            row[FRAG_KEY] = frags  # type: ignore[index]
-        ckey = self._cache_key_of(target)
-        tmpl = frags.get(ckey)
+        """The FragTemplate of a cached row for ``target`` — built on the
+        FIRST hit of that target with that verdict (one
+        materialize-equivalent pass) and kept in the target's memo, keyed
+        by the target's OWN slice of the row: for an eligible target the
+        response is a pure function of the outputs its materializer reads
+        (_frag_eligible), so every later hit of any cached row with the
+        same slice costs one lookup, and no cached row is written to.
+        Packed rows (the device's) and dict rows (the host fast path's)
+        are keyed apart — their slices are raw bytes and decoded scalars.
+        Dict stores are GIL-atomic and racing builders produce identical
+        templates. Returns None for ineligible targets (the caller
+        materializes normally)."""
+        lane = self._frag_lanes.get(id(target))
+        if lane is None:
+            lane = self._frag_lane_of(target)
+        if lane is False:
+            return None
+        slice_of, memo = lane[type(row) is bytes]
+        own = slice_of(row)
+        tmpl = memo.get(own)
         if tmpl is None:
-            if not self._frag_eligible(target):
-                frags[ckey] = False
-                return None
-            # eligibility guarantees the payload is never touched and
-            # the uid is spliced per row, so materialize once with inert
-            # stand-ins and capture the template
-            resp = self._materialize_from_row(target, "", row)
-            st = resp.status
-            try:
-                tmpl = FragTemplate(
-                    allowed=resp.allowed,
-                    code=None if st is None else st.code,
-                    message=None if st is None else st.message,
-                    causes=(
-                        tuple(
-                            (c.field, c.message) for c in st.details.causes
-                        )
-                        if st is not None and st.details is not None
-                        else None
-                    ),
-                )
-            except UnicodeEncodeError:
-                # a static message json can represent but utf-8 cannot
-                # encode (lone surrogates survive json.loads): this
-                # target is permanently Python-rendered — the per-row
-                # path serializes it fine, a raised batch would not
-                frags[ckey] = False
-                return None
-            frags[ckey] = tmpl
+            if len(memo) >= _FRAG_MEMO_MAX:
+                memo.clear()
+            tmpl = memo[own] = self._frag_template(target, row)
         return tmpl or None  # False sentinel → None
+
+    def _frag_lane_of(self, target: "BoundPolicy | BoundGroup"):
+        """``(slice of a dict row, memo), (slice of a packed row, memo)``
+        over the keys ``target``'s materializer reads, or False for a
+        target that is not fragment-eligible. Memoized per target — the
+        registry is immutable post-boot."""
+        lane: Any = False
+        if self._frag_eligible(target):
+            if isinstance(target, BoundGroup):
+                allowed_key, members, _risky = self._group_mat[target.name]
+                keys = [allowed_key]
+                for e in members:
+                    keys.extend(e[2:5])  # eval, allowed, rule
+            else:
+                keys = list(self._single_mat[target.policy_id])
+            lane = (
+                (operator.itemgetter(*keys), {}),
+                (self._out_layout.slice_of(keys), {}),
+            )
+        self._frag_lanes[id(target)] = lane
+        return lane
+
+    def _frag_template(
+        self, target: "BoundPolicy | BoundGroup", row: "bytes | Mapping[str, Any]"
+    ) -> "FragTemplate | bool":
+        """The uid-independent response of an eligible ``target`` to
+        ``row``, or False where it cannot be pre-serialized."""
+        # eligibility guarantees the payload is never touched and the uid
+        # is spliced per row, so materialize once with inert stand-ins
+        # and capture the template
+        resp = self._materialize_from_row(target, "", self._row_face(row))
+        st = resp.status
+        try:
+            return FragTemplate(
+                allowed=resp.allowed,
+                code=None if st is None else st.code,
+                message=None if st is None else st.message,
+                causes=(
+                    tuple((c.field, c.message) for c in st.details.causes)
+                    if st is not None and st.details is not None
+                    else None
+                ),
+            )
+        except UnicodeEncodeError:
+            # a static message json can represent but utf-8 cannot encode
+            # (lone surrogates survive json.loads): this verdict of this
+            # target is permanently Python-rendered — the per-row path
+            # serializes it fine, a raised batch would not
+            return False
+
+    def _row_face(self, row: "bytes | Mapping[str, Any]") -> Mapping[str, Any]:
+        """What the materializers read of a cached row: a dict row as it
+        is, a packed row through its layout."""
+        if type(row) is bytes:
+            return PackedRow(self._out_layout, row)  # type: ignore[return-value]
+        return row
 
     def _materialize_from_row(
         self, target: "BoundPolicy | BoundGroup", uid: str, row: Mapping[str, Any]
@@ -1606,8 +1676,9 @@ class EvaluationEnvironment:
         (bench/metrics). ``cache_*`` keys are the row tier (legacy
         names); ``blob_*`` keys are the pre-encode blob tier."""
         off = dict.fromkeys(
-            ("cache_hits", "cache_misses", "cache_evictions",
-             "cache_entries", "cache_bytes", "cache_capacity"), 0
+            ("cache_hits", "cache_misses", "cache_evictions", "cache_puts",
+             "cache_put_bytes", "cache_entries", "cache_bytes",
+             "cache_capacity"), 0
         )
         stats = (
             self._verdict_cache.stats()
@@ -1972,32 +2043,7 @@ class EvaluationEnvironment:
 
     def _unpack(self, packed: np.ndarray) -> dict[str, np.ndarray]:
         """Packed device output → the per-key dict the materializers use."""
-        packed = np.asarray(packed)
-        n_p = len(self._policy_order)
-        n_g = len(self._group_order)
-        m = self._max_group_members
-        p_allowed = packed[:, :n_p] != 0
-        p_rule = packed[:, n_p : 2 * n_p].astype(np.int32)
-        if self._compact_outputs:
-            # uint8 wire form: the -1 "allowed" sentinel wrapped to 255
-            # (rule indices are bounded < 255, so 255 is unambiguous)
-            p_rule = np.where(p_rule == 255, -1, p_rule)
-        g_allowed = packed[:, 2 * n_p : 2 * n_p + n_g] != 0
-        g_eval = (
-            packed[:, 2 * n_p + n_g :].reshape(packed.shape[0], n_g, m) != 0
-            if n_g
-            else np.zeros((packed.shape[0], 0, 0), np.bool_)
-        )
-        out: dict[str, np.ndarray] = {}
-        for j, pid in enumerate(self._policy_order):
-            out[f"p:{pid}:allowed"] = p_allowed[..., j]
-            out[f"p:{pid}:rule"] = p_rule[..., j]
-        for gi, name in enumerate(self._group_order):
-            out[f"g:{name}:allowed"] = g_allowed[..., gi]
-            group = self._groups[name]
-            for mi, mname in enumerate(group.members):
-                out[f"g:{name}:eval:{mname}"] = g_eval[..., gi, mi]
-        return out
+        return self._out_layout.columns(np.asarray(packed))
 
     def _transport(self, features: Mapping[str, Any]) -> Mapping[str, Any]:
         """Wide packed batch → bit-packed transport form (roughly a
@@ -3121,8 +3167,8 @@ class EvaluationEnvironment:
         _rec = flightrec.recorder()
         _bid = flightrec.current_batch() if _rec is not None else -1
         overflowed: list[int] = []
-        # (device future, slot rows, wasm stash, row-tier insertions,
-        # blob-tier insertions) per chunk
+        # (device future, slot rows, wasm stash, row-tier puts, blob-tier
+        # puts) per chunk; a tier's puts are flat (key, slot) pairs
         drains: list[tuple] = []
         cache = self._verdict_cache
         bcache = self._blob_cache
@@ -3160,35 +3206,31 @@ class EvaluationEnvironment:
             return bl, out
 
         def materialize(entry) -> None:
-            fut, slot_rows, stash, lru_inserts, blob_inserts = entry
+            fut, slot_rows, stash, lru_puts, blob_puts = entry
             t0 = time.perf_counter_ns()
-            raw = fut.result()
+            raw = np.asarray(fut.result())
             t1 = time.perf_counter_ns()
             self._profile_add(dispatch_wait_ns=t1 - t0)
+            if lru_puts or blob_puts:
+                # a dispatched row's cache entry IS its bytes of the
+                # fetched array: one copy of the batch, a slice a slot,
+                # the same object under every key of both tiers
+                width = raw.shape[1] * raw.itemsize
+                fetched = raw.tobytes()
+                rows = [
+                    fetched[at : at + width]
+                    for at in range(0, len(fetched), width)
+                ]
+                if lru_puts:
+                    cache.put_many(
+                        [(key, rows[slot]) for key, slot in lru_puts]
+                    )
+                if blob_puts:
+                    bcache.put_many(
+                        [(key, rows[slot]) for key, slot in blob_puts]
+                    )
             outputs = self._unpack(raw)
             outputs.update(stash)
-            if lru_inserts or blob_inserts:
-                row_of_slot: dict[int, dict] = {}
-
-                def row_for(slot: int) -> dict:
-                    row_out = row_of_slot.get(slot)
-                    if row_out is None:
-                        row_out = extract_row(outputs, slot)
-                        row_of_slot[slot] = row_out
-                    return row_out
-
-                if lru_inserts:
-                    cache.put_many(
-                        (key, row_for(slot))
-                        for slot, keys in lru_inserts.items()
-                        for key in keys
-                    )
-                if blob_inserts:
-                    bcache.put_many(
-                        (key, row_for(slot))
-                        for slot, keys in blob_inserts.items()
-                        for key in keys
-                    )
             for slot, i in slot_rows:
                 _, request = items[i]
                 results[i] = self._materialize(
@@ -3239,8 +3281,8 @@ class EvaluationEnvironment:
                 overflowed.extend(
                     chunk[int(p)] for p in np.flatnonzero(~ok_mask)
                 )
-            lru_inserts: dict[int, set] = {}
-            blob_inserts: dict[int, list] = {}
+            lru_puts: list[tuple] = []
+            blob_puts: list[tuple] = []
             if cache is None:
                 slot_rows = [
                     (pos, i) for pos, i in enumerate(chunk) if ok_mask[pos]
@@ -3435,21 +3477,26 @@ class EvaluationEnvironment:
                         slot_rows = list(
                             zip(slots.tolist(), miss_items.tolist())
                         )
-                        # per-combo cache keys onto their dispatch slot
-                        miss_combos = np.flatnonzero(~hit_flags).tolist()
-                        if uniform_target:
-                            combo_rowuniq = np.arange(m)
+                        # per-combo cache keys onto their dispatch slot:
+                        # one vectorized pass over all the miss combos
+                        miss_combos = np.flatnonzero(~hit_flags)
+                        if keep_uncompacted:
+                            combo_slots = dedup_pos[combo_first[miss_combos]]
                         else:
-                            combo_rowuniq = uc % m
-                        for k in miss_combos:
-                            u = int(combo_rowuniq[k])
-                            if keep_uncompacted:
-                                slot = int(dedup_pos[int(combo_first[k])])
-                            else:
-                                slot = int(
-                                    np.searchsorted(uniq_miss, u)
-                                ) + len(wasm_pos)
-                            lru_inserts.setdefault(slot, set()).add(keys[k])
+                            # a combo's unique row, then that row's place
+                            # among the dispatched ones
+                            combo_slots = np.searchsorted(
+                                uniq_miss,
+                                miss_combos
+                                if uniform_target
+                                else uc[miss_combos] % m,
+                            ) + len(wasm_pos)
+                        lru_puts = [
+                            (keys[k], slot)
+                            for k, slot in zip(
+                                miss_combos.tolist(), combo_slots.tolist()
+                            )
+                        ]
                         if bcache is not None:
                             # blob→row learning is bounded to ONE
                             # representative per dispatched slot (plus the
@@ -3461,21 +3508,19 @@ class EvaluationEnvironment:
                             # replayed variants hit the row tier, whose
                             # (equally bounded) backfill inserts one more
                             # representative blob per combo per cycle.
-                            for j, pos in enumerate(
-                                dedup_pos[keep_rows].tolist()
-                            ):
-                                slot = (
-                                    pos
-                                    if keep_uncompacted
-                                    else j + len(wasm_pos)
-                                )
-                                i = chunk[pos]
-                                blob_inserts.setdefault(slot, []).append(
+                            blob_puts = [
+                                (
                                     (
-                                        self._cache_key_of(targets[i]),
+                                        self._cache_key_of(targets[chunk[pos]]),
                                         chunk_blobs[pos],
-                                    )
+                                    ),
+                                    pos if keep_uncompacted
+                                    else j + len(wasm_pos),
                                 )
+                                for j, pos in enumerate(
+                                    dedup_pos[keep_rows].tolist()
+                                )
+                            ]
                 wasm_rows = []
                 n_keep = len(wasm_pos) + int(keep_rows.size)
                 if wasm_pos:
@@ -3540,8 +3585,8 @@ class EvaluationEnvironment:
                 ),
                 slot_rows,
                 stash,
-                lru_inserts,
-                blob_inserts,
+                lru_puts,
+                blob_puts,
             )
             if defer_sink is not None:
                 defer_sink.append((materialize, entry))
@@ -3560,9 +3605,12 @@ class EvaluationEnvironment:
         self,
         target: BoundPolicy | BoundGroup,
         request: ValidateRequest,
-        outputs: Mapping[str, Any],
+        outputs: "bytes | Mapping[str, Any]",
     ) -> AdmissionResponse:
+        """``outputs``: a view of a dispatched row, or a cached row in
+        either of its forms (verdict_cache.py)."""
         uid = request.uid()
+        outputs = self._row_face(outputs)
         # payload materializes LAZILY: most verdicts (allowed, or rejected
         # with a static message) never need the parsed document, and for
         # wire requests from the prefork frontend payload() costs a JSON

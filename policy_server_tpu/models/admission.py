@@ -254,7 +254,8 @@ class AdmissionResponse:
 
 class FragTemplate:
     """The uid-independent part of a cached verdict's response,
-    pre-computed ONCE per (cached output row × target) so an
+    pre-computed ONCE per target and verdict (environment._frag_of keys
+    it by the target's own outputs in the cached row) so an
     all-cache-hit batch never re-runs response materialization
     (round 19: the flight recorder measured blob-tier cache-hit
     materialization at ~61 µs/row — almost all of it per-row

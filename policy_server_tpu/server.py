@@ -882,6 +882,18 @@ class PolicyServer:
                 ("tier",),
             )
             yield (
+                metrics_names.VERDICT_CACHE_PUTS, "counter",
+                "Entries put into the verdict cache, both tiers together",
+                dedup.get("cache_puts", 0) + dedup.get("blob_cache_puts", 0),
+            )
+            yield (
+                metrics_names.VERDICT_CACHE_PUT_BYTES, "counter",
+                "Bytes the byte budget accounted for those entries (key + "
+                "row + a constant each)",
+                dedup.get("cache_put_bytes", 0)
+                + dedup.get("blob_cache_put_bytes", 0),
+            )
+            yield (
                 metrics_names.BATCH_DEDUP_HITS, "counter",
                 "Rows answered by an identical row in the same batch",
                 dedup.get("batch_dup_hits", 0),

@@ -63,6 +63,10 @@ VERDICT_CACHE_BYTES = "policy_server_verdict_cache_bytes"
 # entries the byte bound pushed out, by tier ("blob" | "row"): a hit rate
 # says nothing of a tier that churns, this does
 VERDICT_CACHE_EVICTIONS = "policy_server_verdict_cache_evictions_total"
+# entries put and their accounted bytes, both tiers together: bytes over
+# puts is what one entry costs the byte budget (PR 32)
+VERDICT_CACHE_PUTS = "policy_server_verdict_cache_puts_total"
+VERDICT_CACHE_PUT_BYTES = "policy_server_verdict_cache_put_bytes_total"
 BATCH_DEDUP_HITS = "policy_server_batch_dedup_hits"
 FRAGMENT_HITS = "policy_server_fragment_hits"
 BUDGET_ROUTED_BATCHES = "policy_server_budget_routed_batches"

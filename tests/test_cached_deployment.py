@@ -13,6 +13,13 @@ guarantees (benchmarks/configs/flagship32-cached.json).
 * no answer carries another request's uid (the ``stale-uid`` fault);
 * a cache of a few KB evicts, ``policy_server_verdict_cache_evictions_total``
   counts it by tier on ``/metrics``, and the answers stay exact;
+* (PR 32) what the device path leaves in the tiers is the packed output
+  row: every entry of both tiers is ``bytes``, a rollout's hits answer
+  from them through the fragment lane and through ``_materialize``
+  (eighteen of the 32 policies are fragment-eligible, fourteen are not),
+  256Mi holds 90,000 entries a tier, and
+  ``policy_server_verdict_cache_puts_total`` /
+  ``..._put_bytes_total`` on ``/metrics`` read as ``cache_bytes_per_put``;
 * the benchmark's data files for the deployment: the manifest is sound,
   the two cells are the issue's letter for letter, every new layer metric
   reads the program's own counters and reads nothing on a program without
@@ -35,7 +42,7 @@ from policy_server_tpu.evaluation.environment import (
     DEFAULT_VERDICT_CACHE_SIZE,
     EvaluationEnvironmentBuilder,
 )
-from policy_server_tpu.evaluation.verdict_cache import VerdictCache
+from policy_server_tpu.evaluation.verdict_cache import VerdictCache, entry_cost
 from policy_server_tpu.models import (
     AdmissionReviewRequest,
     AdmissionReviewResponse,
@@ -66,7 +73,8 @@ CELLS = ("flagship32-cached.rollout-saturate",
          "flagship32-cached.unique-saturate")
 NEW_METRICS = ("device_answered_share", "row_tier_hit_share",
                "batch_duplicate_share", "launched_batch_share",
-               "bookkeeping_ms_mean", "cache_evictions_in_window")
+               "bookkeeping_ms_mean", "cache_evictions_in_window",
+               "cache_bytes_per_put")
 SIGNED = set(CONFIG["signing"]["signed_images"])
 SEED = 2**31 + 31
 POOL = 61  # a prime, as the cells' 16,381 is: a shape meets every policy
@@ -111,13 +119,15 @@ def _sources(env) -> dict[str, int]:
 
 
 def _serve(env, batcher, policies: dict, stream: str, threads: int,
-           base: int, count: int) -> dict:
+           base: int, count: int, reset: bool = True) -> dict:
     """Requests ``base .. base + count`` of the stream through the
     batcher, thread k of K submitting n = k mod K as client k of K does;
-    the tiers start empty, their counters are read around the stream."""
+    the tiers start empty unless ``reset`` is false, their counters are
+    read around the stream."""
     ids = list(policies)
     traffic = Traffic(MIXES[stream], SEED, ids)
-    env.reset_verdict_cache()
+    if reset:
+        env.reset_verdict_cache()
     before = _sources(env)
     futures: list = [None] * count
 
@@ -202,12 +212,60 @@ def test_a_rollout_is_answered_by_the_tiers_and_uniques_by_the_device(
     assert rollout["got"] == rollout["want"]
 
 
+def test_hits_answer_from_packed_entries_through_both_lanes(
+        deployment, policies):
+    """PR 32: after a rollout through the batcher every entry of both
+    tiers is the packed row the device returned (80 bytes here), put at
+    the constant-time cost, and the tiers' hits were served from them
+    through the fragment lane (eligible targets) AND through
+    ``_materialize`` over the packed row (the others), byte for byte as
+    the reference answers."""
+    env, batcher = deployment
+    eligible = [env._frag_eligible(env._fast_target(pid)) for pid in policies]
+    assert 0 < sum(eligible) < len(eligible)  # both kinds among the 32
+    # two whole blocks (two shapes under all 32 policies), then the same
+    # requests again over the tiers they filled: nothing reaches the
+    # device, and in every batch the first request of each (policy,
+    # shape) pair is a tier hit of a packed entry
+    first = _serve(env, batcher, policies, "rollout", 4, 256 * 500, 512)
+    assert first["got"] == first["want"]
+    frag0 = env.dedup_stats["fragment_hits"]
+    out = _serve(env, batcher, policies, "rollout", 4, 256 * 500, 512,
+                 reset=False)
+    assert out["got"] == out["want"]
+    assert out["moved"]["device"] == 0 and sum(out["moved"].values()) == 512
+    stats = env.dedup_stats
+    tier_hits = out["moved"]["row_tier"] + out["moved"]["blob_tier"]
+    frag_hits = stats["fragment_hits"] - frag0
+    assert tier_hits >= 64
+    assert 0 < frag_hits < tier_hits  # some spliced, some materialized
+    width = CONFIG["verdict_bytes_per_row"]["value"]
+    assert width == 80 == len(env._out_layout.index)
+    for tier in (env._verdict_cache, env._blob_cache):
+        entries = list(tier._data.items())
+        assert entries
+        assert all(type(row) is bytes and len(row) == width
+                   for _key, row in entries)
+        assert tier.bytes_used == sum(
+            256 + len(key[1]) + width for key, _row in entries)
+    # no template hangs on a row: they are in the environment, at most one
+    # per target and verdict
+    lanes = [lane for lane in env._frag_lanes.values() if lane]
+    assert lanes and all(len(memo) <= 4 for lane in lanes for _f, memo in lane)
+    row_keys = [len(key[1]) for key in env._verdict_cache._data]
+    per_entry = stats["cache_bytes"] / stats["cache_entries"]
+    assert per_entry == 256 + row_keys[0] + width
+    assert (DEFAULT_VERDICT_CACHE_SIZE // 2) // per_entry >= 90_000
+
+
 # -- a cache too small for its traffic -----------------------------------------
 
 
 @pytest.mark.parametrize("stream", ["rollout", "unique"])
 def test_a_cache_of_a_few_kb_evicts_by_tier_and_stays_exact(policies, stream):
-    env = _build(policies, verdict_cache_size=64 * 1024)
+    # an entry is ~1.4 KB (PR 32; ~7.7 KB before): an 8 KiB tier holds 5,
+    # under the 13-17 rows even a rollout of two shapes dispatches
+    env = _build(policies, verdict_cache_size=16 * 1024)
     batcher = _batcher(env)
     try:
         out = _serve(env, batcher, policies, stream, 4, 80_000, 512)
@@ -219,8 +277,11 @@ def test_a_cache_of_a_few_kb_evicts_by_tier_and_stays_exact(policies, stream):
     assert sum(out["moved"].values()) == 512
     for tier in ("", "blob_"):
         assert stats[tier + "cache_evictions"] > 0
-        assert stats[tier + "cache_bytes"] <= 32 * 1024
-        assert stats[tier + "cache_entries"] < 16
+        assert stats[tier + "cache_bytes"] <= 8 * 1024
+        assert stats[tier + "cache_entries"] < 8
+        # (a key two batches in flight both missed is put twice)
+        assert stats[tier + "cache_puts"] >= (
+            stats[tier + "cache_evictions"] + stats[tier + "cache_entries"])
 
 
 @pytest.mark.parametrize("puts, capacity_entries, reputs", [
@@ -228,7 +289,8 @@ def test_a_cache_of_a_few_kb_evicts_by_tier_and_stays_exact(policies, stream):
 def test_the_cache_counts_what_its_byte_bound_pushes_out(
         puts, capacity_entries, reputs):
     row = {"allowed": True}
-    cost = 256 + 80 + 8  # entry_cost of ((), 8 key bytes) over this row
+    cost = 256 + 80 + 8  # entry_cost of (("t",), 8 key bytes) over this row
+    assert cost == entry_cost((("t",), b"%08d" % 0), row)
     cache = VerdictCache(capacity_entries * cost)
     cache.put_many(((("t",), b"%08d" % i), row) for i in range(puts))
     for _ in range(reputs):  # a live key put again replaces, evicts nothing
@@ -280,6 +342,51 @@ def test_the_eviction_counter_is_on_metrics_by_tier():
         "before": before, "after": after}) == sum(by_tier.values())
 
 
+def test_the_put_counters_are_on_metrics_and_read_as_bytes_per_put():
+    """Forty distinct pods through a served policy; the two counters (one series each, both tiers
+    together) move by the puts and their accounted bytes, and the layer
+    metric's data file reads their ratio with the benchmark's reader."""
+    metrics_mod.reset_metrics_for_tests()
+    handle = ServerHandle(Config(
+        addr="127.0.0.1", port=0, readiness_probe_port=0,
+        tls_config=TlsConfig(),
+        policies={"priv": parse_policy_entry(
+            "priv", {"module": "builtin://pod-privileged"})},
+        policy_timeout_seconds=30.0, max_batch_size=8, batch_timeout_ms=1.0,
+        host_fastpath_threshold=0, latency_budget_ms=0, warmup_at_boot=True,
+    ))
+    try:
+        def scrape() -> reduce.Samples:
+            r = requests.get(handle.readiness_url("/metrics"), timeout=10)
+            return reduce.parse_metrics(r.text)
+
+        traffic = Traffic(MIXES["unique"], SEED, ["priv"])
+        before = scrape()
+        for n in range(40):
+            body = traffic.request(n).partition(b"\r\n\r\n")[2]
+            r = requests.post(handle.url("/validate/priv"), data=body, headers={
+                "Content-Type": "application/json"}, timeout=30)
+            assert r.json()["response"]["uid"] == uid_of(n)
+        after = scrape()
+        stats = handle.server.environment.dedup_stats
+    finally:
+        handle.stop()
+        metrics_mod.reset_metrics_for_tests()
+    puts = reduce.delta(before, after, metrics_mod.VERDICT_CACHE_PUTS)
+    put_bytes = reduce.delta(before, after, metrics_mod.VERDICT_CACHE_PUT_BYTES)
+    # a put a distinct encoded row in the row tier (a pod's name and uid
+    # are no features of this policy), a put a payload in the blob tier
+    assert puts == stats["cache_puts"] + stats["blob_cache_puts"]
+    assert 0 < stats["cache_puts"] <= stats["blob_cache_puts"] == 40
+    assert put_bytes == stats["cache_put_bytes"] + stats["blob_cache_put_bytes"]
+    # nothing was evicted, so what was put is what is resident
+    assert put_bytes == stats["cache_bytes"] + stats["blob_cache_bytes"]
+    got = reduce.read_layer_metric("cache_bytes_per_put", {
+        "before": before, "after": after})
+    assert got == pytest.approx(put_bytes / puts)
+    assert 256 < got < 256 + 4096  # a key and a packed row, not 80 boxed scalars
+
+
 # -- the benchmark's data files for the deployment --------------------------------
 
 
@@ -303,6 +410,26 @@ def test_the_manifest_with_the_deployment_is_sound():
     for key in ("policies", "signing", "response_head", "row_bytes_dense",
                 "verdict_bytes_per_row", "chips", "mesh"):
         assert CONFIG[key] == accepted[key]
+
+
+def test_the_manifest_with_the_bytes_per_put_metric_is_sound():
+    """PR 32 adds one per-layer entry, last in its list, and one data
+    file; nothing else of the benchmark moves."""
+    assert check_manifest.problems(MANIFEST, ROOT) == []
+    assert MANIFEST["per_layer"][-1] == {
+        "name": "cache_bytes_per_put", "unit": "bytes", "better": "lower",
+        "source": "program_counter",
+        "layer": "dedup tiers in front of the device (evaluation/"
+                 "verdict_cache.py, environment.py _native_schema_pass)",
+        "moves": "reviews_per_s", "workloads": list(CELLS)}
+    spec = json.loads(
+        (BENCH / "layer_metrics" / "cache_bytes_per_put.json").read_text())
+    assert spec["reader"] == "counter_ratio" and "module" not in spec
+    assert (spec["numerator"], spec["denominator"]) == (
+        metrics_mod.VERDICT_CACHE_PUT_BYTES, metrics_mod.VERDICT_CACHE_PUTS)
+    layers = {m["layer"] for m in MANIFEST["per_layer"]
+              if m["name"] in NEW_METRICS}
+    assert len(layers) == 1  # the layer's name, letter for letter
 
 
 @pytest.mark.parametrize("cell, traffic, extra", [
@@ -386,11 +513,13 @@ WINDOW = {  # a window of 150,000 answers in 2,000 batches, 300 launched
     PHASE % ("sum", "bookkeeping"): 3.0,
     'policy_server_verdict_cache_evictions_total{tier="blob"}': 20_000,
     'policy_server_verdict_cache_evictions_total{tier="row"}': 1_500,
+    "policy_server_verdict_cache_puts_total": 10_000,
+    "policy_server_verdict_cache_put_bytes_total": 14_000_000,
 }
 
 
 @pytest.mark.parametrize("name, want", zip(NEW_METRICS, (
-    3.0, 40.0, 57.0, 15.0, 1.5, 21_500.0)))
+    3.0, 40.0, 57.0, 15.0, 1.5, 21_500.0, 1_400.0)))
 def test_a_new_layer_metric_reads_the_programs_counters(name, want):
     before, after = _planted(WINDOW)
     ctx = {"before": before, "after": after}

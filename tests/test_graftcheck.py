@@ -387,9 +387,14 @@ def test_verdict_cache_len_and_bytes_consistent_under_concurrent_puts():
     for t in threads:
         t.join(timeout=5)
     assert not errors
-    # post-quiescence invariant: accounted bytes match the entries
+    # post-quiescence invariant: accounted bytes match the entries (a
+    # row is immutable once put, so its cost is a function of key and row)
+    from policy_server_tpu.evaluation.verdict_cache import entry_cost
+
     with cache._lock:
-        assert cache._bytes == sum(c for _row, c in cache._data.values())
+        assert cache._bytes == sum(
+            entry_cost(key, row) for key, row in cache._data.items()
+        )
     assert cache.bytes_used <= cache.capacity_bytes
 
 

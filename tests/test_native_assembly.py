@@ -265,17 +265,20 @@ def test_surrogate_static_message_falls_back_to_python(catalog_env):
 
     env = catalog_env
     target = env._fast_target("pod-privileged")
-    row: dict = {}
+    row = {"p:pod-privileged:allowed": False, "p:pod-privileged:rule": 0}
     bad = AdmissionResponse(
         uid="", allowed=False,
         status=ValidationStatus(message="\ud800bad", code=400),
     )
+    env._frag_lanes.pop(id(target), None)
     with mock.patch.object(env, "_materialize_from_row", return_value=bad):
         assert env._frag_of(target, row) is None
-    # memoized permanently ineligible for THIS row x target
-    from policy_server_tpu.evaluation.environment import FRAG_KEY
-
-    assert row[FRAG_KEY][env._cache_key_of(target)] is False
+    # memoized permanently ineligible for THIS verdict of this target, in
+    # the target's memo (the row is left as it was put)
+    (_own, memo), _packed = env._frag_lanes[id(target)]
+    assert memo == {(False, 0): False}
+    assert set(row) == {"p:pod-privileged:allowed", "p:pod-privileged:rule"}
+    env._frag_lanes.pop(id(target))  # the catalog env is shared
     # and the per-row Python renderer handles the shape fine
     assert nf.pack_verdict_record(1, bad, False) is None
     assert json.dumps(AdmissionReviewResponse(bad).to_dict())

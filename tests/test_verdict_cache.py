@@ -7,13 +7,24 @@ its own materialized patch."""
 
 from __future__ import annotations
 
+import gc
+import os
+import tracemalloc
+
+import numpy as np
 import pytest
 
 from policy_server_tpu.evaluation.environment import (
     DEFAULT_VERDICT_CACHE_SIZE,
     EvaluationEnvironmentBuilder,
 )
-from policy_server_tpu.evaluation.verdict_cache import VerdictCache, extract_row
+from policy_server_tpu.evaluation.verdict_cache import (
+    _ENTRY_OVERHEAD,
+    OutputLayout,
+    PackedRow,
+    VerdictCache,
+    entry_cost,
+)
 from policy_server_tpu.models import AdmissionReviewRequest, ValidateRequest
 from policy_server_tpu.models.policy import parse_policy_entry
 
@@ -225,8 +236,6 @@ def test_lru_eviction_bounds_bytes():
     """Capacity is BYTES (round 6): inserting past the budget evicts
     oldest-first, newest entries survive, and the resident-byte gauge
     stays at or under the budget."""
-    from policy_server_tpu.evaluation.verdict_cache import entry_cost
-
     one = entry_cost(("p", bytes([0])), {"v": 0})
     c = VerdictCache(4 * one)
     for k in range(10):
@@ -250,24 +259,302 @@ def test_get_many_put_many_batched_lock_semantics():
 def test_default_cache_size_is_working_set_scale():
     """The round-5 default (4,096 rows) was smaller than the benchmark's
     own 12,500-template working set; the byte default must comfortably
-    hold that working set in both tiers (~6 KB/entry upper estimate)."""
+    hold that working set in both tiers (an entry was ~6 KB then; since
+    PR 32 it is ~1.4 KB, see the packed-row cases below)."""
     assert DEFAULT_VERDICT_CACHE_SIZE >= 2 * 12_500 * 6_000
-
-
-def test_extract_row_detaches_from_batch():
-    import numpy as np
-
-    outputs = {
-        "a": np.arange(8, dtype=np.int32),
-        "b": np.ones((8, 3), dtype=np.bool_),
-        "s": [None] * 8,
-    }
-    row = extract_row(outputs, 2)
-    assert row["a"] == 2 and isinstance(row["a"], int)
-    outputs["b"][2, :] = False
-    assert row["b"].all()  # copied, not a view
-    assert row["s"] is None
 
 
 def test_default_cache_size_is_on():
     assert DEFAULT_VERDICT_CACHE_SIZE > 0
+
+
+# -- PR 32: a device-produced entry is the packed output row --------------------
+
+
+def _wide_rules_resolver(url: str):
+    """``builtin://wide-rules``: a policy of 300 rules, the last the only
+    one that can fire. Its rule index (299) does not fit the uint8 wire
+    form, so an environment that loads it returns int32 rows
+    (``_compact_outputs`` false)."""
+    from policy_server_tpu.ops.compiler import PolicyProgram, Rule
+    from policy_server_tpu.ops.ir import false
+    from policy_server_tpu.policies import resolve_builtin
+    from policy_server_tpu.policies.base import BuiltinPolicy
+
+    if url != "builtin://wide-rules":
+        return resolve_builtin(url)
+    last = resolve_builtin("builtin://pod-privileged").build({}).rules[0]
+
+    class WideRules(BuiltinPolicy):
+        name = "wide-rules"
+
+        def build(self, settings):
+            never = tuple(
+                Rule(f"never-{k}", false(), "unreachable") for k in range(299)
+            )
+            return PolicyProgram(rules=never + (last,))
+
+    return WideRules()
+
+
+@pytest.fixture(scope="module")
+def wide_env():
+    env = EvaluationEnvironmentBuilder(
+        backend="jax", module_resolver=_wide_rules_resolver
+    ).build(parse_all({**POLICIES, "wide": {"module": "builtin://wide-rules"}}))
+    yield env
+    env.close()
+
+
+def _fetched(env, requests: list[ValidateRequest]) -> np.ndarray:
+    """The array the device returns for these requests, one row each."""
+    schema = env.schemas[0]
+    blobs = [r.payload_json() for r in requests]
+    features, status = schema.native.encode_batch(
+        blobs, env.bucket_for(len(blobs)), env.table
+    )
+    assert not np.asarray(status)[: len(blobs)].any()
+    return np.asarray(env._device_fetch(env._dispatch_features(features)))
+
+
+# allowed everywhere; rejected by pod-privileged's rule (and by it the
+# group, member b); rejected by namespace-validate's rule as well
+ROW_KINDS = {
+    "allowed": pod_request("fine", False),
+    "rejected-by-a-rule-and-the-group": pod_request("fine", True),
+    "rejected-by-every-policy": pod_request("blocked", True),
+}
+
+
+@pytest.mark.parametrize("kind", list(ROW_KINDS))
+@pytest.mark.parametrize("form", ["uint8", "int32"])
+def test_the_packed_rows_face_reads_what_the_batch_decoder_reads(
+    envs, wide_env, form, kind
+):
+    """One layout, one decoder: every output key of a packed row reads,
+    value and Python type, as the column ``_unpack`` gives the
+    materializers of dispatched rows, in both wire forms."""
+    env = envs["on"] if form == "uint8" else wide_env
+    assert env._compact_outputs == (form == "uint8")
+    raw = _fetched(env, list(ROW_KINDS.values()))
+    assert raw.dtype == (np.uint8 if form == "uint8" else np.int32)
+    slot = list(ROW_KINDS).index(kind)
+    columns = env._unpack(raw)
+    face = PackedRow(env._out_layout, raw[slot].tobytes())
+    assert set(columns) == set(env._out_layout.index)
+    assert len(columns) == raw.shape[1]  # no element unread, none twice
+    for key, column in columns.items():
+        want = column[slot].item()  # what extract_row used to store
+        assert face[key] == want and type(face[key]) is type(want), key
+        assert face.get(key, "absent") == want
+        assert type(want) is (int if key.endswith(":rule") else bool)
+    assert face.get("wm:grp/a:mutated", False) is False  # no such key
+    # the verdicts this row kind was built for, rule sentinel included
+    allowed = face["p:priv:allowed"]
+    assert allowed == (kind == "allowed")
+    assert face["p:priv:rule"] == (-1 if allowed else 0)
+    assert face["g:grp:allowed"] == allowed
+    assert face["g:grp:eval:a"] is True and face["g:grp:eval:b"] is True
+    assert face["p:ns:rule"] == (0 if kind == "rejected-by-every-policy" else -1)
+    if form == "int32":
+        assert face["p:wide:rule"] == (-1 if allowed else 299)
+
+
+def test_a_rule_index_of_254_is_not_the_sentinel():
+    layout = OutputLayout({"p:x:allowed": (0, False), "p:x:rule": (1, True)}, True)
+    assert PackedRow(layout, bytes([0, 254]))["p:x:rule"] == 254
+    assert PackedRow(layout, bytes([1, 255]))["p:x:rule"] == -1
+    wide = OutputLayout(layout.index, False)
+    row = np.array([0, 255], np.int32).tobytes()
+    assert PackedRow(wide, row)["p:x:rule"] == 255  # int32 rows wrap nothing
+    assert PackedRow(wide, np.array([1, -1], np.int32).tobytes())["p:x:rule"] == -1
+
+
+@pytest.mark.parametrize("form", ["uint8", "int32"])
+def test_device_entries_are_the_fetched_bytes_and_hits_answer_from_them(
+    envs, wide_env, form
+):
+    """What the device path puts is ``bytes``, one object under both
+    tiers' keys; row-tier and blob-tier hits on it (fragment lane off:
+    ``_materialize`` over the packed row) answer as the cache-off
+    environment does, for a single policy and for a group."""
+    env = envs["on"] if form == "uint8" else wide_env
+    env.reset_verdict_cache()
+    ids = ["priv", "ns", "grp"] + (["wide"] if form == "int32" else [])
+    first = [(pid, pod_request("blocked", True, uid=f"a-{pid}")) for pid in ids]
+    want = [r.to_dict() for r in envs["off"].validate_batch(first)] if (
+        form == "uint8") else None
+    got = env.validate_batch(first)
+    if want is not None:
+        assert [r.to_dict() for r in got] == want
+    rows = {id(v) for v in env._verdict_cache._data.values()}
+    blob_rows = {id(v) for v in env._blob_cache._data.values()}
+    assert all(type(v) is bytes for v in env._verdict_cache._data.values())
+    assert len(rows) == 1 and rows == blob_rows  # one dispatched row, shared
+    s0 = env.dedup_stats
+    again = env.validate_batch(  # fresh uids: row tier
+        [(pid, pod_request("blocked", True, uid=f"b-{pid}")) for pid in ids])
+    replay = env.validate_batch(first)  # the same bytes: blob tier
+    s1 = env.dedup_stats
+    # (the blob tier learns ONE payload a dispatched slot, so of the
+    # replay one request hits it and the others the row tier)
+    moved = {k: s1[k] - s0[k] for k in ("cache_hits", "blob_cache_hits")}
+    assert sum(moved.values()) == 2 * len(ids) and moved["blob_cache_hits"] >= 1
+    for a, b, c in zip(got, again, replay):
+        assert not a.allowed
+        assert c.to_dict() == a.to_dict()
+        assert {**b.to_dict(), "uid": a.uid} == a.to_dict()
+    if form == "int32":
+        assert again[3].status.message == "Privileged container is not allowed"
+
+
+def test_host_and_device_paths_share_a_key_in_both_directions(envs):
+    """A dict row the host fast path put answers the device path, and a
+    packed row the device path put answers the host fast path: the same
+    keys, two forms of row under one VerdictCache, both exact."""
+    env, off = envs["on"], envs["off"]
+    for pid in ("priv", "grp", "ns"):
+        want = off.validate_batch([(pid, pod_request("blocked", True, uid="x"))])[0]
+        # host put -> device-path hit
+        env.reset_verdict_cache()
+        env.validate_batch([(pid, pod_request("blocked", True, uid="h-1"))],
+                           prefer_host=True)
+        assert all(isinstance(v, dict) for v in env._verdict_cache._data.values())
+        p0, s0 = env.host_profile, env.dedup_stats
+        dev = env.validate_batch([(pid, pod_request("blocked", True, uid="x"))])[0]
+        assert env.host_profile["dispatched_rows"] == p0["dispatched_rows"]
+        assert env.dedup_stats["cache_hits"] == s0["cache_hits"] + 1
+        assert dev.to_dict() == want.to_dict()
+        # device put -> host-path hit (row tier, then blob tier)
+        env.reset_verdict_cache()
+        env.validate_batch([(pid, pod_request("blocked", True, uid="d-1"))])
+        assert all(type(v) is bytes for v in env._verdict_cache._data.values())
+        s0 = env.dedup_stats
+        fast = env.validate_batch(
+            [(pid, pod_request("blocked", True, uid="x"))], prefer_host=True)[0]
+        exact = env.validate_batch(
+            [(pid, pod_request("blocked", True, uid="d-1"))], prefer_host=True)[0]
+        s1 = env.dedup_stats
+        assert s1["cache_hits"] == s0["cache_hits"] + 1
+        assert s1["blob_cache_hits"] == s0["blob_cache_hits"] + 1
+        assert s1["cache_puts"] == s0["cache_puts"]  # nothing evaluated again
+        assert fast.to_dict() == want.to_dict()
+        assert {**exact.to_dict(), "uid": "x"} == want.to_dict()
+
+
+def test_fragment_templates_live_in_the_environment_not_on_the_rows(envs):
+    """The hit lane's templates: one per (target, the target's own slice
+    of the row), whatever cached row the hit came from and in whichever
+    form, and no cached row is written to."""
+    env = envs["on"]
+    env.reset_verdict_cache()
+    env._frag_lanes.clear()
+    target = env._fast_target("priv")
+    env.validate_batch([("priv", pod_request("fine", True, uid="d"))])
+    env.validate_batch([("ns", pod_request("blocked", True, uid="d"))])
+    (row_a,) = [v for k, v in env._verdict_cache._data.items() if k[0] == ("p", "priv")]
+    (row_b,) = [v for k, v in env._verdict_cache._data.items() if k[0] == ("p", "ns")]
+    assert row_a != row_b  # two dispatched rows, the same verdict of priv
+    tmpl = env._frag_of(target, row_a)
+    assert tmpl is not None and tmpl.allowed is False
+    assert tmpl.message == "Privileged container is not allowed"
+    assert env._frag_of(target, row_b) is tmpl  # one lookup, no rebuild
+    assert type(row_a) is bytes and type(row_b) is bytes
+    # the host oracle's dict row of the same verdict: its own memo, an
+    # equal template, and the dict is left as it was put
+    host_row = env._oracle_outputs_for(target, pod_request("fine", True).payload())
+    before = dict(host_row)
+    host_tmpl = env._frag_of(target, host_row)
+    assert host_row == before
+    assert (host_tmpl.allowed, host_tmpl.code, host_tmpl.message) == (
+        tmpl.allowed, tmpl.code, tmpl.message)
+    assert env._frag_of(target, dict(before)) is host_tmpl
+    # a target that is not eligible (dynamic message) has no lane
+    assert env._frag_of(env._fast_target("ns"), row_b) is None
+    assert env._frag_lanes[id(env._fast_target("ns"))] is False
+
+
+KEY_BYTES, ROW_BYTES = 1064, 80  # flagship32: the packed row key, the output row
+
+
+def test_a_packed_entry_costs_its_bytes_and_no_more_work():
+    """The accounted cost of a packed entry is the constant plus the key's
+    and the row's bytes, whatever the row holds; ``put_many`` asks the row
+    for nothing but its length."""
+
+    class Opaque(bytes):
+        """A packed row that fails on any look inside it."""
+
+        def __iter__(self):
+            raise AssertionError("put_many iterated a packed row")
+
+        def __getitem__(self, at):
+            raise AssertionError("put_many indexed a packed row")
+
+    ckey = ("p", "some-policy")
+    rows = [Opaque(os.urandom(ROW_BYTES)) for _ in range(64)]
+    pairs = [((ckey, os.urandom(KEY_BYTES)), row) for row in rows]
+    cost = _ENTRY_OVERHEAD + KEY_BYTES + ROW_BYTES
+    assert cost == 1400
+    assert all(entry_cost(key, row) == cost for key, row in pairs)
+    cache = VerdictCache(128 * 1024 * 1024)
+    cache.put_many(pairs)
+    stats = cache.stats()
+    assert stats["cache_bytes"] == stats["cache_put_bytes"] == 64 * cost
+    assert stats["cache_puts"] == stats["cache_entries"] == 64
+    cache.put_many(pairs[:8])  # a live key put again: counted, not resident twice
+    stats = cache.stats()
+    assert (stats["cache_puts"], stats["cache_put_bytes"]) == (72, 72 * cost)
+    assert (stats["cache_entries"], stats["cache_bytes"]) == (64, 64 * cost)
+    # each tier's half of the default holds at least 90,000 such entries
+    assert (DEFAULT_VERDICT_CACHE_SIZE // 2) // cost >= 90_000
+
+
+def test_the_byte_bound_evicts_packed_and_dict_rows_by_their_own_cost():
+    """Costs are not stored: an entry that leaves gives back what it took,
+    so a tier of both forms returns to zero bytes and never exceeds its
+    bound."""
+    packed = entry_cost((("p", "x"), b"k" * 100), b"r" * 80)
+    host = entry_cost((("p", "x"), b"k" * 100), {"a": True, "b": -1})
+    assert (packed, host) == (256 + 100 + 80, 256 + 100 + 160)
+    cache = VerdictCache(3 * host)
+    for n in range(40):
+        row = b"r" * 80 if n % 2 else {"a": True, "b": -1}
+        cache.put((("p", "x"), b"%0100d" % n), row)
+        assert cache.bytes_used <= cache.capacity_bytes
+    stats = cache.stats()
+    assert stats["cache_evictions"] == 40 - stats["cache_entries"] > 30
+    live = list(cache._data.items())
+    assert stats["cache_bytes"] == sum(entry_cost(k, v) for k, v in live)
+    cache.clear()
+    assert cache.bytes_used == 0
+
+
+def test_the_accounted_bytes_are_not_under_the_resident_bytes():
+    """The bound is honest: for entries of this configuration's sizes
+    (one shared target tuple, a fresh key and a fresh row each, as the
+    device path puts them) CPython keeps no more than the cache accounts."""
+    n = 20_000
+    ckey = ("p", "some-policy")
+    cache = VerdictCache(1 << 30)
+    gc.collect()
+    # a server booted with pprof in this process keeps tracing for its
+    # heap profile: leave the tracer as it was found
+    was_tracing = tracemalloc.is_tracing()
+    if not was_tracing:
+        tracemalloc.start()
+    try:
+        base = tracemalloc.get_traced_memory()[0]
+        for lo in range(0, n, 100):
+            cache.put_many(
+                ((ckey, os.urandom(KEY_BYTES)), os.urandom(ROW_BYTES))
+                for _ in range(100)
+            )
+        resident = tracemalloc.get_traced_memory()[0] - base
+    finally:
+        if not was_tracing:
+            tracemalloc.stop()
+    accounted = cache.bytes_used
+    assert accounted == n * (_ENTRY_OVERHEAD + KEY_BYTES + ROW_BYTES)
+    assert resident <= accounted, (resident / n, accounted / n)
+    assert resident > 0.9 * accounted  # and not far over either
