@@ -176,12 +176,6 @@ def _flag_specs() -> list[tuple[str, str | None, dict[str, Any]]]:
                    "indices stay on the device. 'off' restores the "
                    "row-packed transport. Multi-process meshes always "
                    "use the packed transport")),
-        ("--donate-buffers", "KUBEWARDEN_DONATE_BUFFERS",
-         dict(default="on", metavar="MODE", choices=["on", "off"],
-              help="Donate columnar input buffers on dispatch "
-                   "(jax donate_argnums) so the device transport does not "
-                   "round-trip dead input buffers; 'off' disables "
-                   "donation (diagnostic)")),
         ("--predicate-opt", "KUBEWARDEN_PREDICATE_OPT",
          dict(default="on", metavar="MODE", choices=["on", "off"],
               help="Predicate-program optimizer (round 15): before "

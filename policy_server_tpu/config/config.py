@@ -209,8 +209,6 @@ class Config:
     # bit-packed / dictionary-narrowed column planes with all-zero
     # columns elided; False restores the row-packed transport
     columnar: bool = True
-    # donate columnar input buffers on dispatch (jax donate_argnums)
-    donate_buffers: bool = True
     # predicate-program optimizer (round 15, ops/optimizer.py):
     # cross-policy CSE + constant folding + dead-field/mask pruning
     # before lowering; False restores the naive per-policy lowering
@@ -616,7 +614,6 @@ class Config:
             breaker_window_seconds=float(args.breaker_window_seconds),
             breaker_cooldown_seconds=float(args.breaker_cooldown_seconds),
             columnar=args.columnar == "on",
-            donate_buffers=args.donate_buffers == "on",
             predicate_opt=args.predicate_opt == "on",
             degraded_mode=args.degraded_mode,
             policy_reload_mode=args.policy_reload_mode,

@@ -35,7 +35,7 @@ import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
-from typing import Any, Callable, Mapping
+from typing import Any, Callable, Iterable, Mapping
 
 import jax
 import jax.numpy as jnp
@@ -59,9 +59,9 @@ from policy_server_tpu.evaluation.precompiled import (
 )
 from policy_server_tpu.evaluation.settings import PolicyEvaluationSettings
 from policy_server_tpu.evaluation.verdict_cache import (
+    DedupTiers,
     OutputLayout,
     PackedRow,
-    VerdictCache,
 )
 from policy_server_tpu.models import (
     AdmissionResponse,
@@ -104,20 +104,11 @@ GROUP_MUTATION_MESSAGE = "mutation is not allowed inside of policy group"
 # participate in the fused on-device group reduction.
 WASM_BITS_KEY = "__wasm_bits__"
 
-# Default verdict-cache budget in BYTES, split evenly between the blob
-# tier (pre-encode exact-replay dedup) and the row tier (post-encode
-# uid-insensitive dedup) — see verdict_cache.py for why both tiers exist.
-# Sized to working-set scale: the round-5 default of 4,096 ROWS was
-# smaller than the benchmark's own 12,500-template working set, so the
-# cross-batch cache thrashed. An entry is one (policy, payload) or
-# (policy, encoded row) key over the device's packed output row: ~1.4 KB
-# with the flagship set's 1,064-byte row key and 80-byte output row, so
-# each tier's half of 256 MiB holds ~95,000 entries (verdict_cache.py).
+# Default budget of the dedup tiers in BYTES, sized to working-set scale
+# (verdict_cache.py: each tier's half holds ~95,000 flagship entries).
 # 0 disables caching AND in-batch row dedup.
 DEFAULT_VERDICT_CACHE_SIZE = 256 * 1024 * 1024
 
-
-_donation_warning_silenced = False
 
 # -- pre-serialized cache-hit fragments (round 19) ---------------------------
 # A hit row of a fragment-eligible target answers as uid + FragTemplate;
@@ -175,29 +166,6 @@ def _named_program(fn: Callable, name: str) -> Callable:
 
     program.__name__ = program.__qualname__ = name
     return program
-
-
-def _silence_donation_decline_warning() -> None:
-    """XLA declines to alias a donated input that matches no output in
-    shape and dtype — every input here: the one verdict output is tiny —
-    and warns once per compile. Measured on the v5e as on the CPU (PR 21,
-    un-silenced for one chip run): the same warning for the same buffers,
-    and device-resident donated inputs are NOT consumed, so
-    ``--donate-buffers`` changes nothing on either backend (ROADMAP D1).
-    Silence exactly this warning, once per process (epoch flips rebuild
-    environments, and re-appending the filter per build would grow the
-    global warnings registry)."""
-    global _donation_warning_silenced
-    if _donation_warning_silenced:
-        return
-    _donation_warning_silenced = True
-    import warnings
-
-    warnings.filterwarnings(
-        "ignore",
-        message="Some donated buffers were not usable",
-        category=UserWarning,
-    )
 
 
 class _InlineFetch:
@@ -509,7 +477,6 @@ class EvaluationEnvironmentBuilder:
         verdict_cache_size: int = DEFAULT_VERDICT_CACHE_SIZE,
         breaker_config: Mapping[str, Any] | None = None,
         columnar: bool = True,
-        donate_buffers: bool = True,
         predicate_opt: bool = True,
     ) -> None:
         self.backend = backend
@@ -540,10 +507,6 @@ class EvaluationEnvironmentBuilder:
         # narrowed PLANES with all-zero columns elided instead of one
         # row-packed buffer; False restores the packed transport
         self.columnar = columnar
-        # donate delta-plane input buffers on dispatch
-        # (jax.jit donate_argnums) so the transport stops round-tripping
-        # dead buffers
-        self.donate_buffers = donate_buffers
         # predicate-program optimizer (round 15, ops/optimizer.py):
         # cross-policy CSE + constant folding + dead-field/mask pruning
         # before lowering; False restores the naive per-policy lowering
@@ -667,7 +630,6 @@ class EvaluationEnvironmentBuilder:
             verdict_cache_size=self.verdict_cache_size,
             breaker_config=self.breaker_config,
             columnar=self.columnar,
-            donate_buffers=self.donate_buffers,
             predicate_opt=self.predicate_opt,
         )
         # the source policy mapping the environment was built from: the
@@ -714,7 +676,6 @@ class EvaluationEnvironment:
         verdict_cache_size: int = DEFAULT_VERDICT_CACHE_SIZE,
         breaker_config: Mapping[str, Any] | None = None,
         columnar: bool = True,
-        donate_buffers: bool = True,
         predicate_opt: bool = True,
     ) -> None:
         self.backend = backend
@@ -874,16 +835,12 @@ class EvaluationEnvironment:
         # only all-nonzero ("delta") columns ship, in ONE wire buffer a
         # launch (_WireForm) — all-zero planes and columns are
         # reconstructed on device from resident zero constants, the
-        # column indices stay on the device, and the shipped buffer is
-        # DONATED so the transport never round-trips dead input buffers.
+        # column indices stay on the device.
         # ``spec`` (static arg 0) carries (schema index, batch, narrow,
         # the form's shape) and keys the jit cache per plane subset. The
         # root itself is branch-free (TP02); structure branching lives in
         # the _features_from_planes helper.
         self.columnar = bool(columnar) and backend == "jax"
-        self.donate_buffers = bool(donate_buffers)
-        if self.donate_buffers and self.columnar:
-            _silence_donation_decline_warning()
         self._fused_planes = self._jit_planes()
         # specs whose program is compiled — the serving path dispatches
         # only these (see _plane_dispatch); also sizes the resident
@@ -929,23 +886,15 @@ class EvaluationEnvironment:
         # Serving-layer host fast-path counter (validate_batch(prefer_host=
         # True) rows answered by the targeted host oracle; metrics surface)
         self._host_fastpath_requests = 0  # guarded-by: _fallback_lock
-        # Two-tier bit-exact verdict cache + in-batch row dedup
-        # (verdict_cache.py: blob tier dedups exact payload replays BEFORE
-        # encode; row tier dedups uid/name-varying duplicates after).
-        # ``verdict_cache_size`` is a BYTE budget split between the tiers.
-        # jax-backend only: the oracle backend exists to be the
-        # independent differential reference, so it always recomputes.
-        caching = verdict_cache_size > 0 and backend == "jax"
-        self._verdict_cache = (
-            VerdictCache(max(1, verdict_cache_size // 2)) if caching else None
-        )
-        self._blob_cache = (
-            VerdictCache(max(1, verdict_cache_size - verdict_cache_size // 2))
-            if caching
+        # The dedup tiers in front of the device (verdict_cache.py owns
+        # them and every rule about them); ``verdict_cache_size`` is their
+        # BYTE budget. jax-backend only: the oracle backend exists to be
+        # the independent differential reference, so it always recomputes.
+        self._tiers = (
+            DedupTiers(verdict_cache_size)
+            if verdict_cache_size > 0 and backend == "jax"
             else None
         )
-        # rows answered by another identical row in the SAME batch
-        self._batch_dedup_hits = 0  # guarded-by: _fallback_lock
         # Host-pipeline decomposition counters (round 6): where
         # the per-row host time goes on the native dispatch path. All
         # nanosecond totals + row counts; bench/metrics divide.
@@ -966,7 +915,6 @@ class EvaluationEnvironment:
             "wire_rows": 0,                # form would have shipped
             "delta_cols_shipped": 0,   # 32-bit columns shipped (delta)
             "delta_cols_total": 0,     # 32-bit columns in the schema
-            "donated_dispatches": 0,   # dispatches with donated inputs
             "resident_const_bytes": 0,  # device-resident zero-plane bytes
         }
         # memoized service-layer lookups (immutable registry; unknown ids
@@ -989,8 +937,6 @@ class EvaluationEnvironment:
         # per eligible target, the templates of the verdicts it has
         # answered hits with (_frag_of); False for an ineligible target
         self._frag_lanes: dict[int, Any] = {}  # graftcheck: lockfree — GIL-atomic dict ops; racing builders store identical values
-        # rows answered as pre-serialized fragments (metrics surface)
-        self._frag_hits = 0  # guarded-by: _fallback_lock
         # Pre-built output-key strings per policy/group: the per-row
         # f-string construction in the materializers showed up in the
         # round-6 profile at ~7 µs/row on group targets.
@@ -1132,7 +1078,6 @@ class EvaluationEnvironment:
                 self._forward_planes, FUSED_PLANES_PROGRAM_NAME
             ),
             static_argnums=(0,),
-            donate_argnums=(1,) if self.donate_buffers else (),
         )
 
     def _columnar_mesh_ok(self) -> bool:
@@ -1479,6 +1424,31 @@ class EvaluationEnvironment:
             # serializes it fine, a raised batch would not
             return False
 
+    def _answer_hits(
+        self,
+        items: list[tuple[str, ValidateRequest]],
+        targets: list[Any],
+        results: list,
+        hits: "Iterable[tuple[int, bytes | Mapping[str, Any]]]",
+    ) -> None:
+        """Answer each ``(item index, cached row)`` a tier served. Under
+        the batcher's fragment scope (round 19) an eligible target's hit
+        answers as uid + pre-built template: its responses differ ONLY in
+        uid, so the AdmissionResponse/ValidationStatus construction
+        happens once per target and verdict, not once per hit. Every
+        other hit materializes from the row."""
+        frag_on = _fragments_enabled()
+        n_frag = 0
+        for i, row in hits:
+            tmpl = self._frag_of(targets[i], row) if frag_on else None
+            if tmpl is not None:
+                results[i] = FragVerdict(items[i][1].uid(), tmpl)
+                n_frag += 1
+            else:
+                results[i] = self._materialize(targets[i], items[i][1], row)
+        if n_frag:
+            self._tiers.count_fragment_hits(n_frag)
+
     def _row_face(self, row: "bytes | Mapping[str, Any]") -> Mapping[str, Any]:
         """What the materializers read of a cached row: a dict row as it
         is, a packed row through its layout."""
@@ -1502,17 +1472,11 @@ class EvaluationEnvironment:
             return self._materialize_group(target, uid, _no_payload, row)
         return self._materialize_single(target, uid, _no_payload, row)
 
-    def _row_cache_key(self, target, blob: bytes) -> tuple | None:
-        """(target, packed row bytes) verdict-cache key for ONE request —
-        the host fast-path's entry into the same key space the device
-        path dedups on. None when the key cannot be computed (no native
-        encoder, schema overflow): the caller just evaluates normally.
-        Packed-row keying is uid-insensitive — the request uid is not a
-        policy feature, so identical admissions with fresh uids share a
-        key — and the unique schema widths make the bytes unambiguous.
-        Costs a single-row encode; the fast path therefore consults the
-        BLOB tier first (key already in hand) and only pays this on a
-        blob miss."""
+    def _packed_row_of(self, blob: bytes) -> bytes | None:
+        """The packed row bytes of ONE request's blob — the host
+        fast-path's entry into the key space the device path dedups on —
+        at the cost of a single-row encode. None when they cannot be had
+        (no native encoder, schema overflow)."""
         if not self.native_encoding:
             return None
         try:
@@ -1521,10 +1485,7 @@ class EvaluationEnvironment:
                     [blob], 1, self.table
                 )
                 if status[0] == 0:
-                    return (
-                        self._cache_key_of(target),
-                        features[PACKED_KEY][0].tobytes(),
-                    )
+                    return features[PACKED_KEY][0].tobytes()
         except ValueError:
             return None
         return None
@@ -1533,10 +1494,8 @@ class EvaluationEnvironment:
         """Drop every cached verdict row in both tiers (benchmark pass
         isolation; a no-op when caching is disabled). Counters are kept —
         they are cumulative serving metrics."""
-        if self._verdict_cache is not None:
-            self._verdict_cache.clear()
-        if self._blob_cache is not None:
-            self._blob_cache.clear()
+        if self._tiers is not None:
+            self._tiers.clear()
 
     def _profile_add(self, **deltas: int) -> None:
         with self._profile_lock:
@@ -1559,8 +1518,7 @@ class EvaluationEnvironment:
 
     @property
     def batch_dedup_hits(self) -> int:
-        with self._fallback_lock:
-            return self._batch_dedup_hits
+        return 0 if self._tiers is None else self._tiers.batch_dup_hits
 
     @property
     def breaker_short_circuited_requests(self) -> int:
@@ -1672,26 +1630,10 @@ class EvaluationEnvironment:
 
     @property
     def dedup_stats(self) -> dict[str, int]:
-        """Two-tier verdict-cache + in-batch dedup counters
-        (bench/metrics). ``cache_*`` keys are the row tier (legacy
-        names); ``blob_*`` keys are the pre-encode blob tier."""
-        off = dict.fromkeys(
-            ("cache_hits", "cache_misses", "cache_evictions", "cache_puts",
-             "cache_put_bytes", "cache_entries", "cache_bytes",
-             "cache_capacity"), 0
-        )
-        stats = (
-            self._verdict_cache.stats()
-            if self._verdict_cache is not None
-            else dict(off)
-        )
-        blob = self._blob_cache.stats() if self._blob_cache is not None else off
-        for k, v in blob.items():
-            stats["blob_" + k] = v
-        with self._fallback_lock:
-            stats["batch_dup_hits"] = self._batch_dedup_hits
-            stats["fragment_hits"] = self._frag_hits
-        return stats
+        """The dedup tiers' counters (bench/metrics): DedupTiers.stats."""
+        if self._tiers is None:
+            return DedupTiers.stats_when_off()
+        return self._tiers.stats()
 
     def has_policy(self, policy_id: str) -> bool:
         try:
@@ -1763,12 +1705,11 @@ class EvaluationEnvironment:
     ):
         """Columnar jit root: ``spec`` is static (schema index, batch,
         narrow, the wire form's shape); ``shipped`` is what this launch
-        copied to the device (the wire buffer, donated); ``resident`` the
-        column-index vectors that live there across launches, an
-        argument of their own because a donated one would be dead at the
-        second launch. The body is deliberately branch-free — plane
-        reconstruction (which branches on the form's STRUCTURE at trace
-        time) lives in the helper."""
+        copied to the device (the wire buffer); ``resident`` the
+        column-index vectors that live there across launches. The body
+        is deliberately branch-free — plane reconstruction (which
+        branches on the form's STRUCTURE at trace time) lives in the
+        helper."""
         features = self._features_from_planes(spec, shipped, resident)
         return self._eval_features(features)
 
@@ -2179,8 +2120,8 @@ class EvaluationEnvironment:
         self, schema_idx: int, features: Mapping[str, Any], rows: int = 0
     ) -> Any:
         """Columnar device dispatch: build the one wire buffer, account
-        wire bytes / delta columns / donation, and launch the donated
-        columnar program (async — caller fetches through _device_fetch).
+        wire bytes / delta columns, and launch the columnar program
+        (async — caller fetches through _device_fetch).
 
         A batch with no live column ships nothing (the all-elided
         program). Every other batch ships its schema's settled column set
@@ -2237,8 +2178,6 @@ class EvaluationEnvironment:
             hp["delta_cols_shipped"] += form.shape[0][0] + form.shape[1][0]
             hp["delta_cols_total"] += playout.total32
             hp["launch_h2d_arrays"] += len(shipped) + bool(form.width)
-            if self.donate_buffers:
-                hp["donated_dispatches"] += 1
         if schedule:
             self._compile_columns_async(schema_idx, narrow, version)
         if form.width:
@@ -2872,45 +2811,21 @@ class EvaluationEnvironment:
                         target, request.uid(), payload, {}
                     )
                     continue
-                # the verdict cache serves the fast-path too: executors are
+                # the dedup tiers serve the fast-path too: executors are
                 # bit-exact by the differential guarantee, and the serving
-                # layer already mixes host/device answers per batch size.
-                # Blob tier first — the key is already in hand, so an
-                # exact replay costs no encode at all; the row tier (which
-                # needs a single-row encode to compute its key) only runs
-                # on a blob miss.
-                key = bkey = None
-                if self._verdict_cache is not None and self._cacheable(target):
-                    blob = self._blob_of(target, request, payload)
-                    bkey = (self._cache_key_of(target), blob)
-                    row = self._blob_cache.get(bkey)
-                    if row is not None:
-                        results[i] = self._materialize(target, request, row)
-                        n_host += 1
-                        continue
-                    key = self._row_cache_key(target, blob)
-                    if key is not None:
-                        row = self._verdict_cache.get(key)
-                        if row is not None:
-                            # no blob-tier backfill here: on sustained
-                            # uid-varying traffic every hit carries a
-                            # never-recurring blob, and a per-request
-                            # insert would churn the byte-bounded blob
-                            # tier out of its genuine exact-replay
-                            # entries (the native path bounds its
-                            # backfill for the same reason); the blob key
-                            # was inserted when this row first MISSED
-                            results[i] = self._materialize(
-                                target, request, row
-                            )
-                            n_host += 1
-                            continue
-                outputs = self._oracle_outputs_for(target, payload)
-                if key is not None:
-                    self._verdict_cache.put(key, outputs)
-                if bkey is not None:
-                    self._blob_cache.put(bkey, outputs)
-                results[i] = self._materialize(target, request, outputs)
+                # layer already mixes host/device answers per batch size
+                row = learn = None
+                if self._tiers is not None and self._cacheable(target):
+                    row, learn = self._tiers.get_one(
+                        self._cache_key_of(target),
+                        self._blob_of(target, request, payload),
+                        self._packed_row_of,
+                    )
+                if row is None:
+                    row = self._oracle_outputs_for(target, payload)
+                    if learn is not None:
+                        self._tiers.put_one(learn, row)
+                results[i] = self._materialize(target, request, row)
                 n_host += 1
             except Exception as e:  # noqa: BLE001 — per-item error channel
                 results[i] = e
@@ -2930,19 +2845,14 @@ class EvaluationEnvironment:
         re-stack). Rows that overflow a bucket cascade to the next; rows
         failing the widest bucket fall back to the host oracle.
 
-        Round 6: the payload blob is built once per item up front and a
-        BLOB-TIER cache lookup (one locked batch get) answers exact
-        payload replays before any encoding happens — the round-5 profile
-        showed every duplicate still paying a full C++ encode just to
-        compute its post-encode row key (verdict_cache.py explains the
-        two tiers). ``defer_sink``: see validate_batch_begin."""
+        The payload blob is built once per item up front, and the blob
+        tier (verdict_cache.py) answers exact payload replays before any
+        encoding happens. ``defer_sink``: see validate_batch_begin."""
         results: list[AdmissionResponse | Exception | None] = [None] * len(items)
         targets: list[Any] = [None] * len(items)
         blobs: list[bytes | None] = [None] * len(items)
         pending: list[int] = []
         wasm_infos: dict[int, dict] = {}
-        uniform_tid: int | None = None
-        uniform_target = True
         # flight recorder: the target-resolution + payload-blob loop is
         # its own phase — round 18's first phase-report run measured it
         # as ~90 µs/row of UNATTRIBUTED dispatch time on the all-cache-
@@ -2985,10 +2895,6 @@ class EvaluationEnvironment:
                         target, self.payload_for(target, request)
                     )
                 blobs[i] = self._payload_blob(target, request)
-                if uniform_tid is None:
-                    uniform_tid = id(target)
-                elif id(target) != uniform_tid:
-                    uniform_target = False
                 pending.append(i)
             except Exception as e:  # noqa: BLE001 — per-item error channel
                 results[i] = e
@@ -2998,42 +2904,23 @@ class EvaluationEnvironment:
                 rows=len(items), batch=flightrec.current_batch(),
             )
 
-        # Tier-1 blob dedup: exact payload replays are answered here and
-        # never reach the encoder (ONE locked batch lookup; wasm-involving
-        # targets are uncacheable and pass through as None keys).
-        bcache = self._blob_cache
-        if bcache is not None and pending:
+        # the blob tier: exact payload replays are answered here and never
+        # reach the encoder (wasm-involving targets are uncacheable)
+        if self._tiers is not None and pending:
             t0 = time.perf_counter_ns()
-            keys = [
-                (self._cache_key_of(targets[i]), blobs[i])
-                if self._cacheable(targets[i])
-                else None
-                for i in pending
-            ]
-            rows = bcache.get_many(keys)
-            still: list[int] = []
-            # cache-hit fast lane (round 19): under the batcher's
-            # fragment scope a hit row answers as uid + pre-built
-            # template — the per-row AdmissionResponse/ValidationStatus
-            # construction the round-18 profile measured at ~61 µs/row
-            # happens once per cached row, not once per hit
-            frag_on = _fragments_enabled()
-            n_frag = 0
-            for i, row in zip(pending, rows):
-                if row is None:
-                    still.append(i)
-                    continue
-                tmpl = self._frag_of(targets[i], row) if frag_on else None
-                if tmpl is not None:
-                    results[i] = FragVerdict(items[i][1].uid(), tmpl)
-                    n_frag += 1
-                else:
-                    results[i] = self._materialize(
-                        targets[i], items[i][1], row
-                    )
-            if n_frag:
-                with self._fallback_lock:
-                    self._frag_hits += n_frag
+            rows = self._tiers.get_blobs(
+                (
+                    self._cache_key_of(targets[i])
+                    if self._cacheable(targets[i])
+                    else None
+                    for i in pending
+                ),
+                (blobs[i] for i in pending),
+            )
+            self._answer_hits(
+                items, targets, results,
+                ((i, row) for i, row in zip(pending, rows) if row is not None),
+            )
             t1 = time.perf_counter_ns()
             self._profile_add(
                 bookkeeping_ns=t1 - t0,
@@ -3044,15 +2931,14 @@ class EvaluationEnvironment:
                     flightrec.PH_BLOB_DEDUP, t0, t1,
                     rows=len(pending), batch=flightrec.current_batch(),
                 )
-            pending = still
+            pending = [i for i, row in zip(pending, rows) if row is None]
 
         for schema in self.schemas:
             if not pending:
                 break
             pending = self._native_schema_pass(
                 schema, items, targets, results, pending, wasm_infos,
-                blobs=blobs, uniform_target=uniform_target,
-                defer_sink=defer_sink,
+                blobs, defer_sink,
             )
 
         for i in pending:  # beyond the widest schema → oracle
@@ -3105,8 +2991,8 @@ class EvaluationEnvironment:
         """Device half: block on each chunk's device fetch and materialize
         responses. Watchdog-safe — all blocking happens here."""
         results, deferred = handle
-        for materialize_fn, entry in deferred:
-            materialize_fn(entry)
+        for land in deferred:
+            land()
         return results  # type: ignore[return-value]
 
     # Largest single device dispatch; bigger lists pipeline in chunks so
@@ -3124,135 +3010,41 @@ class EvaluationEnvironment:
         targets: list[Any],
         results: list[AdmissionResponse | Exception | None],
         pending: list[int],
-        wasm_infos: dict[int, dict] | None = None,
-        blobs: list[bytes | None] | None = None,
-        uniform_target: bool = False,
-        defer_sink: list | None = None,
+        wasm_infos: dict[int, dict],
+        blobs: list[bytes | None],
+        defer_sink: list | None,
     ) -> list[int]:
-        """Encode+dispatch all ``pending`` rows against one schema.
+        """Encode+dispatch all ``pending`` rows against one schema, a
+        chunk at a time in four steps: encode (_encode_chunk) → plan, who
+        answers each row (_plan_chunk; the dedup tiers of verdict_cache.py
+        sit here, between encode and dispatch) → launch (_launch_chunk) →
+        fetch, learn, materialize (_land_chunk). Returns the rows that
+        overflowed this schema.
 
         Pipeline shape: the dispatch thread only encodes (GIL-free C
         call) and enqueues device executions; every result fetch runs on
         the drain pool, so its sync latency overlaps other fetches and
-        device work. Returns the rows that overflowed this schema.
-
-        Bit-exact ROW-TIER dedup (the second tier; verdict_cache.py) sits
-        between encode and dispatch: the fused program is a pure function
-        of the encoded row, so rows with identical packed bytes are
-        GUARANTEED identical outputs — answer repeats from the cross-batch
-        verdict cache, collapse in-chunk duplicates onto one dispatched
-        row, and ship only unique rows to the device. Packed-row keying
-        is uid-insensitive by construction: the request uid is not a
-        policy feature, so it never reaches the encoded row — this is
-        what the blob tier structurally cannot see.
-
-        Round 6: the per-row Python slot/LRU loop is gone. Row identity
-        comes from ONE np.unique over a void view of the packed rows,
-        slot assignment from a second np.unique over the cache misses,
-        and each tier pays ONE locked batch call per chunk — the round-5
-        profile burned ~45 µs/row in exactly this per-row bookkeeping.
-        With ``defer_sink`` set, materialization
-        closures are appended instead of run, so validate_batch_finish
-        can block on device results on a different thread than the one
-        encoding the next batch (double-buffering)."""
+        device work. With ``defer_sink`` set, the last step is appended
+        instead of run, so validate_batch_finish can block on device
+        results on a different thread than the one encoding the next
+        batch (double-buffering)."""
         chunk_size = min(self.bucket_for(len(pending)), self.max_dispatch_batch)
         chunks = [
             pending[c : c + chunk_size]
             for c in range(0, len(pending), chunk_size)
         ]
         # flight recorder (round 18): the ambient batch id rides the
-        # encode-thread's scope (batcher._scoped_rec); closures below
-        # capture it so drain/device-pool events still attribute to the
-        # submitting batch
+        # encode-thread's scope (batcher._scoped_rec); the steps are handed
+        # it so drain/device-pool events attribute to the submitting batch
         _rec = flightrec.recorder()
         _bid = flightrec.current_batch() if _rec is not None else -1
         overflowed: list[int] = []
-        # (device future, slot rows, wasm stash, row-tier puts, blob-tier
-        # puts) per chunk; a tier's puts are flat (key, slot) pairs
-        drains: list[tuple] = []
-        cache = self._verdict_cache
-        bcache = self._blob_cache
-        # mixed-target batches: memoized small-int id per distinct target
-        tid_of: dict[int, int] = {}
-        ckey_of_tid: list[tuple] = []
-
-        def encode(chunk: list[int]):
-            failpoints.fire("encode.batch")
-            # the CPU clock is read inside the wall clock's interval, so
-            # encode_cpu_ns never exceeds encode_ns; the difference is
-            # time this thread was off a core (GIL wait, descheduled)
-            t0 = time.perf_counter_ns()
-            c0 = time.thread_time_ns()
-            if blobs is None:
-                bl = [
-                    self._payload_blob(targets[i], items[i][1]) for i in chunk
-                ]
-            else:
-                bl = [blobs[i] for i in chunk]
-            out = schema.native.encode_batch(
-                bl, self.bucket_for(len(bl)), self.table
-            )
-            c1 = time.thread_time_ns()
-            t1 = time.perf_counter_ns()
-            self._profile_add(
-                encode_ns=t1 - t0, encode_cpu_ns=c1 - c0,
-                encode_rows=len(chunk),
-            )
-            if _rec is not None:
-                _rec.record_phase(
-                    flightrec.PH_ENCODE, t0, t1, rows=len(chunk),
-                    batch=_bid,
-                )
-            return bl, out
-
-        def materialize(entry) -> None:
-            fut, slot_rows, stash, lru_puts, blob_puts = entry
-            t0 = time.perf_counter_ns()
-            raw = np.asarray(fut.result())
-            t1 = time.perf_counter_ns()
-            self._profile_add(dispatch_wait_ns=t1 - t0)
-            if lru_puts or blob_puts:
-                # a dispatched row's cache entry IS its bytes of the
-                # fetched array: one copy of the batch, a slice a slot,
-                # the same object under every key of both tiers
-                width = raw.shape[1] * raw.itemsize
-                fetched = raw.tobytes()
-                rows = [
-                    fetched[at : at + width]
-                    for at in range(0, len(fetched), width)
-                ]
-                if lru_puts:
-                    cache.put_many(
-                        [(key, rows[slot]) for key, slot in lru_puts]
-                    )
-                if blob_puts:
-                    bcache.put_many(
-                        [(key, rows[slot]) for key, slot in blob_puts]
-                    )
-            outputs = self._unpack(raw)
-            outputs.update(stash)
-            for slot, i in slot_rows:
-                _, request = items[i]
-                results[i] = self._materialize(
-                    targets[i], request, _RowView(outputs, slot)
-                )
-            if _rec is not None:
-                t2 = time.perf_counter_ns()
-                _rec.record_phase(
-                    flightrec.PH_FETCH, t0, t1, rows=len(slot_rows),
-                    batch=_bid,
-                )
-                _rec.record_phase(
-                    flightrec.PH_MATERIALIZE, t1, t2,
-                    rows=len(slot_rows), batch=_bid,
-                )
-
+        drains: list[Callable] = []  # launched chunks' _land_chunk
         # encode ahead on the pool (bounded window), dispatch in order.
         # A SINGLE chunk — every serving batch up to max_dispatch_batch —
         # encodes inline instead (round 19): with nothing to overlap, the
         # pool submit + future-wake per chunk was pure handoff cost.
         single = len(chunks) == 1
-        window = self.max_inflight_dispatches
         encode_futs: dict[int, Any] = {}
         drained = 0
         for ci, chunk in enumerate(chunks):
@@ -3260,11 +3052,13 @@ class EvaluationEnvironment:
                 for cj in range(ci, min(ci + 4, len(chunks))):
                     if cj not in encode_futs:
                         encode_futs[cj] = self._encode_pool.submit(
-                            encode, chunks[cj]
+                            self._encode_chunk, schema, chunks[cj], blobs,
+                            _rec, _bid,
                         )
             try:
                 chunk_blobs, (features, status) = (
-                    encode(chunk) if single
+                    self._encode_chunk(schema, chunk, blobs, _rec, _bid)
+                    if single
                     else encode_futs.pop(ci).result()
                 )
             except ValueError:
@@ -3273,331 +3067,204 @@ class EvaluationEnvironment:
                 # schema / the oracle instead of failing the batch
                 overflowed.extend(chunk)
                 continue
-            n_chunk = len(chunk)
-            status = np.asarray(status)[:n_chunk]
-            ok_mask = status == 0
-            all_ok = bool(ok_mask.all())
-            if not all_ok:
+            ok_mask = np.asarray(status)[: len(chunk)] == 0
+            if not ok_mask.all():
                 overflowed.extend(
-                    chunk[int(p)] for p in np.flatnonzero(~ok_mask)
+                    chunk[p] for p in np.flatnonzero(~ok_mask).tolist()
                 )
-            lru_puts: list[tuple] = []
-            blob_puts: list[tuple] = []
-            if cache is None:
-                slot_rows = [
-                    (pos, i) for pos, i in enumerate(chunk) if ok_mask[pos]
-                ]
-                wasm_rows = [
-                    (pos, wasm_infos[i])
-                    for pos, i in enumerate(chunk)
-                    if wasm_infos and i in wasm_infos
-                ]
-                if not slot_rows:
-                    continue
-                n_dispatched = len(slot_rows)
-            else:
-                t_book = time.perf_counter_ns()
-                packed = features[PACKED_KEY]
-                item_arr = np.asarray(chunk, dtype=np.intp)
-                if wasm_infos:
-                    # wasm verdict bits ride beside the row — not a pure
-                    # function of the row bytes, never deduped or cached
-                    wasm_pos = [
-                        pos
-                        for pos, i in enumerate(chunk)
-                        if i in wasm_infos and ok_mask[pos]
-                    ]
-                    wset = set(wasm_pos)
-                    dedup_pos = np.asarray(
-                        [
-                            int(p)
-                            for p in np.flatnonzero(ok_mask)
-                            if int(p) not in wset
-                        ],
-                        dtype=np.intp,
-                    )
-                else:
-                    wasm_pos = []
-                    dedup_pos = np.flatnonzero(ok_mask)
-                slot_rows = []
-                n_d = int(dedup_pos.size)
-                keep_uncompacted = False
-                keep_rows = np.empty(0, dtype=np.intp)
-                rows_arr = None
-                if n_d:
-                    # ROW IDENTITY in one vectorized pass: a void view
-                    # makes each packed row one comparable scalar, so
-                    # np.unique replaces the per-row tobytes/dict loop
-                    rows_arr = np.ascontiguousarray(packed[dedup_pos])
-                    void = rows_arr.view(
-                        np.dtype(
-                            (np.void, rows_arr.shape[1] * rows_arr.itemsize)
-                        )
-                    ).ravel()
-                    uniq, first, inverse = np.unique(
-                        void, return_index=True, return_inverse=True
-                    )
-                    inverse = np.asarray(inverse).ravel()
-                    m = int(uniq.size)
-                    if uniform_target:
-                        # one target → combo space IS the row space
-                        ckey = self._cache_key_of(
-                            targets[int(item_arr[dedup_pos[0]])]
-                        )
-                        combo_first = first
-                        combo_inverse = inverse
-                        keys = [
-                            (ckey, rows_arr[int(ri)].tobytes())
-                            for ri in first
-                        ]
-                    else:
-                        # distinct (target, row) combos: same row bytes
-                        # under different targets share a dispatch slot
-                        # but carry separate cache keys
-                        def tid(t) -> int:
-                            k = tid_of.get(id(t))
-                            if k is None:
-                                k = len(ckey_of_tid)
-                                tid_of[id(t)] = k
-                                ckey_of_tid.append(self._cache_key_of(t))
-                            return k
-
-                        tids = np.fromiter(
-                            (tid(targets[int(p)]) for p in item_arr[dedup_pos]),
-                            dtype=np.int64,
-                            count=n_d,
-                        )
-                        combos = tids * m + inverse
-                        uc, combo_first, combo_inverse = np.unique(
-                            combos, return_index=True, return_inverse=True
-                        )
-                        combo_inverse = np.asarray(combo_inverse).ravel()
-                        keys = [
-                            (
-                                ckey_of_tid[int(uc[k] // m)],
-                                rows_arr[int(combo_first[k])].tobytes(),
-                            )
-                            for k in range(len(uc))
-                        ]
-                    # ONE locked lookup per chunk for the whole row tier
-                    cached = cache.get_many(keys)
-                    hit_flags = np.fromiter(
-                        (c is not None for c in cached),
-                        dtype=bool,
-                        count=len(cached),
-                    )
-                    row_hit = hit_flags[combo_inverse]
-                    hit_rows = np.flatnonzero(row_hit)
-                    # get_many counted one hit/miss per combo KEY; rescale
-                    # to rows so the counters keep their round-5 meaning
-                    # (rows served from / missed by the row tier)
-                    n_hit_keys = int(hit_flags.sum())
-                    cache.adjust_counts(
-                        hits=int(hit_rows.size) - n_hit_keys,
-                        misses=(n_d - int(hit_rows.size))
-                        - (len(cached) - n_hit_keys),
-                    )
-                    if hit_rows.size:
-                        hit_items = item_arr[dedup_pos[hit_rows]].tolist()
-                        hit_combos = combo_inverse[hit_rows].tolist()
-                        # same fragment fast lane as the blob tier: the
-                        # row tier serves uid-varying duplicates, whose
-                        # responses differ ONLY in uid for eligible
-                        # targets
-                        frag_on = _fragments_enabled()
-                        n_frag = 0
-                        for i, k in zip(hit_items, hit_combos):
-                            tmpl = (
-                                self._frag_of(targets[i], cached[k])
-                                if frag_on else None
-                            )
-                            if tmpl is not None:
-                                results[i] = FragVerdict(
-                                    items[i][1].uid(), tmpl
-                                )
-                                n_frag += 1
-                            else:
-                                results[i] = self._materialize(
-                                    targets[i], items[i][1], cached[k]
-                                )
-                        if n_frag:
-                            with self._fallback_lock:
-                                self._frag_hits += n_frag
-                        if bcache is not None:
-                            # Backfill the blob tier so the NEXT identical
-                            # payload skips encoding entirely — bounded to
-                            # ONE representative per hit combo per chunk,
-                            # mirroring the miss path: a per-row backfill
-                            # on steady uid-varying rollout traffic (where
-                            # nearly every row is a row-tier hit with a
-                            # never-recurring blob) would churn the whole
-                            # blob tier in seconds and evict the genuine
-                            # exact-replay entries. Replayed streams still
-                            # converge, one representative per cycle.
-                            seen_combos: set[int] = set()
-                            bput = []
-                            for pos, k in zip(
-                                dedup_pos[hit_rows].tolist(), hit_combos
-                            ):
-                                if k in seen_combos:
-                                    continue
-                                seen_combos.add(k)
-                                bput.append(
-                                    (
-                                        (keys[k][0], chunk_blobs[pos]),
-                                        cached[k],
-                                    )
-                                )
-                            bcache.put_many(bput)
-                    miss_rows = np.flatnonzero(~row_hit)
-                    if miss_rows.size:
-                        miss_inv = inverse[miss_rows]
-                        uniq_miss, miss_first, slot_inv = np.unique(
-                            miss_inv, return_index=True, return_inverse=True
-                        )
-                        slot_inv = np.asarray(slot_inv).ravel()
-                        dup_hits = int(miss_rows.size - uniq_miss.size)
-                        if dup_hits:
-                            with self._fallback_lock:
-                                self._batch_dedup_hits += dup_hits
-                        keep_rows = miss_rows[miss_first]
-                        keep_uncompacted = (
-                            not wasm_pos
-                            and all_ok
-                            and hit_rows.size == 0
-                            and dup_hits == 0
-                        )
-                        if keep_uncompacted:
-                            # nothing collapsed: ship the encoded buffer
-                            # as-is — slots are the encode positions
-                            slots = dedup_pos[miss_rows]
-                        else:
-                            slots = slot_inv + len(wasm_pos)
-                        miss_items = item_arr[dedup_pos[miss_rows]]
-                        slot_rows = list(
-                            zip(slots.tolist(), miss_items.tolist())
-                        )
-                        # per-combo cache keys onto their dispatch slot:
-                        # one vectorized pass over all the miss combos
-                        miss_combos = np.flatnonzero(~hit_flags)
-                        if keep_uncompacted:
-                            combo_slots = dedup_pos[combo_first[miss_combos]]
-                        else:
-                            # a combo's unique row, then that row's place
-                            # among the dispatched ones
-                            combo_slots = np.searchsorted(
-                                uniq_miss,
-                                miss_combos
-                                if uniform_target
-                                else uc[miss_combos] % m,
-                            ) + len(wasm_pos)
-                        lru_puts = [
-                            (keys[k], slot)
-                            for k, slot in zip(
-                                miss_combos.tolist(), combo_slots.tolist()
-                            )
-                        ]
-                        if bcache is not None:
-                            # blob→row learning is bounded to ONE
-                            # representative per dispatched slot (plus the
-                            # row-tier backfill above): inserting every
-                            # collapsed duplicate's blob cost ~4 µs/row on
-                            # uid-varying rollout streams and bought
-                            # nothing — those variant blobs never repeat.
-                            # An exact stream replay still converges: the
-                            # replayed variants hit the row tier, whose
-                            # (equally bounded) backfill inserts one more
-                            # representative blob per combo per cycle.
-                            blob_puts = [
-                                (
-                                    (
-                                        self._cache_key_of(targets[chunk[pos]]),
-                                        chunk_blobs[pos],
-                                    ),
-                                    pos if keep_uncompacted
-                                    else j + len(wasm_pos),
-                                )
-                                for j, pos in enumerate(
-                                    dedup_pos[keep_rows].tolist()
-                                )
-                            ]
-                wasm_rows = []
-                n_keep = len(wasm_pos) + int(keep_rows.size)
-                if wasm_pos:
-                    for j, pos in enumerate(wasm_pos):
-                        i = chunk[pos]
-                        wasm_rows.append((j, wasm_infos[i]))
-                        slot_rows.append((j, i))
-                # ns only: these rows were already counted once by the
-                # blob-tier pre-pass (bookkeeping_rows must mean ROWS, not
-                # stage-passes, or the µs/row denominator doubles)
-                t_book_end = time.perf_counter_ns()
-                self._profile_add(bookkeeping_ns=t_book_end - t_book)
-                if _rec is not None:
-                    _rec.record_phase(
-                        flightrec.PH_BOOKKEEPING, t_book, t_book_end,
-                        rows=len(chunk), batch=_bid,
-                    )
-                if not slot_rows:
-                    continue  # entire chunk answered from the caches
-                if not keep_uncompacted:
-                    # compact: ship only unique rows over the transport
-                    bucket = self.bucket_for(n_keep)
-                    compact = np.zeros((bucket, packed.shape[1]), packed.dtype)
-                    if wasm_pos:
-                        compact[: len(wasm_pos)] = packed[
-                            np.asarray(wasm_pos, dtype=np.intp)
-                        ]
-                    if keep_rows.size:
-                        compact[len(wasm_pos) : n_keep] = rows_arr[keep_rows]
-                    features = {PACKED_KEY: compact}
-                n_dispatched = n_keep
-            stash = self._add_wasm_bits(
-                features, features[PACKED_KEY].shape[0], wasm_rows
+            plan = self._plan_chunk(
+                items, targets, results, chunk, features[PACKED_KEY],
+                ok_mask, wasm_infos, chunk_blobs, _rec, _bid,
             )
-            t_launch = time.perf_counter_ns() if _rec is not None else 0
-            dev_out = self._dispatch_features(  # async dispatch
-                features, rows=n_dispatched
+            if not plan.slot_rows:
+                continue  # all overflowed, or answered by the tiers
+            fetch, stash = self._launch_chunk(
+                features, plan, chunk, wasm_infos, single, _rec, _bid
             )
-            if _rec is not None:
-                # plane selection, the jit call's host-to-device copies
-                # and the enqueue: on the chip, milliseconds a batch
-                # between encode's end and the program's start, and with
-                # encode most of the device's idle time (PERF.md, PR 27)
-                _rec.record_phase(
-                    flightrec.PH_LAUNCH, t_launch, time.perf_counter_ns(),
-                    rows=n_dispatched, batch=_bid,
-                )
-            self._profile_add(
-                dispatched_rows=n_dispatched, dispatched_chunks=1
-            )
-            entry = (
-                _InlineFetch(
-                    self._scoped_device_fetch,
-                    failpoints.current_scope(), dev_out,
-                    _bid, n_dispatched,
-                )
-                if single
-                else self._drain_pool.submit(
-                    self._scoped_device_fetch,
-                    failpoints.current_scope(), dev_out,
-                    _bid, n_dispatched,
-                ),
-                slot_rows,
-                stash,
-                lru_puts,
-                blob_puts,
+            land = functools.partial(
+                self._land_chunk, fetch, plan, stash, chunk, items,
+                targets, results, _rec, _bid,
             )
             if defer_sink is not None:
-                defer_sink.append((materialize, entry))
+                defer_sink.append(land)
                 continue
-            drains.append(entry)
-            if len(drains) - drained >= window:
-                materialize(drains[drained])
+            drains.append(land)
+            if len(drains) - drained >= self.max_inflight_dispatches:
+                drains[drained]()
                 drained += 1
-        for entry in drains[drained:]:
-            materialize(entry)
+        for land in drains[drained:]:
+            land()
         return overflowed
+
+    def _encode_chunk(
+        self,
+        schema: FeatureSchema,
+        chunk: list[int],
+        blobs: list[bytes | None],
+        rec: Any,
+        bid: int,
+    ) -> tuple[list, tuple[dict[str, np.ndarray], np.ndarray]]:
+        """Step 1: the chunk's blobs, and their rows and per-row status
+        out of ONE native call."""
+        failpoints.fire("encode.batch")
+        # the CPU clock is read inside the wall clock's interval, so
+        # encode_cpu_ns never exceeds encode_ns; the difference is
+        # time this thread was off a core (GIL wait, descheduled)
+        t0 = time.perf_counter_ns()
+        c0 = time.thread_time_ns()
+        bl = [blobs[i] for i in chunk]
+        out = schema.native.encode_batch(
+            bl, self.bucket_for(len(bl)), self.table
+        )
+        c1 = time.thread_time_ns()
+        t1 = time.perf_counter_ns()
+        self._profile_add(
+            encode_ns=t1 - t0, encode_cpu_ns=c1 - c0,
+            encode_rows=len(chunk),
+        )
+        if rec is not None:
+            rec.record_phase(
+                flightrec.PH_ENCODE, t0, t1, rows=len(chunk), batch=bid,
+            )
+        return bl, out
+
+    def _plan_chunk(
+        self,
+        items: list[tuple[str, ValidateRequest]],
+        targets: list[Any],
+        results: list[AdmissionResponse | Exception | None],
+        chunk: list[int],
+        packed: np.ndarray,
+        ok_mask: np.ndarray,
+        wasm_infos: dict[int, dict],
+        chunk_blobs: list,
+        rec: Any,
+        bid: int,
+    ) -> DedupTiers.Plan:
+        """Step 2: who answers each row of an encoded chunk — with the
+        tiers on, their plan (row identity, the locked lookup, slots and
+        keys laid out), its hits answered here; else every row its own
+        slot."""
+        tiers = self._tiers
+        if tiers is None:
+            return DedupTiers.passthrough(ok_mask)
+        t0 = time.perf_counter_ns()
+        # wasm verdict bits ride beside the row — not a pure function of
+        # the row bytes, never deduped or cached
+        wasm_pos = [
+            pos
+            for pos, i in enumerate(chunk)
+            if i in wasm_infos and ok_mask[pos]
+        ] if wasm_infos else []
+        # a small-int id per distinct target, by first sight
+        distinct = {id(targets[i]): targets[i] for i in chunk}
+        tid_of = {key: tid for tid, key in enumerate(distinct)}
+        tids = np.fromiter(
+            (tid_of[id(targets[i])] for i in chunk),
+            dtype=np.intp, count=len(chunk),
+        )
+        tkeys = [self._cache_key_of(t) for t in distinct.values()]
+        plan = tiers.plan(packed, ok_mask, wasm_pos, tids, tkeys, chunk_blobs)
+        self._answer_hits(
+            items, targets, results,
+            ((chunk[pos], row) for pos, row in plan.hits),
+        )
+        # ns only: these rows were already counted once by the blob-tier
+        # pre-pass (bookkeeping_rows must mean ROWS, not stage-passes, or
+        # the µs/row denominator doubles)
+        t1 = time.perf_counter_ns()
+        self._profile_add(bookkeeping_ns=t1 - t0)
+        if rec is not None:
+            rec.record_phase(
+                flightrec.PH_BOOKKEEPING, t0, t1, rows=len(chunk), batch=bid,
+            )
+        return plan
+
+    def _launch_chunk(
+        self,
+        features: dict[str, np.ndarray],
+        plan: DedupTiers.Plan,
+        chunk: list[int],
+        wasm_infos: dict[int, dict],
+        single: bool,
+        rec: Any,
+        bid: int,
+    ) -> tuple[Any, dict[str, list]]:
+        """Step 3: ship the plan's rows (async). Returns the fetch of the
+        result — run inline by whoever asks for a single chunk's, on the
+        drain pool otherwise — and the wasm stash."""
+        if plan.ship_pos is not None:
+            # compact: only the rows a request waits for cross the wire
+            # (a copy of the batch: kept out of the bookkeeping span)
+            packed = features[PACKED_KEY]
+            rows = np.zeros(
+                (self.bucket_for(plan.n_rows), packed.shape[1]), packed.dtype
+            )
+            rows[: plan.n_rows] = packed[plan.ship_pos]
+            features = {PACKED_KEY: rows}
+        stash = self._add_wasm_bits(
+            features, features[PACKED_KEY].shape[0],
+            [
+                (slot, wasm_infos[chunk[pos]])
+                for slot, pos in plan.slot_rows
+                if chunk[pos] in wasm_infos
+            ] if wasm_infos else None,
+        )
+        t_launch = time.perf_counter_ns() if rec is not None else 0
+        dev_out = self._dispatch_features(features, rows=plan.n_rows)
+        if rec is not None:
+            # plane selection, the jit call's host-to-device copies
+            # and the enqueue: on the chip, milliseconds a batch
+            # between encode's end and the program's start, and with
+            # encode most of the device's idle time (PERF.md, PR 27)
+            rec.record_phase(
+                flightrec.PH_LAUNCH, t_launch, time.perf_counter_ns(),
+                rows=plan.n_rows, batch=bid,
+            )
+        self._profile_add(dispatched_rows=plan.n_rows, dispatched_chunks=1)
+        fetch = (_InlineFetch if single else self._drain_pool.submit)(
+            self._scoped_device_fetch, failpoints.current_scope(), dev_out,
+            bid, plan.n_rows,
+        )
+        return fetch, stash
+
+    def _land_chunk(
+        self,
+        fetch: Any,
+        plan: DedupTiers.Plan,
+        stash: dict[str, list],
+        chunk: list[int],
+        items: list[tuple[str, ValidateRequest]],
+        targets: list[Any],
+        results: list[AdmissionResponse | Exception | None],
+        rec: Any,
+        bid: int,
+    ) -> None:
+        """Step 4: block on a launched chunk's fetch, teach the tiers its
+        rows, materialize the responses of the rows that rode it."""
+        t0 = time.perf_counter_ns()
+        raw = np.asarray(fetch.result())
+        t1 = time.perf_counter_ns()
+        self._profile_add(dispatch_wait_ns=t1 - t0)
+        if self._tiers is not None:
+            self._tiers.learn(plan, raw)
+        outputs = self._unpack(raw)
+        outputs.update(stash)
+        for slot, pos in plan.slot_rows:
+            i = chunk[pos]
+            results[i] = self._materialize(
+                targets[i], items[i][1], _RowView(outputs, slot)
+            )
+        if rec is not None:
+            t2 = time.perf_counter_ns()
+            rec.record_phase(
+                flightrec.PH_FETCH, t0, t1, rows=len(plan.slot_rows),
+                batch=bid,
+            )
+            rec.record_phase(
+                flightrec.PH_MATERIALIZE, t1, t2,
+                rows=len(plan.slot_rows), batch=bid,
+            )
 
     # -- response materialization (host side) ------------------------------
 

@@ -72,7 +72,15 @@ import struct
 import threading
 from collections import OrderedDict
 from operator import itemgetter
-from typing import Any, Callable, Hashable, Iterable, Mapping, Sequence
+from typing import (
+    Any,
+    Callable,
+    Hashable,
+    Iterable,
+    Mapping,
+    NamedTuple,
+    Sequence,
+)
 
 import numpy as np
 
@@ -305,3 +313,326 @@ class VerdictCache:
                 "cache_bytes": self._bytes,
                 "cache_capacity": self.capacity_bytes,
             }
+
+
+class ChunkPlan(NamedTuple):
+    """Who answers each row of one encoded chunk. Every row the ok mask
+    admits is in exactly one of ``hits`` and ``slot_rows``; positions are
+    the chunk's own (the caller knows which request sits at each)."""
+
+    # (position, cached row): answered by the row tier
+    hits: "list[tuple[int, bytes | Mapping[str, Any]]]"
+    # (slot, position): answered by row ``slot`` of the dispatch, its own
+    # or, for an in-chunk duplicate, another row's
+    slot_rows: list[tuple[int, int]]
+    # the encode positions to ship, in slot order; None when nothing
+    # collapsed: the encode buffer ships as it is, a slot is a position
+    ship_pos: "np.ndarray | None"
+    # rows of the dispatch that some request waits for
+    n_rows: int
+    # (key, slot) of what the dispatch teaches each tier
+    row_puts: list[tuple[Hashable, int]]
+    blob_puts: list[tuple[Hashable, int]]
+
+
+def _row_identity(rows: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """``(first, inverse)`` of the distinct rows of a contiguous 2-D
+    array in ONE vectorized pass: a void view makes each row one
+    comparable scalar, so ``np.unique`` replaces a per-row
+    tobytes-and-dict loop."""
+    void = rows.view(
+        np.dtype((np.void, rows.shape[1] * rows.itemsize))
+    ).ravel()
+    _uniq, first, inverse = np.unique(
+        void, return_index=True, return_inverse=True
+    )
+    return first, np.asarray(inverse).ravel()
+
+
+def _combos(
+    first: np.ndarray, inverse: np.ndarray, tids: np.ndarray, one_target: bool
+) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """The distinct (target, row) combos of a chunk's rows: for each
+    combo its target id, the first row that shows it and which distinct
+    row it is; for each row its combo. Same row bytes under different
+    targets share a dispatch slot but carry separate cache keys. With
+    one target the combo space IS the row space."""
+    m = first.size
+    if one_target:
+        return np.zeros(m, dtype=np.intp), first, np.arange(m), inverse
+    combos, combo_first, combo_inverse = np.unique(
+        tids * m + inverse, return_index=True, return_inverse=True
+    )
+    return (
+        combos // m, combo_first, combos % m,
+        np.asarray(combo_inverse).ravel(),
+    )
+
+
+class DedupTiers:
+    """The two tiers in front of the device (module docstring) and every
+    rule about them: lookup order, key shapes, back-fill, in-chunk
+    duplicate collapse, how hits are counted. The environment holds one
+    (None: caching off, or the oracle backend) and brings target keys
+    (one shared tuple a target), blobs and encoded rows, no more.
+
+    Keys: blob tier ``(target key, payload blob)``, row tier ``(target
+    key, packed row bytes)``.
+
+    Back-fill of the blob tier is BOUNDED: on uid-varying rollout
+    traffic nearly every blob never recurs, and a put a row (~4 us) would
+    churn the byte-bounded tier out of its genuine exact-replay entries.
+    A dispatch teaches it one representative blob a dispatched slot, a
+    row-tier hit one a hit combo a chunk, the host fast path's row-tier
+    hit none (its blob went in when the row first missed). A replayed
+    stream still converges, one representative a cycle."""
+
+    Plan = ChunkPlan  # what plan() and passthrough() return
+
+    def __init__(self, capacity_bytes: int) -> None:
+        # the byte budget is split between the tiers
+        self.row = VerdictCache(max(1, capacity_bytes // 2))
+        self.blob = VerdictCache(max(1, capacity_bytes - capacity_bytes // 2))
+        self._lock = threading.Lock()
+        # rows answered by another identical row of the SAME chunk
+        self._batch_dup_hits = 0  # guarded-by: _lock
+        # tier hits the environment answered as pre-built fragments
+        self._fragment_hits = 0  # guarded-by: _lock
+
+    # -- before encode: exact payload replays ----------------------------
+
+    def get_blobs(
+        self,
+        target_keys: Iterable[Hashable | None],
+        blobs: Iterable[bytes],
+    ) -> "list[bytes | Mapping[str, Any] | None]":
+        """The blob tier's answer to each (target, blob) of a batch, ONE
+        locked lookup; a None target key is an uncacheable row and comes
+        back None, uncounted."""
+        return self.blob.get_many(
+            None if tkey is None else (tkey, blob)
+            for tkey, blob in zip(target_keys, blobs)
+        )
+
+    # -- the host fast path: one request ---------------------------------
+
+    def get_one(
+        self,
+        target_key: Hashable,
+        blob: bytes,
+        packed_row_of: Callable[[bytes], "bytes | None"],
+    ) -> "tuple[bytes | Mapping[str, Any] | None, tuple | None]":
+        """``(row, None)`` on a hit, else ``(None, keys)`` with what
+        ``put_one`` files the evaluated row under. Blob tier first: its
+        key is in hand, so an exact replay costs no encode; the row key
+        (``packed_row_of``: a single-row encode, None where it cannot be
+        had) is only paid for on a blob miss."""
+        blob_key = (target_key, blob)
+        row = self.blob.get(blob_key)
+        if row is not None:
+            return row, None
+        row_key = None
+        packed = packed_row_of(blob)
+        if packed is not None:
+            row_key = (target_key, packed)
+            row = self.row.get(row_key)
+            if row is not None:
+                return row, None  # and no blob back-fill (class docstring)
+        return None, (row_key, blob_key)
+
+    def put_one(self, keys: tuple, row: "bytes | Mapping[str, Any]") -> None:
+        row_key, blob_key = keys
+        if row_key is not None:
+            self.row.put(row_key, row)
+        self.blob.put(blob_key, row)
+
+    # -- after encode: one chunk -----------------------------------------
+
+    def plan(
+        self,
+        packed: np.ndarray,
+        ok_mask: np.ndarray,
+        wasm_pos: Sequence[int],
+        target_ids: np.ndarray,
+        target_keys: Sequence[Hashable],
+        blobs: Sequence[bytes],
+    ) -> ChunkPlan:
+        """Lay out one encoded chunk: a (target, row) combo the row tier
+        holds is a hit for every row that shows it, in-chunk duplicates
+        collapse onto one dispatched row, and only distinct missed rows
+        ship (equal packed bytes, equal outputs: module docstring).
+
+        ``target_ids[pos]`` indexes ``target_keys``. ``wasm_pos`` (ok
+        positions, ascending) carry verdict bits beside the row that are
+        no function of its bytes: never deduped or cached, they take the
+        first slots of a compacted dispatch. ONE locked lookup and at
+        most one locked back-fill; reads and writes the tiers and
+        nothing else."""
+        n_wasm = len(wasm_pos)
+        dedup_pos = np.flatnonzero(ok_mask)
+        if n_wasm:
+            dedup_pos = dedup_pos[~np.isin(dedup_pos, wasm_pos)]
+        hits: list = []
+        slot_rows: list[tuple[int, int]] = []
+        row_puts: list = []
+        blob_puts: list = []
+        uncompacted = False
+        keep_pos = miss_rows = dedup_pos[:0]
+        if dedup_pos.size:
+            rows_arr = np.ascontiguousarray(packed[dedup_pos])
+            first, inverse = _row_identity(rows_arr)
+            combo_tid, combo_first, combo_row, combo_inverse = _combos(
+                first, inverse, target_ids[dedup_pos], len(target_keys) == 1
+            )
+            keys = [
+                (target_keys[tid], rows_arr[at].tobytes())
+                for tid, at in zip(combo_tid.tolist(), combo_first.tolist())
+            ]
+            hits, hit_flags = self._row_tier_hits(
+                keys, combo_inverse, dedup_pos, blobs
+            )
+            miss_rows = np.flatnonzero(~hit_flags[combo_inverse])
+        if miss_rows.size:
+            uniq_miss, miss_first = np.unique(
+                inverse[miss_rows], return_index=True
+            )
+            dup_hits = int(miss_rows.size - uniq_miss.size)
+            if dup_hits:
+                with self._lock:
+                    self._batch_dup_hits += dup_hits
+            keep_pos = dedup_pos[miss_rows[miss_first]]
+            # nothing collapsed: ship the encode buffer as it is
+            uncompacted = (
+                not n_wasm and not hits and not dup_hits
+                and bool(ok_mask.all())
+            )
+            # the slot of each distinct missed row: its encode position
+            # in the buffer as it is, else its place among the kept rows
+            slot_of = np.empty(first.size, dtype=np.intp)
+            slot_of[uniq_miss] = (
+                keep_pos if uncompacted
+                else np.arange(n_wasm, n_wasm + uniq_miss.size)
+            )
+            miss_pos = dedup_pos[miss_rows].tolist()
+            slot_rows = list(
+                zip(slot_of[inverse[miss_rows]].tolist(), miss_pos)
+            )
+            miss_combos = np.flatnonzero(~hit_flags)
+            combo_slots = slot_of[combo_row[miss_combos]].tolist()
+            row_puts = [
+                (keys[k], slot)
+                for k, slot in zip(miss_combos.tolist(), combo_slots)
+            ]
+            blob_puts = [
+                ((target_keys[tid], blobs[pos]), slot)
+                for tid, pos, slot in zip(
+                    target_ids[keep_pos].tolist(), keep_pos.tolist(),
+                    slot_of[uniq_miss].tolist(),
+                )
+            ]
+        slot_rows += enumerate(wasm_pos)
+        ship_pos = None
+        if not uncompacted:
+            ship_pos = np.concatenate(
+                (np.asarray(wasm_pos, dtype=np.intp), keep_pos)
+            )
+        return ChunkPlan(
+            hits, slot_rows, ship_pos, n_wasm + keep_pos.size,
+            row_puts, blob_puts,
+        )
+
+    @staticmethod
+    def passthrough(ok_mask: np.ndarray) -> ChunkPlan:
+        """The plan of a chunk no tier stands in front of: every row
+        rides its own encode position and nothing is learned."""
+        slot_rows = [(pos, pos) for pos in np.flatnonzero(ok_mask).tolist()]
+        return ChunkPlan([], slot_rows, None, len(slot_rows), [], [])
+
+    def _row_tier_hits(
+        self,
+        keys: list[tuple[Hashable, bytes]],
+        combo_inverse: np.ndarray,
+        dedup_pos: np.ndarray,
+        blobs: Sequence[bytes],
+    ) -> tuple[list, np.ndarray]:
+        """ONE locked lookup of a chunk's combo keys: ``(position, cached
+        row)`` of every row a held combo answers, and which combos were
+        held. Counts rows, and back-fills the blob tier with one
+        representative blob a hit combo (class docstring)."""
+        cached = self.row.get_many(keys)
+        hit_flags = np.fromiter(
+            (c is not None for c in cached), dtype=bool, count=len(cached)
+        )
+        hit_rows = np.flatnonzero(hit_flags[combo_inverse])
+        # get_many counted one hit/miss per combo KEY; the counters mean
+        # ROWS served from / missed by the row tier
+        n_hit_keys = int(hit_flags.sum())
+        self.row.adjust_counts(
+            hits=int(hit_rows.size) - n_hit_keys,
+            misses=(int(dedup_pos.size) - int(hit_rows.size))
+            - (len(cached) - n_hit_keys),
+        )
+        hits = []
+        backfill: dict[int, tuple] = {}  # the first row of each hit combo
+        for pos, k in zip(
+            dedup_pos[hit_rows].tolist(), combo_inverse[hit_rows].tolist()
+        ):
+            hits.append((pos, cached[k]))
+            if k not in backfill:
+                backfill[k] = ((keys[k][0], blobs[pos]), cached[k])
+        if backfill:
+            self.blob.put_many(backfill.values())
+        return hits, hit_flags
+
+    def learn(self, plan: ChunkPlan, fetched: np.ndarray) -> None:
+        """File the fetched output rows of a planned dispatch under both
+        tiers' keys. A dispatched row's entry IS its bytes of the fetched
+        array: one copy of the batch, a slice a slot, the same object
+        under every key of both tiers."""
+        if not (plan.row_puts or plan.blob_puts):
+            return
+        width = fetched.shape[1] * fetched.itemsize
+        whole = fetched.tobytes()
+        rows = [whole[at : at + width] for at in range(0, len(whole), width)]
+        if plan.row_puts:
+            self.row.put_many(
+                [(key, rows[slot]) for key, slot in plan.row_puts]
+            )
+        if plan.blob_puts:
+            self.blob.put_many(
+                [(key, rows[slot]) for key, slot in plan.blob_puts]
+            )
+
+    # -- counters ---------------------------------------------------------
+
+    def count_fragment_hits(self, n: int) -> None:
+        with self._lock:
+            self._fragment_hits += n
+
+    @property
+    def batch_dup_hits(self) -> int:
+        with self._lock:
+            return self._batch_dup_hits
+
+    def clear(self) -> None:
+        """Drop every cached row of both tiers; the counters are
+        cumulative serving metrics and stay."""
+        self.row.clear()
+        self.blob.clear()
+
+    def stats(self) -> dict[str, int]:
+        """``cache_*`` keys are the row tier (legacy names), ``blob_*``
+        the blob tier."""
+        stats = self.row.stats()
+        for k, v in self.blob.stats().items():
+            stats["blob_" + k] = v
+        with self._lock:
+            stats["batch_dup_hits"] = self._batch_dup_hits
+            stats["fragment_hits"] = self._fragment_hits
+        return stats
+
+    @classmethod
+    def stats_when_off(cls) -> dict[str, int]:
+        """``stats()`` of an environment that holds no tiers: the same
+        keys, all zero."""
+        return dict.fromkeys(cls(2).stats(), 0)

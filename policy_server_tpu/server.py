@@ -320,9 +320,8 @@ class PolicyServer:
                 window_seconds=config.breaker_window_seconds,
                 cooldown_seconds=config.breaker_cooldown_seconds,
             ),
-            # columnar device transport + input-buffer donation (round 12)
+            # columnar device transport (round 12)
             columnar=config.columnar,
-            donate_buffers=config.donate_buffers,
             # predicate-program optimizer (round 15)
             predicate_opt=config.predicate_opt,
         )
@@ -1229,12 +1228,6 @@ class PolicyServer:
                 "32-bit feature columns in the dispatched schemas (hit "
                 "rate = 1 - shipped/total)",
                 profile.get("delta_cols_total", 0),
-            )
-            yield (
-                metrics_names.DONATED_DISPATCHES, "counter",
-                "Columnar dispatches whose input buffers were donated "
-                "(jax donate_argnums)",
-                profile.get("donated_dispatches", 0),
             )
             yield (
                 metrics_names.RESIDENT_CONST_BYTES, "counter",

@@ -136,7 +136,6 @@ WIRE_BYTES_PACKED_EQUIV = "policy_server_wire_bytes_packed_equivalent"
 WIRE_ROWS = "policy_server_wire_rows"
 DELTA_COLS_SHIPPED = "policy_server_delta_columns_shipped"
 DELTA_COLS_TOTAL = "policy_server_delta_columns_available"
-DONATED_DISPATCHES = "policy_server_donated_buffer_dispatches"
 RESIDENT_CONST_BYTES = "policy_server_device_resident_constant_bytes"
 # round 13 — cluster-scale soak + live watch feed: the audit snapshot
 # store's list+watch event accounting (audit/watch_feed.py), the native

@@ -241,7 +241,7 @@ def test_hits_answer_from_packed_entries_through_both_lanes(
     assert 0 < frag_hits < tier_hits  # some spliced, some materialized
     width = CONFIG["verdict_bytes_per_row"]["value"]
     assert width == 80 == len(env._out_layout.index)
-    for tier in (env._verdict_cache, env._blob_cache):
+    for tier in (env._tiers.row, env._tiers.blob):
         entries = list(tier._data.items())
         assert entries
         assert all(type(row) is bytes and len(row) == width
@@ -252,7 +252,7 @@ def test_hits_answer_from_packed_entries_through_both_lanes(
     # per target and verdict
     lanes = [lane for lane in env._frag_lanes.values() if lane]
     assert lanes and all(len(memo) <= 4 for lane in lanes for _f, memo in lane)
-    row_keys = [len(key[1]) for key in env._verdict_cache._data]
+    row_keys = [len(key[1]) for key in env._tiers.row._data]
     per_entry = stats["cache_bytes"] / stats["cache_entries"]
     assert per_entry == 256 + row_keys[0] + width
     assert (DEFAULT_VERDICT_CACHE_SIZE // 2) // per_entry >= 90_000

@@ -172,8 +172,8 @@ class TestColumnarParity:
         once per column set, its index vectors' (they go to the device
         when the set first launches and stay there, so a pass over a
         settled corpus adds none); they are strictly below the
-        packed-form equivalent, and every columnar dispatch was
-        donated."""
+        packed-form equivalent, and every dispatched chunk was one
+        columnar launch."""
         _settle(col_env, corpus)
         launched = _record_launches(col_env, monkeypatch)
         before = col_env.host_profile
@@ -186,7 +186,6 @@ class TestColumnarParity:
             - before["wire_bytes_packed_equiv"]
         )
         rows = after["wire_rows"] - before["wire_rows"]
-        donated = after["donated_dispatches"] - before["donated_dispatches"]
         chunks = after["dispatched_chunks"] - before["dispatched_chunks"]
         assert rows > 0 and shipped > 0
         assert all(served for *_rest, served in launched)
@@ -196,7 +195,7 @@ class TestColumnarParity:
         )
         assert all(list(sent) == [WIRE_KEY] for _s, _f, sent, _ in launched)
         assert shipped < packed
-        assert donated == chunks == len(launched)
+        assert chunks == len(launched)
         assert (
             after["delta_cols_shipped"] - before["delta_cols_shipped"]
             <= after["delta_cols_total"] - before["delta_cols_total"]
@@ -232,22 +231,6 @@ class TestColumnarParity:
             assert env.host_profile["wire_bytes_shipped"] == wire + indices
         finally:
             env.close()
-
-    def test_donation_off_still_bit_exact(self, corpus):
-        env = EvaluationEnvironmentBuilder(
-            backend="jax", donate_buffers=False
-        ).build(_parsed())
-        oracle_env = EvaluationEnvironmentBuilder(backend="oracle").build(
-            _parsed()
-        )
-        try:
-            assert _dicts(env.validate_batch(corpus)) == _dicts(
-                oracle_env.validate_batch(corpus)
-            )
-            assert env.host_profile["donated_dispatches"] == 0
-        finally:
-            env.close()
-            oracle_env.close()
 
     def test_all_zero_batch_planes_elided(self, col_env):
         """The warmup shape: an all-missing batch ships ZERO delta
@@ -364,22 +347,20 @@ class TestOneWireBuffer:
     """The columnar launch ships ONE packed wire buffer and its column
     indices stay on the device (PR 28)."""
 
-    @pytest.mark.parametrize("donate", [True, False], ids=["donate", "keep"])
     @pytest.mark.parametrize(
         "mode", ["settled", "dense-fallback", "all-elided", "whole-plane"]
     )
     @pytest.mark.parametrize("narrow", [True, False], ids=["u16", "i32"])
     def test_one_buffer_equals_row_packed_and_oracle(
-        self, narrow, mode, donate, corpus, references, monkeypatch
+        self, narrow, mode, corpus, references, monkeypatch
     ):
         """Every form of the wire buffer, on the narrow and the
-        full-width id plane, with donation on and off, answers what the
-        row-packed transport and the oracle answer — launched TWICE
-        through the same resident index vectors, which a donated one
-        would not survive."""
+        full-width id plane, answers what the row-packed transport and
+        the oracle answer — launched TWICE through the same resident
+        index vectors."""
         row, zero = references
         env = EvaluationEnvironmentBuilder(
-            backend="jax", verdict_cache_size=0, donate_buffers=donate
+            backend="jax", verdict_cache_size=0
         ).build(_parsed())
         try:
             if not narrow:
@@ -402,7 +383,6 @@ class TestOneWireBuffer:
             elif mode != "all-elided":
                 _settle(env, corpus)
             del launched[:]
-            donated = env.host_profile["donated_dispatches"]
             for _ in range(2):
                 if mode == "all-elided":
                     got = env.run_batch(env.schemas[0].empty_batch_packed(8))
@@ -436,9 +416,6 @@ class TestOneWireBuffer:
                     assert wire.dtype == np.uint8 and wire.flags.c_contiguous
                     assert wire.shape == (spec[1], form.width)
                     assert form.width % 4 == 0
-            assert env.host_profile["donated_dispatches"] - donated == (
-                len(launched) if donate else 0
-            )
         finally:
             env.close()
 

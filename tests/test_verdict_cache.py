@@ -20,6 +20,7 @@ from policy_server_tpu.evaluation.environment import (
 )
 from policy_server_tpu.evaluation.verdict_cache import (
     _ENTRY_OVERHEAD,
+    DedupTiers,
     OutputLayout,
     PackedRow,
     VerdictCache,
@@ -387,9 +388,9 @@ def test_device_entries_are_the_fetched_bytes_and_hits_answer_from_them(
     got = env.validate_batch(first)
     if want is not None:
         assert [r.to_dict() for r in got] == want
-    rows = {id(v) for v in env._verdict_cache._data.values()}
-    blob_rows = {id(v) for v in env._blob_cache._data.values()}
-    assert all(type(v) is bytes for v in env._verdict_cache._data.values())
+    rows = {id(v) for v in env._tiers.row._data.values()}
+    blob_rows = {id(v) for v in env._tiers.blob._data.values()}
+    assert all(type(v) is bytes for v in env._tiers.row._data.values())
     assert len(rows) == 1 and rows == blob_rows  # one dispatched row, shared
     s0 = env.dedup_stats
     again = env.validate_batch(  # fresh uids: row tier
@@ -419,7 +420,7 @@ def test_host_and_device_paths_share_a_key_in_both_directions(envs):
         env.reset_verdict_cache()
         env.validate_batch([(pid, pod_request("blocked", True, uid="h-1"))],
                            prefer_host=True)
-        assert all(isinstance(v, dict) for v in env._verdict_cache._data.values())
+        assert all(isinstance(v, dict) for v in env._tiers.row._data.values())
         p0, s0 = env.host_profile, env.dedup_stats
         dev = env.validate_batch([(pid, pod_request("blocked", True, uid="x"))])[0]
         assert env.host_profile["dispatched_rows"] == p0["dispatched_rows"]
@@ -428,7 +429,7 @@ def test_host_and_device_paths_share_a_key_in_both_directions(envs):
         # device put -> host-path hit (row tier, then blob tier)
         env.reset_verdict_cache()
         env.validate_batch([(pid, pod_request("blocked", True, uid="d-1"))])
-        assert all(type(v) is bytes for v in env._verdict_cache._data.values())
+        assert all(type(v) is bytes for v in env._tiers.row._data.values())
         s0 = env.dedup_stats
         fast = env.validate_batch(
             [(pid, pod_request("blocked", True, uid="x"))], prefer_host=True)[0]
@@ -452,8 +453,8 @@ def test_fragment_templates_live_in_the_environment_not_on_the_rows(envs):
     target = env._fast_target("priv")
     env.validate_batch([("priv", pod_request("fine", True, uid="d"))])
     env.validate_batch([("ns", pod_request("blocked", True, uid="d"))])
-    (row_a,) = [v for k, v in env._verdict_cache._data.items() if k[0] == ("p", "priv")]
-    (row_b,) = [v for k, v in env._verdict_cache._data.items() if k[0] == ("p", "ns")]
+    (row_a,) = [v for k, v in env._tiers.row._data.items() if k[0] == ("p", "priv")]
+    (row_b,) = [v for k, v in env._tiers.row._data.items() if k[0] == ("p", "ns")]
     assert row_a != row_b  # two dispatched rows, the same verdict of priv
     tmpl = env._frag_of(target, row_a)
     assert tmpl is not None and tmpl.allowed is False
@@ -558,3 +559,201 @@ def test_the_accounted_bytes_are_not_under_the_resident_bytes():
     assert accounted == n * (_ENTRY_OVERHEAD + KEY_BYTES + ROW_BYTES)
     assert resident <= accounted, (resident / n, accounted / n)
     assert resident > 0.9 * accounted  # and not far over either
+
+
+# -- the tiers' plan of one chunk: no environment, no jax ---------------------
+
+PLAN_WIDTH = 12
+# per scenario: the row "shape" at each chunk position (equal shape, equal
+# packed bytes), which positions failed to encode, which carry wasm bits,
+# and which shapes the row tier already holds (for every target)
+PLAN_SCENARIOS = {
+    "nothing-cached": ([0, 1, 2, 3, 4, 5, 6, 7], (), (), ()),
+    "all-cached": ([0, 1, 2, 3, 4, 5, 6, 7], (), (), range(8)),
+    "some-cached": ([0, 1, 2, 3, 4, 5, 6, 7], (), (), (1, 2, 6)),
+    "in-chunk-duplicates": ([0, 0, 1, 1, 0, 2, 2, 3, 1], (), (), ()),
+    "duplicates-of-a-cached-row": ([0, 0, 0, 1, 1, 2, 0, 3, 3], (), (), (0,)),
+    "wasm-beside-plain": ([0, 1, 1, 2, 0, 3, 3, 4], (), (1, 5), (2,)),
+    "overflowed-in-the-ok-mask": ([0, 1, 2, 3, 4, 5, 6, 7], (2, 6), (), ()),
+}
+
+
+def _plan_inputs(scenario: str, targets: str):
+    shapes, failed, wasm_pos, held = PLAN_SCENARIOS[scenario]
+    n = len(shapes)
+    packed = np.zeros((16, PLAN_WIDTH), np.uint8)
+    for pos, shape in enumerate(shapes):
+        packed[pos] = shape + 1
+        packed[pos, 0] = 200  # rows differ in ONE byte, not in all of them
+    ok_mask = np.array([pos not in failed for pos in range(n)])
+    target_keys = (
+        [("p", "only")] if targets == "uniform"
+        else [("p", "a"), ("g", "b"), ("p", "c")]
+    )
+    target_ids = np.arange(n, dtype=np.intp) % len(target_keys)
+    blobs = [b"blob-%d" % pos for pos in range(n)]
+    tiers = DedupTiers(1 << 20)
+    for tkey in target_keys:
+        for shape in held:
+            row = np.full(PLAN_WIDTH, shape + 1, np.uint8)
+            row[0] = 200
+            tiers.row.put((tkey, row.tobytes()), b"held-%s-%d" % (
+                tkey[1].encode(), shape))
+    return (packed, ok_mask, list(wasm_pos), target_ids, target_keys, blobs,
+            tiers)
+
+
+def _shipped(plan, packed):
+    """What the dispatch loop ships for a plan: the encode buffer, or the
+    plan's positions copied into a zeroed bucket."""
+    if plan.ship_pos is None:
+        return packed
+    rows = np.zeros((max(4, 2 * plan.n_rows), packed.shape[1]), packed.dtype)
+    rows[: plan.n_rows] = packed[plan.ship_pos]
+    return rows
+
+
+@pytest.mark.parametrize("targets", ["uniform", "mixed"])
+@pytest.mark.parametrize("scenario", list(PLAN_SCENARIOS))
+def test_the_plan_of_a_chunk_against_a_per_row_reference(scenario, targets):
+    """``DedupTiers.plan`` against a plain per-row dict walk of the same
+    chunk: every admitted row is answered exactly once (a hit, its own
+    slot, or another row's), slots index shipped rows that carry the
+    row's bytes, each tier learns each missed key once, the counters
+    move by ROWS, and the encode buffer ships as it is exactly when
+    nothing collapsed."""
+    packed, ok_mask, wasm_pos, tids, tkeys, blobs, tiers = _plan_inputs(
+        scenario, targets)
+    before, blob_puts0 = tiers.stats(), tiers.blob.puts
+    held = dict(tiers.row._data)
+
+    # the reference: one row at a time, a dict and a list of shipped rows
+    want_hits: dict[int, bytes] = {}
+    want_miss: dict[int, tuple] = {}   # position -> its (target key, bytes)
+    for pos in np.flatnonzero(ok_mask).tolist():
+        if pos in wasm_pos:
+            continue
+        key = (tkeys[tids[pos]], packed[pos].tobytes())
+        if key in held:
+            want_hits[pos] = held[key]
+        else:
+            want_miss[pos] = key
+    missed_rows = {key[1] for key in want_miss.values()}
+    missed_keys = set(want_miss.values())
+    collapsed = bool(
+        want_hits or wasm_pos or not ok_mask.all()
+        or len(missed_rows) < len(want_miss))
+
+    plan = tiers.plan(packed, ok_mask, wasm_pos, tids, tkeys, blobs)
+    shipped = _shipped(plan, packed)
+
+    # answered exactly once, by the source the reference names
+    assert dict(plan.hits) == want_hits and len(plan.hits) == len(want_hits)
+    assert all(got is want_hits[pos] for pos, got in plan.hits)
+    rode = [pos for _slot, pos in plan.slot_rows]
+    assert sorted(rode) == sorted([*want_miss, *wasm_pos])
+    assert sorted([*rode, *want_hits]) == np.flatnonzero(ok_mask).tolist()
+    # a slot carries its row's bytes; distinct missed rows, a slot each,
+    # a wasm row a slot of its own
+    for slot, pos in plan.slot_rows:
+        assert shipped[slot].tobytes() == packed[pos].tobytes()
+    slots = {slot for slot, _pos in plan.slot_rows}
+    assert len(slots) == plan.n_rows == len(missed_rows) + len(wasm_pos)
+    wasm_slots = {slot for slot, pos in plan.slot_rows if pos in wasm_pos}
+    assert len(wasm_slots) == len(wasm_pos)
+    assert not wasm_slots & {
+        slot for slot, pos in plan.slot_rows if pos not in wasm_pos}
+    # the short cut: the encode buffer itself, slots its positions
+    if plan.slot_rows:
+        assert (plan.ship_pos is None) == (not collapsed)
+    if plan.ship_pos is None:
+        assert shipped is packed
+        assert all(slot == pos for slot, pos in plan.slot_rows)
+    elif plan.slot_rows:
+        assert slots == set(range(plan.n_rows))  # compacted: dense slots
+        assert len(plan.ship_pos) == plan.n_rows
+    # what the dispatch will teach: each missed key once, on the slot
+    # that carries its bytes; one representative blob a non-wasm slot
+    assert sorted(key for key, _slot in plan.row_puts) == sorted(missed_keys)
+    for (_tkey, row_bytes), slot in plan.row_puts:
+        assert shipped[slot].tobytes() == row_bytes
+    blob_slots = [slot for _key, slot in plan.blob_puts]
+    assert sorted(blob_slots) == sorted(slots - wasm_slots)
+    for (tkey, blob), slot in plan.blob_puts:
+        pos = blobs.index(blob)
+        assert (slot, pos) in plan.slot_rows and tkey == tkeys[tids[pos]]
+    # the counters count rows; a hit combo back-fills ONE blob
+    after = tiers.stats()
+    assert after["cache_hits"] - before["cache_hits"] == len(want_hits)
+    assert after["cache_misses"] - before["cache_misses"] == len(want_miss)
+    assert after["batch_dup_hits"] - before["batch_dup_hits"] == (
+        len(want_miss) - len(missed_rows))
+    assert tiers.batch_dup_hits == after["batch_dup_hits"]
+    hit_combos = {(tids[pos], packed[pos].tobytes()) for pos in want_hits}
+    assert tiers.blob.puts - blob_puts0 == len(hit_combos)
+
+    # learn, then the same chunk again: every plain row is a hit of the
+    # row its slot fetched, both tiers hold the one object
+    fetched = np.arange(shipped.shape[0] * 5, dtype=np.uint8).reshape(-1, 5)
+    tiers.learn(plan, fetched)
+    again = tiers.plan(packed, ok_mask, wasm_pos, tids, tkeys, blobs)
+    assert sorted(pos for pos, _row in again.hits) == sorted(
+        [*want_hits, *want_miss])
+    assert [pos for _slot, pos in again.slot_rows] == wasm_pos
+    assert not again.row_puts and not again.blob_puts
+    slot_of = {pos: slot for slot, pos in plan.slot_rows}
+    for pos, row in again.hits:
+        if pos in want_miss:
+            assert row == fetched[slot_of[pos]].tobytes()
+    for (tkey, blob), slot in plan.blob_puts:
+        pos = blobs.index(blob)
+        assert tiers.blob._data[(tkey, blob)] is tiers.row._data[
+            (tkey, packed[pos].tobytes())]
+
+
+@pytest.mark.parametrize("scenario", list(PLAN_SCENARIOS))
+def test_without_tiers_every_admitted_row_rides_its_own_position(scenario):
+    """The form the dispatch loop gets with caching off: the same record,
+    the encode buffer as it is, nothing to learn."""
+    _packed, ok_mask, *_rest = _plan_inputs(scenario, "uniform")
+    plan = DedupTiers.passthrough(ok_mask)
+    admitted = np.flatnonzero(ok_mask).tolist()
+    assert plan.ship_pos is None and plan.n_rows == len(admitted)
+    assert plan.slot_rows == [(pos, pos) for pos in admitted]
+    assert plan.hits == plan.row_puts == plan.blob_puts == []
+
+
+def test_the_host_fast_path_rule_blob_first_and_no_blob_backfill():
+    """``get_one`` / ``put_one``: the blob tier is asked first and the row
+    key is only computed on a blob miss; a row-tier hit back-fills no
+    blob; a miss files the evaluated row under both keys; where the row
+    key cannot be had the blob tier alone learns."""
+    tiers = DedupTiers(1 << 20)
+    encodes: list[bytes] = []
+
+    def packed_row_of(blob: bytes) -> bytes:
+        encodes.append(blob)
+        return b"row-of-" + blob[:4]
+
+    tkey = ("p", "x")
+    row, keys = tiers.get_one(tkey, b"podA-uid1", packed_row_of)
+    assert row is None and keys == (
+        (tkey, b"row-of-podA"), (tkey, b"podA-uid1"))
+    tiers.put_one(keys, {"v": 1})
+    assert tiers.get_one(tkey, b"podA-uid1", packed_row_of) == ({"v": 1}, None)
+    assert encodes == [b"podA-uid1"]  # the replay paid no encode
+    blob_entries = len(tiers.blob)
+    row, keys = tiers.get_one(tkey, b"podA-uid2", packed_row_of)
+    assert row == {"v": 1} and keys is None  # the uid variant: row tier
+    assert len(tiers.blob) == blob_entries
+    row, keys = tiers.get_one(tkey, b"podB-uid3", lambda blob: None)
+    assert row is None and keys == (None, (tkey, b"podB-uid3"))
+    tiers.put_one(keys, {"v": 2})
+    assert len(tiers.row) == 1 and len(tiers.blob) == blob_entries + 1
+    stats = tiers.stats()
+    assert (stats["cache_hits"], stats["blob_cache_hits"]) == (1, 1)
+    assert stats.keys() == DedupTiers.stats_when_off().keys()
+    assert not any(DedupTiers.stats_when_off().values())
+    tiers.clear()
+    assert len(tiers.row) == len(tiers.blob) == 0
+    assert tiers.stats()["cache_hits"] == 1  # counters are cumulative

@@ -196,7 +196,6 @@ def profile_delta(after: dict, before: dict) -> dict:
             / max(1, d.get("delta_cols_total", 0)),
             4,
         ),
-        "donated_dispatches": d.get("donated_dispatches", 0),
     }
 
 
