@@ -5,8 +5,10 @@
 // policy-derived feature arrays (ops/codec.py). This is the native
 // implementation of exactly that codec: a minimal JSON parser fused with the
 // extraction trie, writing numeric/bool/presence features straight into the
-// caller's numpy buffers and collecting ID/pred strings into an arena for
-// the (memoized, cheap) Python-side interning pass.
+// caller's numpy buffers. ID/pred strings resolve against a MIRROR of the
+// Python intern table kept in the encoder handle (string bytes -> id and
+// predicate bits, published by Python after it interned them); only strings
+// the mirror has not seen go back to Python, as records over an arena.
 //
 // Semantics mirror ops/codec.py bit for bit:
 //   * dtype mismatches are "missing" (mask stays 0): ID wants a JSON string;
@@ -27,7 +29,9 @@
 #include <cstdlib>
 #include <cstring>
 #include <algorithm>
+#include <atomic>
 #include <memory>
+#include <mutex>
 #include <string>
 #include <unordered_map>
 #include <vector>
@@ -59,14 +63,102 @@ struct ArrayInfo {
   int32_t ndim;        // 0..2 element axes
   int32_t caps[2];     // axis capacities
   int32_t elsize;      // bytes per element in the caller buffer
-  int64_t row_stride;  // batch-mode row stride in BYTES; 0 = contiguous
-                       // (elems*elsize). Non-zero when the array is a
-                       // column block of a wider packed batch buffer.
+  int64_t row_stride;  // batch-mode row stride in BYTES: the width of the
+                       // packed batch buffer the array is a column block of
+  int64_t offset;      // batch-mode byte offset of that block within a row
+};
+
+// ----------------------------------------------------------------- mirror --
+// What the Python intern table (utils/interning.py) said about the strings
+// this encoder has seen: bytes -> id, and the bit of each of this encoder's
+// predicates. The table stays the only source of ids and bits; Python
+// publishes an entry (fastenc_learn) only after table.intern returned, so
+// the table's publish-last rule holds here by construction.
+//
+// Many threads encode on one handle at once, GIL released, and must not wait
+// on each other or on a publisher: the slots are a fixed open-addressed
+// array of pointers, never resized and never cleared, so a reader is
+// wait-free (acquire loads along a probe run) and a publisher, under a mutex
+// only publishers take, fills an entry completely before one release store
+// makes it visible. Bounded by construction: past kMaxEntries or kMaxBytes
+// nothing more is published and a miss keeps the record path. Exact either
+// way.
+
+struct MirrorEntry {
+  uint64_t hash;
+  int32_t id;
+  uint32_t len;
+  // then len key bytes, then one byte per predicate of the encoder
+  const char* key() const { return (const char*)(this + 1); }
+  const uint8_t* bits() const { return (const uint8_t*)key() + len; }
+};
+
+struct Mirror {
+  static constexpr size_t kSlots = 1 << 16;       // power of two
+  static constexpr int64_t kMaxEntries = 1 << 15;  // slots at most half full
+  static constexpr int64_t kMaxBytes = 4 << 20;    // entries, keys and bits
+  static constexpr size_t kChunk = 1 << 16;
+
+  std::unique_ptr<std::atomic<const MirrorEntry*>[]> slots{
+      new std::atomic<const MirrorEntry*>[kSlots]()};
+  int32_t n_preds = 0;
+  std::atomic<int64_t> entries{0};
+  // publishers only
+  bool full = false;  // a cap refused an entry
+  std::mutex publish_mu;
+  std::vector<std::unique_ptr<char[]>> chunks;
+  size_t chunk_used = kChunk;
+  int64_t bytes = 0;
+
+  static uint64_t hash_of(const char* s, size_t n) {
+    uint64_t h = 1469598103934665603ull;  // FNV-1a
+    for (size_t i = 0; i < n; i++) h = (h ^ (unsigned char)s[i]) * 1099511628211ull;
+    return h;
+  }
+
+  const MirrorEntry* find(const char* s, size_t n, uint64_t h) const {
+    for (size_t i = (size_t)h & (kSlots - 1);; i = (i + 1) & (kSlots - 1)) {
+      const MirrorEntry* e = slots[i].load(std::memory_order_acquire);
+      if (!e) return nullptr;
+      if (e->hash == h && e->len == n && memcmp(e->key(), s, n) == 0) return e;
+    }
+  }
+
+  // Caller holds publish_mu.
+  void publish(const char* s, size_t n, int32_t id, const uint8_t* bits) {
+    uint64_t h = hash_of(s, n);
+    if (find(s, n, h)) return;
+    size_t need = (sizeof(MirrorEntry) + n + (size_t)n_preds + 7) & ~(size_t)7;
+    if (need > kChunk) return;  // a key too long to keep misses every time
+    if (entries.load(std::memory_order_relaxed) >= kMaxEntries ||
+        bytes + (int64_t)need > kMaxBytes) {
+      full = true;
+      return;
+    }
+    if (need > kChunk - chunk_used) {
+      chunks.emplace_back(new char[kChunk]);
+      chunk_used = 0;
+    }
+    char* at = chunks.back().get() + chunk_used;
+    chunk_used += need;
+    MirrorEntry* e = (MirrorEntry*)at;
+    e->hash = h;
+    e->id = id;
+    e->len = (uint32_t)n;
+    memcpy(at + sizeof(MirrorEntry), s, n);
+    if (n_preds) memcpy(at + sizeof(MirrorEntry) + n, bits, (size_t)n_preds);
+    bytes += (int64_t)need;
+    size_t i = (size_t)h & (kSlots - 1);
+    while (slots[i].load(std::memory_order_relaxed)) i = (i + 1) & (kSlots - 1);
+    slots[i].store(e, std::memory_order_release);
+    entries.fetch_add(1, std::memory_order_relaxed);
+  }
 };
 
 struct Schema {
   Node root;
   std::vector<ArrayInfo> arrays;
+  Mirror mirror;
 };
 
 // ------------------------------------------------------ schema JSON parse --
@@ -241,6 +333,7 @@ struct StringRecord {
 
 struct EncodeState {
   const Schema* schema;
+  const Mirror* mirror = nullptr;  // null: every string leaf is a record
   uint8_t** buffers;       // array_id -> destination buffer
   std::string arena;       // collected ID/pred strings
   std::vector<StringRecord> records;
@@ -282,6 +375,18 @@ inline bool fits_f32(double v) {
 
 bool emit_terminals(EncodeState& st, const Node& node, const Leaf& leaf,
                     const int32_t* coords, int depth) {
+  // what the mirror knows of this leaf's string, looked up once for all of
+  // the node's terminals; null: not a string, no mirror, or never seen
+  const MirrorEntry* known = nullptr;
+  bool looked = false;
+  auto resolve = [&]() {
+    if (!looked && st.mirror) {
+      known = st.mirror->find(leaf.s->data(), leaf.s->size(),
+                              Mirror::hash_of(leaf.s->data(), leaf.s->size()));
+    }
+    looked = true;
+    return known;
+  };
   for (const Terminal& t : node.terminals) {
     const ArrayInfo& a = st.schema->arrays[(size_t)t.array_id];
     int32_t off = flat_offset(a, coords, depth);
@@ -292,6 +397,10 @@ bool emit_terminals(EncodeState& st, const Node& node, const Leaf& leaf,
         break;
       case KIND_PRED:
         if (leaf.type == LEAF_STR) {
+          if (resolve()) {
+            st.buffers[t.array_id][off] = known->bits()[t.pred_id];
+            break;
+          }
           st.records.push_back({t.array_id, off, 1, t.pred_id,
                                 (int32_t)st.arena.size(),
                                 (int32_t)leaf.s->size()});
@@ -306,11 +415,15 @@ bool emit_terminals(EncodeState& st, const Node& node, const Leaf& leaf,
         switch (t.dtype) {
           case DT_ID:
             if (leaf.type == LEAF_STR) {
+              if (mask) mask[off] = 1;
+              if (resolve()) {
+                ((int32_t*)buf)[off] = known->id;
+                break;
+              }
               st.records.push_back({t.array_id, off, 0, -1,
                                     (int32_t)st.arena.size(),
                                     (int32_t)leaf.s->size()});
               st.arena.append(*leaf.s);
-              if (mask) mask[off] = 1;
             }
             break;
           case DT_F32:
@@ -680,10 +793,11 @@ void* fastenc_create(const char* schema_json, int64_t len) {
     for (size_t i = 0; i < caps.size() && i < 2; i++)
       info.caps[i] = (int32_t)caps[i]->num;
     info.elsize = (int32_t)a->obj.at("elsize")->num;
-    auto rs = a->obj.find("row_stride");
-    info.row_stride = rs != a->obj.end() ? (int64_t)rs->second->num : 0;
+    info.row_stride = (int64_t)a->obj.at("row_stride")->num;
+    info.offset = (int64_t)a->obj.at("offset")->num;
     schema->arrays.push_back(info);
   }
+  schema->mirror.n_preds = (int32_t)desc->obj.at("n_preds")->num;
   if (!build_node(*desc->obj.at("trie"), schema->root)) return nullptr;
   return schema.release();
 }
@@ -722,44 +836,56 @@ int64_t fastenc_encode(void* handle, const char* json, int64_t len,
   return (int64_t)st.records.size();
 }
 
-// Encode a BATCH of JSON documents directly into batched (leading row axis)
-// buffers — one call per dispatch, rows written in place, so the host never
-// materializes per-request arrays or re-stacks them.
-//   base_buffers — per-array base pointers of the batch arrays (pre-zeroed)
-//   row_status   — per-row result: 0 ok, -1 parse error,
-//                  -(1000+array_id) axis overflow (those rows are re-tried
-//                  host-side on a wider bucket / the oracle)
+// Encode a BATCH of JSON documents directly into the packed batch buffer
+// (codec.PackedLayout) — one call per dispatch, rows written in place, so
+// the host never materializes per-request arrays or re-stacks them.
+//   base       — the packed buffer (pre-zeroed); array i's column block of
+//                row r starts at base + r * row_stride + offset, both from
+//                the schema description
+//   use_mirror — non-zero: strings the mirror knows are written as ids and
+//                predicate bits here and leave no record; zero: every
+//                string leaf is a record (a caller whose intern table is
+//                not the one the mirror was published from)
+//   row_status — per-row result: 0 ok, -1 parse error,
+//                -(1000+array_id) axis overflow (those rows are re-tried
+//                host-side on a wider bucket / the oracle)
 //   records gain ABSOLUTE flat offsets (row * prod(caps) + local).
 // Returns number of string records, or -2 on arena/records overflow.
 int64_t fastenc_encode_batch(void* handle, const char** jsons,
                              const int64_t* lens, int64_t n_rows,
-                             uint8_t** base_buffers, uint8_t* arena,
-                             int64_t arena_cap, int32_t* records,
-                             int64_t records_cap, int32_t* row_status) {
+                             uint8_t* base, int32_t use_mirror,
+                             uint8_t* arena, int64_t arena_cap,
+                             int32_t* records, int64_t records_cap,
+                             int32_t* row_status) {
   Schema* schema = (Schema*)handle;
   size_t n_arrays = schema->arrays.size();
-  std::vector<int64_t> stride_elems(n_arrays), block_bytes(n_arrays),
-      row_stride_bytes(n_arrays);
+  std::vector<int64_t> stride_elems(n_arrays), block_bytes(n_arrays);
   for (size_t i = 0; i < n_arrays; i++) {
     const ArrayInfo& a = schema->arrays[i];
     int64_t elems = 1;
     for (int d = 0; d < a.ndim; d++) elems *= a.caps[d];
     stride_elems[i] = elems;
     block_bytes[i] = elems * a.elsize;
-    row_stride_bytes[i] = a.row_stride ? a.row_stride : block_bytes[i];
   }
   std::vector<uint8_t*> row_buffers(n_arrays);
   std::string arena_acc;
   std::vector<StringRecord> records_acc;
-  // Batch-level string dedup: request corpora repeat names/images/keys
-  // heavily, and the Python-side interning pass is O(#unique) after this.
+  // Batch-level string dedup: what the mirror has not seen still repeats
+  // within a batch, and the Python-side interning pass is O(#unique).
   std::unordered_map<std::string, int32_t> interned;
+  EncodeState st;
+  st.schema = schema;
+  st.mirror = use_mirror ? &schema->mirror : nullptr;
+  st.buffers = row_buffers.data();
   for (int64_t row = 0; row < n_rows; row++) {
-    for (size_t i = 0; i < n_arrays; i++)
-      row_buffers[i] = base_buffers[i] + row * row_stride_bytes[i];
-    EncodeState st;
-    st.schema = schema;
-    st.buffers = row_buffers.data();
+    for (size_t i = 0; i < n_arrays; i++) {
+      const ArrayInfo& a = schema->arrays[i];
+      row_buffers[i] = base + row * a.row_stride + a.offset;
+    }
+    st.arena.clear();
+    st.records.clear();
+    st.error_array = -1;
+    st.unencodable = false;
     Parser ps(jsons[row], (size_t)lens[row]);
     int32_t coords[4] = {0, 0, 0, 0};
     bool ok = walk(st, ps, schema->root, coords, 0);
@@ -799,6 +925,27 @@ int64_t fastenc_encode_batch(void* handle, const char** jsons,
     memcpy(records, records_acc.data(),
            records_acc.size() * sizeof(StringRecord));
   return (int64_t)records_acc.size();
+}
+
+// Publish n strings Python has interned: string i is the len[i] bytes at
+// arena + offs[i], its table id ids[i], and bits + i * n_preds holds one
+// byte for each of this encoder's predicates (the description's n_preds).
+// A string already there is left as it is (its id and bits never change).
+// Returns 1 once a cap has refused an entry — nothing more will be
+// published, misses keep the record path — else 0.
+int32_t fastenc_learn(void* handle, const char* arena, const int32_t* offs,
+                      const int32_t* lens, const int32_t* ids,
+                      const uint8_t* bits, int64_t n) {
+  Mirror& m = ((Schema*)handle)->mirror;
+  std::lock_guard<std::mutex> hold(m.publish_mu);
+  for (int64_t i = 0; i < n; i++)
+    m.publish(arena + offs[i], (size_t)lens[i], ids[i],
+              bits + i * (int64_t)m.n_preds);
+  return m.full ? 1 : 0;
+}
+
+int64_t fastenc_mirror_entries(void* handle) {
+  return ((Schema*)handle)->mirror.entries.load(std::memory_order_relaxed);
 }
 
 }  // extern "C"

@@ -476,10 +476,10 @@ def _flag_specs() -> list[tuple[str, str | None, dict[str, Any]]]:
                    "(make phase-report). 'off' disables the recorder "
                    "and the timeline endpoint")),
         ("--recorder-ring-events", "KUBEWARDEN_RECORDER_RING_EVENTS",
-         dict(type=int, default=65536, metavar="N",
+         dict(type=int, default=262144, metavar="N",
               help="Flight-recorder ring capacity in events (rounded up "
-                   "to a power of two; ~10 batch events per dispatched "
-                   "batch, so the default holds the last ~6.5k batches; "
+                   "to a power of two; ~17 batch events per dispatched "
+                   "batch, so the default holds the last ~12k batches; "
                    "older events are overwritten, never blocked on)")),
         ("--recorder-row-sample-rate", "KUBEWARDEN_RECORDER_ROW_SAMPLE_RATE",
          dict(type=float, default=0.01, metavar="FRACTION",

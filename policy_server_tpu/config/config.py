@@ -332,9 +332,9 @@ class Config:
     # exports, empty)
     flight_recorder: bool = True
     # preallocated phase-event ring capacity (rounded up to a power of
-    # two); at ~10 batch events per batch, the default holds the last
-    # ~6.5k batches
-    recorder_ring_events: int = 65536
+    # two); at ~17 batch events per batch plus the native frontend's
+    # burst events, the default holds the last ~12k batches
+    recorder_ring_events: int = 262144
     # fraction of delivered rows that record per-row timeline segments
     # (deterministic 1-in-round(1/rate) stride, no RNG on the serving
     # path); 0 disables row sampling (batch events and exemplars remain)

@@ -752,7 +752,7 @@ class EvaluationEnvironment:
             from policy_server_tpu.ops import fastenc
 
             for schema in self.schemas:
-                fastenc.attach_native(schema)
+                fastenc.attach_native(schema, self.table)
         if self.optimization is not None:
             from policy_server_tpu.ops.compiler import compile_constant
 
@@ -900,9 +900,12 @@ class EvaluationEnvironment:
         # nanosecond totals + row counts; bench/metrics divide.
         self._profile_lock = threading.Lock()
         self._host_profile: dict[str, int] = {  # guarded-by: _profile_lock
-            "encode_ns": 0,          # _payload_blob + native encode_batch
+            "encode_ns": 0,          # native encode_batch (_encode_chunk)
             "encode_cpu_ns": 0,      # the encoding thread's CPU time of it
             "encode_rows": 0,        # rows that went through the encoder
+            # string leaves of those rows that the encoder's mirror of the
+            # intern table had not seen, resolved in Python (fastenc.py)
+            "encode_python_strings": 0,
             "bookkeeping_ns": 0,     # dedup tiers + slot/LRU bookkeeping
             "bookkeeping_rows": 0,
             "dispatch_wait_ns": 0,   # blocked in device_get at materialize
@@ -1481,7 +1484,7 @@ class EvaluationEnvironment:
             return None
         try:
             for schema in self.schemas:
-                features, status = schema.native.encode_batch(
+                features, status, _ = schema.native.encode_batch(
                     [blob], 1, self.table
                 )
                 if status[0] == 0:
@@ -1529,9 +1532,15 @@ class EvaluationEnvironment:
     def host_profile(self) -> dict[str, int]:
         """Host-pipeline decomposition counters (ns totals + row counts)
         for the native dispatch path: encode / dedup-bookkeeping /
-        dispatch-wait. Bench and /metrics read this."""
+        dispatch-wait, and the strings the native encoders' mirrors hold
+        (a gauge, read here). Bench and /metrics read this."""
         with self._profile_lock:
-            return dict(self._host_profile)
+            profile = dict(self._host_profile)
+        if self.native_encoding:
+            profile["encode_mirror_entries"] = sum(
+                schema.native.mirror_entries for schema in self.schemas
+            )
+        return profile
 
     @property
     def plane_program_compiles(self) -> int:
@@ -3021,13 +3030,14 @@ class EvaluationEnvironment:
         fetch, learn, materialize (_land_chunk). Returns the rows that
         overflowed this schema.
 
-        Pipeline shape: the dispatch thread only encodes (GIL-free C
-        call) and enqueues device executions; every result fetch runs on
-        the drain pool, so its sync latency overlaps other fetches and
-        device work. With ``defer_sink`` set, the last step is appended
-        instead of run, so validate_batch_finish can block on device
-        results on a different thread than the one encoding the next
-        batch (double-buffering)."""
+        Pipeline shape: the dispatch thread only encodes (one C call
+        with the GIL released; Python resolves only strings the encoder's
+        mirror of the intern table has not seen, fastenc.py) and enqueues
+        device executions; every result fetch runs on the drain pool, so
+        its sync latency overlaps other fetches and device work. With
+        ``defer_sink`` set, the last step is appended instead of run, so
+        validate_batch_finish can block on device results on a different
+        thread than the one encoding the next batch (double-buffering)."""
         chunk_size = min(self.bucket_for(len(pending)), self.max_dispatch_batch)
         chunks = [
             pending[c : c + chunk_size]
@@ -3113,20 +3123,20 @@ class EvaluationEnvironment:
         t0 = time.perf_counter_ns()
         c0 = time.thread_time_ns()
         bl = [blobs[i] for i in chunk]
-        out = schema.native.encode_batch(
+        features, status, python_strings = schema.native.encode_batch(
             bl, self.bucket_for(len(bl)), self.table
         )
         c1 = time.thread_time_ns()
         t1 = time.perf_counter_ns()
         self._profile_add(
             encode_ns=t1 - t0, encode_cpu_ns=c1 - c0,
-            encode_rows=len(chunk),
+            encode_rows=len(chunk), encode_python_strings=python_strings,
         )
         if rec is not None:
             rec.record_phase(
                 flightrec.PH_ENCODE, t0, t1, rows=len(chunk), batch=bid,
             )
-        return bl, out
+        return bl, (features, status)
 
     def _plan_chunk(
         self,

@@ -2,10 +2,21 @@
 
 The native encoder is the C++ twin of the codec's extraction trie
 (ops/codec.py): it parses the request's JSON bytes directly (no Python dict
-on the hot path), writes numeric/bool/presence features straight into the
-numpy buffers, and returns the ID/pred strings via an arena that Python
-interns with its memoized tables. The whole encode runs with the GIL
-released, so the batcher can encode on parallel threads.
+on the hot path) and writes the features straight into the packed batch
+buffer, with the GIL released, so the batcher can encode on parallel threads.
+
+Strings (ID and string-predicate columns) resolve in the same native call
+against a MIRROR of the intern table that the encoder handle keeps: string
+bytes -> id and the bit of each of this encoder's predicates. A string the
+mirror has not seen comes back as a record; ``_scatter_strings`` interns
+those in Python (the table stays the only source of ids and bits) and
+``_learn`` publishes what it resolved, so the next batch finds them. A warm
+batch is therefore the output buffer, the blob arrays and one native call:
+no records, no numpy call over them, no Python loop over strings — numpy
+hands the GIL away in any call over a few hundred elements, and in a
+serving process another thread always takes it (PERF.md section 6, PR 34).
+The mirror is bounded (constants in fastenc.cpp); past its cap nothing more
+is published and the record path answers, exactly, as before.
 
 Build model: compiled on demand with g++ into ``build/`` under a name that
 hashes its source and flags (utils/nativebuild.py). A failed build or load
@@ -25,6 +36,7 @@ import numpy as np
 
 from policy_server_tpu.ops.codec import (
     BATCH_KEY,
+    PACKED_KEY,
     FeatureSchema,
     FeatureSpec,
     SchemaOverflow,
@@ -41,6 +53,8 @@ _SRC = REPO_ROOT / "csrc" / "fastenc.cpp"
 
 _KIND = {"value": 0, "present": 1, "pred": 2}
 _DTYPE = {"id": 0, "f32": 1, "bool": 2, "i32": 3}
+
+_I32P = ctypes.POINTER(ctypes.c_int32)
 
 _lib_lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -81,11 +95,20 @@ def _load() -> ctypes.CDLL:
             ctypes.c_void_p,
             ctypes.POINTER(ctypes.c_char_p), ctypes.POINTER(ctypes.c_int64),
             ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_void_p),
+            ctypes.c_void_p, ctypes.c_int32,
             ctypes.c_char_p, ctypes.c_int64,
             ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
             ctypes.POINTER(ctypes.c_int32),
         ]
+        lib.fastenc_learn.restype = ctypes.c_int32
+        lib.fastenc_learn.argtypes = [
+            ctypes.c_void_p, ctypes.c_char_p,
+            ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32),
+            ctypes.POINTER(ctypes.c_int32),
+            ctypes.c_char_p, ctypes.c_int64,
+        ]
+        lib.fastenc_mirror_entries.restype = ctypes.c_int64
+        lib.fastenc_mirror_entries.argtypes = [ctypes.c_void_p]
         _lib = lib
         return _lib
 
@@ -129,16 +152,21 @@ def _describe_schema(schema: FeatureSchema) -> tuple[str, list[FeatureSpec], lis
         return 4 if spec.kind == "value" and spec.dtype is not None and spec.dtype.value in ("id", "f32", "i32") else 1
 
     # Batch mode writes into column blocks of the single packed buffer
-    # (codec.PackedLayout); every array's row stride is the full packed
-    # row width.
+    # (codec.PackedLayout): every array's row stride is the full packed
+    # row width, and its block starts ``offset`` bytes into the row.
     layout = schema.packed_layout()
+    offset = {e.key: e.offset for e in layout.entries8}
+    offset.update(
+        (e.key, layout.off32_bytes + 4 * e.offset) for e in layout.entries32
+    )
     arrays = [
         {"caps": list(s.caps), "elsize": elsize(s),
-         "row_stride": layout.width}
+         "row_stride": layout.width, "offset": offset[s.key]}
         for s in specs
     ]
     arrays += [
-        {"caps": list(s.caps), "elsize": 1, "row_stride": layout.width}
+        {"caps": list(s.caps), "elsize": 1, "row_stride": layout.width,
+         "offset": offset[mask_key_for(s.key)]}
         for s in specs if s.has_mask
     ]
 
@@ -166,18 +194,28 @@ def _describe_schema(schema: FeatureSchema) -> tuple[str, list[FeatureSpec], lis
             "overflow_id": array_id.get(node.repr_key, -1),
         }
 
-    doc = {"arrays": arrays, "trie": node_desc(schema._trie())}
+    doc = {
+        "arrays": arrays,
+        "n_preds": len(pred_keys),
+        "trie": node_desc(schema._trie()),
+    }
     return json.dumps(doc), specs, pred_keys
 
 
 class NativeEncoder:
-    """Per-schema native encoder instance (thread-safe for concurrent
-    encodes — all mutable state is per-call)."""
+    """Per-schema native encoder instance. Thread-safe for concurrent
+    encodes: per-call state is the call's own, and the mirror inside the
+    handle is read without a lock and published to under one only
+    publishers take (csrc/fastenc.cpp).
+
+    ``table`` is the intern table the mirror is published from; an encode
+    against any other table (or with none bound) resolves every string in
+    Python, since an id means nothing outside the table that gave it."""
 
     ARENA_CAP = 1 << 20
     RECORDS_CAP = 1 << 16
 
-    def __init__(self, schema: FeatureSchema):
+    def __init__(self, schema: FeatureSchema, table: InternTable | None = None):
         self._lib = lib = _load()
         desc, self._specs, self._pred_keys = _describe_schema(schema)
         raw = desc.encode()
@@ -188,12 +226,21 @@ class NativeEncoder:
         # optimizer's mask-elided columns)
         self._value_specs = [s for s in self._specs if s.has_mask]
         self._schema = schema
+        self._table = table
+        # cleared once a cap of the mirror refused an entry: what is left
+        # of _learn then is not worth its Python (racing writers agree)
+        self._mirror_open = table is not None
         self._scratch = threading.local()
 
     def __del__(self) -> None:  # pragma: no cover
         lib, handle = getattr(self, "_lib", None), getattr(self, "_handle", None)
         if lib is not None and handle:
             lib.fastenc_destroy(handle)
+
+    @property
+    def mirror_entries(self) -> int:
+        """Strings the mirror holds (bounded: fastenc.cpp Mirror)."""
+        return int(self._lib.fastenc_mirror_entries(self._handle))
 
     def encode_json(
         self, payload_json: bytes, table: InternTable
@@ -253,31 +300,24 @@ class NativeEncoder:
         payload_jsons: list[bytes],
         batch_size: int,
         table: InternTable,
-    ) -> tuple[dict[str, np.ndarray], np.ndarray]:
+    ) -> tuple[dict[str, np.ndarray], np.ndarray, int]:
         """Encode a whole batch in ONE native call, rows written directly
-        into the TWO packed batch buffers (codec.PackedLayout) — a dispatch
-        is O(1) host→device transfers regardless of schema width.
+        into the packed batch buffer (codec.PackedLayout) — a dispatch is
+        O(1) host→device transfers regardless of schema width.
 
-        → ({PACKED32_KEY, PACKED8_KEY} feature dict,
+        → ({PACKED_KEY: buffer} feature dict,
            per-row status: 0 ok, <0 failed — failed rows are all-missing
-           in the buffers and must be re-routed by the caller)."""
+           in the buffer and must be re-routed by the caller,
+           strings the mirror had not seen, resolved in Python: 0 for a
+           warm batch, which then made no numpy call over its strings)."""
         n = len(payload_jsons)
         assert n <= batch_size
         out = self._schema.empty_batch_packed(batch_size)
-        views = self._schema.packed_views(out)
-        n_arrays = len(self._specs) + len(self._value_specs)
-        buffers = (ctypes.c_void_p * n_arrays)()
-        for i, spec in enumerate(self._specs):
-            buffers[i] = views[spec.key].ctypes.data_as(ctypes.c_void_p)
-        mi = len(self._specs)
-        for spec in self._value_specs:
-            buffers[mi] = views[mask_key_for(spec.key)].ctypes.data_as(
-                ctypes.c_void_p
-            )
-            mi += 1
+        buf = out[PACKED_KEY]
+        blob_lens = [len(b) for b in payload_jsons]
         jsons = (ctypes.c_char_p * n)(*payload_jsons)
-        lens = (ctypes.c_int64 * n)(*[len(b) for b in payload_jsons])
-        arena_cap = max(self.ARENA_CAP, sum(len(b) for b in payload_jsons))
+        lens = (ctypes.c_int64 * n)(*blob_lens)
+        arena_cap = max(self.ARENA_CAP, sum(blob_lens))
         records_cap = self.RECORDS_CAP * max(1, (n + 63) // 64)
         # Reusable per-thread scratch: allocating+zeroing tens of MB per
         # dispatch would dominate the very path this encoder accelerates.
@@ -288,24 +328,30 @@ class NativeEncoder:
         records = getattr(scratch, "records", None)
         if records is None or len(records) < records_cap * 6:
             records = scratch.records = (ctypes.c_int32 * (records_cap * 6))()
-        status = (ctypes.c_int32 * n)()
+            scratch.records_ptr = ctypes.cast(records, _I32P)
+        status = np.empty(n, np.int32)
+        mirrored = table is self._table
         n_rec = self._lib.fastenc_encode_batch(
             self._handle, jsons, lens, n,
-            buffers, arena, len(arena),
-            ctypes.cast(records, ctypes.POINTER(ctypes.c_int32)),
-            len(records) // 6,
-            status,
+            buf.ctypes.data, mirrored,
+            arena, len(arena),
+            scratch.records_ptr, len(records) // 6,
+            status.ctypes.data_as(_I32P),
         )
         if n_rec == -2:
             raise ValueError("fastenc: arena/records overflow")
         if n_rec:
-            self._scatter_strings(
+            # the cold path: strings the mirror has not seen (all of them
+            # under a table it is not bound to)
+            learned = self._scatter_strings(
                 np.frombuffer(
                     records, dtype=np.int32, count=int(n_rec) * 6
                 ).reshape(-1, 6),
-                arena, views, table,
+                arena, self._schema.packed_views(out), table,
             )
-        return out, np.frombuffer(status, dtype=np.int32).copy()
+            if mirrored and self._mirror_open:
+                self._learn(arena, learned, table)
+        return out, status, int(n_rec)
 
     def _scatter_strings(
         self,
@@ -313,10 +359,13 @@ class NativeEncoder:
         arena,
         views: dict[str, np.ndarray],
         table: InternTable,
-    ) -> None:
-        """Vectorized interning: the native encoder dedups strings at the
-        batch level, so Python work is O(#unique strings) + a handful of
-        numpy scatters — not a Python loop over every record."""
+    ) -> dict[int, tuple[int, int]]:
+        """Intern the strings of ``rec`` and scatter their ids and
+        predicate bits into ``views``. Vectorized: the native encoder
+        dedups strings at the batch level, so Python work is O(#unique
+        strings) + a handful of numpy scatters — not a Python loop over
+        every record. → arena offset of each string it resolved →
+        (its length, its id), what ``_learn`` publishes."""
         specs = self._specs
         pred_keys = self._pred_keys
         used = int((rec[:, 4] + rec[:, 5]).max())
@@ -333,13 +382,19 @@ class NativeEncoder:
             keys, return_index=True, return_inverse=True
         )
         vals = np.empty(len(uniq), np.int32)
+        learned: dict[int, tuple[int, int]] = {}
         for u, ri in enumerate(first):
-            is_pred, pred_idx, soff, slen = rec[ri, 2:6]
-            s = raw_arena[soff : soff + slen].decode("utf-8", "surrogatepass")
+            is_pred, pred_idx, soff, slen = rec[ri, 2:6].tolist()
+            known = learned.get(soff)
+            if known is None:
+                s = raw_arena[soff : soff + slen].decode(
+                    "utf-8", "surrogatepass"
+                )
+                known = learned[soff] = (slen, table.intern(s))
             vals[u] = (
-                table.pred_value(pred_keys[pred_idx], s)
+                table.pred_bit(pred_keys[pred_idx], known[1])
                 if is_pred
-                else table.intern(s)
+                else known[1]
             )
         rvals = vals[inverse]
         aids = rec[:, 0]
@@ -347,12 +402,36 @@ class NativeEncoder:
             m = aids == aid
             arr = views[specs[aid].key]
             arr.flat[rec[m, 1]] = rvals[m].astype(arr.dtype, copy=False)
+        return learned
+
+    def _learn(
+        self, arena, learned: dict[int, tuple[int, int]], table: InternTable
+    ) -> None:
+        """Publish what ``_scatter_strings`` resolved to the mirror: each
+        string's id, and its bit under EVERY predicate of this encoder
+        (so a string first met under one predicate is right under the
+        next). Runs after ``table.intern`` returned for each of them, so
+        the table's publish-last rule holds for the mirror too."""
+        n = len(learned)
+        offs = np.fromiter(learned, np.int32, n)
+        lens = np.fromiter((v[0] for v in learned.values()), np.int32, n)
+        ids = np.fromiter((v[1] for v in learned.values()), np.int32, n)
+        bits = bytes(
+            table.pred_bit(pk, i) for i in ids.tolist()
+            for pk in self._pred_keys
+        )
+        if self._lib.fastenc_learn(
+            self._handle, arena, offs.ctypes.data_as(_I32P),
+            lens.ctypes.data_as(_I32P), ids.ctypes.data_as(_I32P), bits, n,
+        ):
+            self._mirror_open = False
 
 
-def attach_native(schema: FeatureSchema) -> None:
-    """Give a FeatureSchema a native encoder (used by the evaluation
-    environment at boot). Raises NativeBuildError when the library cannot
-    be built or loaded: the jax backend asks for the native encoder, and
-    a server that silently encodes in Python instead is a different,
-    slower server."""
-    schema.native = NativeEncoder(schema)
+def attach_native(schema: FeatureSchema, table: InternTable) -> None:
+    """Give a FeatureSchema a native encoder whose mirror is published from
+    ``table`` (used by the evaluation environment at boot, after
+    ``schema.register_preds(table)``). Raises NativeBuildError when the
+    library cannot be built or loaded: the jax backend asks for the native
+    encoder, and a server that silently encodes in Python instead is a
+    different, slower server."""
+    schema.native = NativeEncoder(schema, table)

@@ -924,6 +924,19 @@ class PolicyServer:
                 profile.get("encode_rows", 0),
             )
             yield (
+                metrics_names.HOST_ENCODE_PYTHON_STRINGS, "counter",
+                "String leaves of encoded rows that the native encoder's "
+                "mirror of the intern table had not seen, resolved in "
+                "Python (per encoded row: ~0 once warm)",
+                profile.get("encode_python_strings", 0),
+            )
+            yield (
+                metrics_names.HOST_ENCODE_MIRROR_ENTRIES, "gauge",
+                "Strings the native encoders' mirrors of the intern table "
+                "hold (bounded; past the bound misses stay in Python)",
+                profile.get("encode_mirror_entries", 0),
+            )
+            yield (
                 metrics_names.HOST_BOOKKEEPING_SECONDS, "counter",
                 "Host time in dedup tiers + slot/LRU bookkeeping",
                 profile.get("bookkeeping_ns", 0) / 1e9,

@@ -144,7 +144,10 @@ _KIND_MIX = 2
 
 _KIND_NAMES = ("batch", "row", "mix")
 
-DEFAULT_RING_EVENTS = 65536
+# ~17 batch events a dispatched batch plus the native frontend's burst
+# events: ~8,600 events a second at 350 small batches a second (PERF.md
+# section 6, PR 34), so this holds the last ~30 s there; 31 bytes an event
+DEFAULT_RING_EVENTS = 262144
 DEFAULT_ROW_SAMPLE_RATE = 0.01
 EXEMPLAR_SLOTS = 8
 EXEMPLAR_WINDOW_SECONDS = 30.0

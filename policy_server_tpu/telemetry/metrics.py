@@ -118,6 +118,11 @@ HOST_ENCODE_ROWS = "policy_server_host_encode_rows_total"
 # HOST_ENCODE_SECONDS (wall): the difference is time that thread was off
 # a core, waiting for the GIL or descheduled
 HOST_ENCODE_CPU_SECONDS = "policy_server_host_encode_cpu_seconds_total"
+# the native encoder's mirror of the intern table (csrc/fastenc.cpp, PR
+# 34): string leaves it had not seen, which Python resolved (per row
+# against HOST_ENCODE_ROWS: ~0 once warm), and the strings it holds
+HOST_ENCODE_PYTHON_STRINGS = "policy_server_host_encode_python_strings_total"
+HOST_ENCODE_MIRROR_ENTRIES = "policy_server_host_encode_mirror_entries"
 HOST_BOOKKEEPING_SECONDS = "policy_server_host_bookkeeping_seconds_total"
 DISPATCH_WAIT_SECONDS = "policy_server_dispatch_wait_seconds_total"
 DISPATCHED_ROWS = "policy_server_dispatched_rows_total"

@@ -412,11 +412,33 @@ def test_the_manifest_with_the_deployment_is_sound():
         assert CONFIG[key] == accepted[key]
 
 
-def test_the_manifest_with_the_bytes_per_put_metric_is_sound():
-    """PR 32 adds one per-layer entry, last in its list, and one data
-    file; nothing else of the benchmark moves."""
+def _per_layer(name: str) -> dict:
+    return next(m for m in MANIFEST["per_layer"] if m["name"] == name)
+
+
+def test_the_manifest_with_the_python_strings_metric_is_sound():
+    """PR 34 adds one per-layer entry, last in its list, and one data
+    file: strings a row that the native encoder's mirror of the intern
+    table had not seen, in every cell, by the program's own counters."""
     assert check_manifest.problems(MANIFEST, ROOT) == []
+    encode = _per_layer("encode_us_per_row")
     assert MANIFEST["per_layer"][-1] == {
+        "name": "encode_python_strings_per_row", "unit": "strings/row",
+        "better": "lower", "source": "program_counter",
+        "layer": encode["layer"], "moves": "reviews_per_s",
+        "workloads": [w["name"] for w in MANIFEST["workloads"]]}
+    spec = json.loads((BENCH / "layer_metrics"
+                       / "encode_python_strings_per_row.json").read_text())
+    assert spec["reader"] == "counter_ratio" and "module" not in spec
+    assert (spec["numerator"], spec["denominator"]) == (
+        metrics_mod.HOST_ENCODE_PYTHON_STRINGS, metrics_mod.HOST_ENCODE_ROWS)
+
+
+def test_the_manifest_with_the_bytes_per_put_metric_is_sound():
+    """PR 32 adds one per-layer entry and one data file; nothing else of
+    the benchmark moves."""
+    assert check_manifest.problems(MANIFEST, ROOT) == []
+    assert _per_layer("cache_bytes_per_put") == {
         "name": "cache_bytes_per_put", "unit": "bytes", "better": "lower",
         "source": "program_counter",
         "layer": "dedup tiers in front of the device (evaluation/"

@@ -1,9 +1,16 @@
 """Native encoder differential tests: the C++ encoder (csrc/fastenc.cpp)
 must be bit-exact vs the Python trie encoder on every feature array, across
 the synthetic firehose, unicode/escape torture, overflow routing, and the
-batch API. Skipped when no C++ toolchain is available."""
+batch API — and whether its mirror of the intern table answered a string
+or Python did (cold, warm, mixed, concurrent, at its cap). Skipped when no
+C++ toolchain is available."""
 
 from __future__ import annotations
+
+import copy
+import ctypes
+import sys
+import threading
 
 import numpy as np
 import pytest
@@ -16,6 +23,7 @@ from policy_server_tpu.evaluation.environment import EvaluationEnvironmentBuilde
 from policy_server_tpu.models import AdmissionReviewRequest, ValidateRequest
 from policy_server_tpu.models.policy import parse_policy_entry
 from policy_server_tpu.ops import fastenc
+from policy_server_tpu.ops.codec import PACKED_KEY
 from policy_server_tpu.policies.flagship import flagship_policies, synthetic_firehose
 
 pytestmark = pytest.mark.skipif(
@@ -78,7 +86,7 @@ def test_batch_api_matches_single(env):
     schema = env.schemas[0]
     docs = synthetic_firehose(17, seed=5)
     blobs = [to_request(d).payload_json() for d in docs]
-    packed, status = schema.native.encode_batch(blobs, 32, env.table)
+    packed, status, _ = schema.native.encode_batch(blobs, 32, env.table)
     assert (status == 0).all()
     batch = schema.unpack_host(packed)
     for row, d in enumerate(docs):
@@ -95,7 +103,7 @@ def test_batch_overflow_rows_flagged_and_zeroed(env):
         {"name": f"c{i}", "image": "nginx"} for i in range(12)  # > cap 8
     ]
     blobs = [to_request(ok_doc).payload_json(), to_request(big_doc).payload_json()]
-    packed, status = schema.native.encode_batch(blobs, 2, env.table)
+    packed, status, _ = schema.native.encode_batch(blobs, 2, env.table)
     assert status[0] == 0 and status[1] < 0
     # the failed row must read all-missing
     for k, arr in schema.unpack_host(packed).items():
@@ -164,7 +172,7 @@ def test_out_of_range_int_routes_to_oracle(tmp_path):
     with pytest.raises(SchemaOverflow):
         jax_env.schemas[-1].encode(req.payload(), jax_env.table)
     # native batch flags the row
-    _, status = jax_env.schemas[-1].native.encode_batch(
+    _, status, _ = jax_env.schemas[-1].native.encode_batch(
         [req.payload_json()], 1, jax_env.table
     )
     assert status[0] != 0
@@ -173,3 +181,267 @@ def test_out_of_range_int_routes_to_oracle(tmp_path):
     resp = jax_env.validate_batch([("replica-cap", req)])[0]
     assert not resp.allowed and resp.status.message == "too many replicas"
     assert jax_env.oracle_fallbacks == before + 1
+
+
+# ---------------------------------------------------------------------------
+# The mirror of the intern table (PR 34): a string the encoder handle has
+# seen is written as its id and predicate bits by the native call itself.
+# ---------------------------------------------------------------------------
+
+
+def _torture_doc(seed: int) -> dict:
+    """A review whose string columns carry escapes and non-ASCII, in id
+    columns (namespace, label and annotation keys and values,
+    capabilities, procMount) and predicate columns (images, hostPath
+    paths, the apparmor annotation prefix)."""
+    doc = synthetic_firehose(1, seed=seed)[0]
+    pod = doc["request"]["object"]
+    doc["request"]["namespace"] = pod["metadata"]["namespace"] = "équipe-\"a\""
+    pod["metadata"]["labels"] = {
+        "app": "café-☃️",
+        'quote"key': "line1\nline2\tend \U0001f600",
+        "backslash\\key": "nul ctrl",
+    }
+    pod["metadata"]["annotations"] = {
+        "container.apparmor.security.beta.kubernetes.io/c": "unconfined",
+        "prod.example.com/dépôt": "true",
+    }
+    pod["spec"]["volumes"] = [
+        {"name": "v0", "hostPath": {"path": "/var/log/\u00e9\t"}},
+        {"name": "v1", "hostPath": {"path": "/tmp/x"}},
+    ]
+    pod["spec"]["containers"][0]["image"] = "docker.io/library/ngïnx:latest"
+    pod["spec"]["containers"][0]["securityContext"] = {
+        "procMount": "Unmasked",
+        "capabilities": {"add": ["NET_ADMIN", "SYS_\u2603"]},
+    }
+    return doc
+
+
+def _docs(n: int, seed: int) -> list[dict]:
+    return synthetic_firehose(n, seed=seed) + [_torture_doc(seed)]
+
+
+def _renamed(docs: list[dict], namespace: str, image: str) -> list[dict]:
+    """The same reviews under a namespace and a first image the encoder
+    has not met."""
+    out = copy.deepcopy(docs)
+    for doc in out:
+        doc["request"]["namespace"] = namespace
+        doc["request"]["object"]["metadata"]["namespace"] = namespace
+        doc["request"]["object"]["spec"]["containers"][0]["image"] = image
+    return out
+
+
+def _blobs(docs: list[dict]) -> list[bytes]:
+    return [to_request(d).payload_json() for d in docs]
+
+
+def _trie_packed(schema, table, docs: list[dict], batch: int) -> np.ndarray:
+    """The batch as the Python trie encodes it (the differential
+    reference), in the packed layout."""
+    encoded = [schema.encode(to_request(d).payload(), table) for d in docs]
+    return schema.pack(schema.stack(encoded, batch_size=batch))[PACKED_KEY]
+
+
+def _assert_exact(schema, table, enc, parent, docs: list[dict]) -> int:
+    """``enc``'s packed buffer for ``docs`` is byte for byte the Python
+    trie's and the parent path's (every string through _scatter_strings:
+    an encoder with no table bound). → strings Python resolved."""
+    batch = len(docs) + 3  # pad rows stay all-missing
+    blobs = _blobs(docs)
+    got, status, python_strings = enc.encode_batch(blobs, batch, table)
+    assert not status.any()
+    old, old_status, every_string = parent.encode_batch(blobs, batch, table)
+    assert not old_status.any()
+    assert python_strings <= every_string
+    assert got[PACKED_KEY].tobytes() == old[PACKED_KEY].tobytes()
+    assert (
+        got[PACKED_KEY].tobytes()
+        == _trie_packed(schema, table, docs, batch).tobytes()
+    )
+    return python_strings
+
+
+@pytest.mark.parametrize("which", [0, -1], ids=["narrow", "wide"])
+@pytest.mark.parametrize("state", ["cold", "warm", "mixed"])
+def test_differential_against_trie_and_parent_path(env, which, state):
+    schema = env.schemas[which]
+    enc = fastenc.NativeEncoder(schema, env.table)  # an empty mirror
+    parent = fastenc.NativeEncoder(schema)  # no mirror: the parent's path
+    docs = _docs(12, seed=21)
+    every_string = parent.encode_batch(_blobs(docs), 16, env.table)[2]
+    assert every_string > 0 and enc.mirror_entries == 0
+    # cold: the mirror is empty, every string leaf is a record
+    assert _assert_exact(schema, env.table, enc, parent, docs) == every_string
+    assert 0 < enc.mirror_entries < every_string  # strings repeat
+    if state == "cold":
+        return
+    # warm: the same strings again come back with no record at all
+    assert _assert_exact(schema, env.table, enc, parent, docs) == 0
+    if state == "warm":
+        return
+    # mixed: a namespace and an image the mirror has not seen, mid-run
+    held = enc.mirror_entries
+    new = _renamed(docs, "ns-nouveau-\u00e9", "quay.io/new/image:latest")
+    met = _assert_exact(schema, env.table, enc, parent, new)
+    assert 0 < met < every_string
+    assert enc.mirror_entries == held + 2
+    assert _assert_exact(schema, env.table, enc, parent, new) == 0
+
+
+def test_warm_batch_makes_no_python_pass_over_strings(env, monkeypatch):
+    """Once every string is in the mirror an encode is the buffer, the
+    blob arrays and one native call: neither _scatter_strings nor the
+    per-array views exist on that path."""
+    schema = env.schemas[0]
+    enc = fastenc.NativeEncoder(schema, env.table)
+    blobs = _blobs(_docs(8, seed=22))
+    cold, _, met = enc.encode_batch(blobs, 16, env.table)
+    assert met > 0
+
+    def never(*args, **kwargs):
+        raise AssertionError("a warm batch took the cold path")
+
+    monkeypatch.setattr(enc, "_scatter_strings", never)
+    monkeypatch.setattr(enc, "_learn", never)
+    monkeypatch.setattr(schema, "packed_views", never)
+    warm, status, met = enc.encode_batch(blobs, 16, env.table)
+    assert met == 0 and not status.any()
+    assert warm[PACKED_KEY].tobytes() == cold[PACKED_KEY].tobytes()
+
+
+def test_a_table_the_mirror_is_not_bound_to_takes_the_cold_path(env):
+    """An id means nothing outside the table that gave it: under another
+    table every string is Python's, and nothing is published."""
+    from policy_server_tpu.utils.interning import InternTable
+
+    schema = env.schemas[0]
+    enc = fastenc.NativeEncoder(schema, env.table)
+    docs = _docs(4, seed=23)
+    blobs = _blobs(docs)
+    met = enc.encode_batch(blobs, 8, env.table)[2]
+    held = enc.mirror_entries
+    other = InternTable()
+    other.intern("shifts every id by one")
+    schema.register_preds(other)
+    got, status, met_other = enc.encode_batch(blobs, 8, other)
+    assert met_other == met and enc.mirror_entries == held
+    assert not status.any()
+    assert (
+        got[PACKED_KEY].tobytes()
+        == _trie_packed(schema, other, docs, 8).tobytes()
+    )
+
+
+def test_eight_threads_encode_while_strings_are_published(env):
+    """Readers of the mirror take no lock and publishers take their own:
+    eight threads on one handle, every batch bringing strings nobody has
+    seen, every buffer exact."""
+    schema = env.schemas[0]
+    enc = fastenc.NativeEncoder(schema, env.table)
+    parent = fastenc.NativeEncoder(schema)
+    base = _docs(6, seed=24)
+    rounds = 12
+    work = [
+        [
+            _blobs(_renamed(base, f"ns-{t % 4}-{r}", f"r.example/{t}/{r}:v1"))
+            for r in range(rounds)
+        ]
+        for t in range(8)
+    ]
+    failures: list[str] = []
+    start = threading.Barrier(8)
+
+    def run(t: int) -> None:
+        try:
+            start.wait(timeout=60)
+            for r, blobs in enumerate(work[t]):
+                # twice: the second pass reads what was just published
+                for _ in range(2):
+                    got, status, _ = enc.encode_batch(blobs, 8, env.table)
+                    want, _, _ = parent.encode_batch(blobs, 8, env.table)
+                    if status.any() or (
+                        got[PACKED_KEY].tobytes() != want[PACKED_KEY].tobytes()
+                    ):
+                        failures.append(f"thread {t} round {r}")
+        except Exception as e:  # a thread's failure must reach the test
+            failures.append(f"thread {t}: {e!r}")
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=run, args=(t,)) for t in range(8)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(th.is_alive() for th in threads)
+    assert failures == []
+    # threads t and t + 4 shared a namespace each round: published once
+    assert enc.mirror_entries >= 8 * rounds + 4 * rounds
+    for blobs in (work[0][0], work[7][rounds - 1]):
+        assert enc.encode_batch(blobs, 8, env.table)[2] == 0
+
+
+def test_mirror_at_its_cap_publishes_nothing_more_and_stays_exact():
+    """The Python table is unbounded; the mirror is not. Past its cap a
+    string it has not seen is Python's every time, exactly."""
+    small = EvaluationEnvironmentBuilder(backend="jax").build(
+        {
+            "priv": parse_policy_entry(
+                "priv", {"module": "builtin://pod-privileged"}
+            ),
+            "latest": parse_policy_entry(
+                "latest", {"module": "builtin://disallow-latest-tag"}
+            ),
+        }
+    )
+    try:
+        schema, table = small.schemas[0], small.table
+        enc = schema.native
+        parent = fastenc.NativeEncoder(schema)
+        offered = 40_000
+        names = [b"filler-%07d" % i for i in range(offered)]
+        arena = ctypes.create_string_buffer(b"".join(names))
+        learned = {
+            14 * i: (14, table.intern(name.decode()))
+            for i, name in enumerate(names)
+        }
+        enc._learn(arena, learned, table)
+        cap = enc.mirror_entries
+        assert 0 < cap < offered and not enc._mirror_open
+        docs = _renamed(_docs(4, seed=25), "ns-past-the-cap", "r.io/x:latest")
+        first = _assert_exact(schema, table, enc, parent, docs)
+        assert first > 0 and enc.mirror_entries == cap
+        # not published: Python's again, and as exact
+        assert _assert_exact(schema, table, enc, parent, docs) == first
+        # what went in before the cap still answers from the mirror
+        warm = _renamed(docs, "filler-0000007", "filler-0000008")
+        assert _assert_exact(schema, table, enc, parent, warm) < first
+    finally:
+        small.close()
+
+
+def test_string_met_under_one_predicate_then_another(env):
+    """A string is published with its bit under every predicate of the
+    encoder, so one first met as a hostPath path (the prefix predicates)
+    is right when it later arrives as an image (suffix, regex, globs) and
+    as an annotation key (an id column and a prefix predicate)."""
+    schema = env.schemas[-1]
+    enc = fastenc.NativeEncoder(schema, env.table)
+    parent = fastenc.NativeEncoder(schema)
+    word = "/tmp/registry.prod.example.com/x:latest"
+    as_path = _torture_doc(26)
+    as_path["request"]["object"]["spec"]["volumes"] = [
+        {"name": "v", "hostPath": {"path": word}}
+    ]
+    assert _assert_exact(schema, env.table, enc, parent, [as_path]) > 0
+    elsewhere = copy.deepcopy(as_path)
+    pod = elsewhere["request"]["object"]
+    pod["spec"]["containers"][0]["image"] = word
+    pod["spec"]["initContainers"] = [{"name": "i", "image": word}]
+    pod["metadata"]["annotations"] = {word: word}
+    assert _assert_exact(schema, env.table, enc, parent, [elsewhere]) == 0

@@ -49,6 +49,11 @@ def server():
             "pod-privileged": parse_policy_entry(
                 "pod-privileged", {"module": "builtin://pod-privileged"}
             ),
+            # gives the schema a string column (the image, under its
+            # predicates): what the encoder's mirror is about
+            "latest-tag": parse_policy_entry(
+                "latest-tag", {"module": "builtin://disallow-latest-tag"}
+            ),
         },
         policy_timeout_seconds=30.0,
         max_batch_size=8,
@@ -56,6 +61,9 @@ def server():
         # 0 forces the DEVICE path so the encode/dedup/dispatch counters
         # all move (the host fast-path would bypass the native pipeline)
         host_fastpath_threshold=0,
+        # and no budget routing: on a loaded machine the device's round
+        # trip outruns the 50 ms budget and the batcher answers host-side
+        latency_budget_ms=0,
         warmup_at_boot=True,
     )
     handle = ServerHandle(config)
@@ -193,6 +201,47 @@ def test_launch_h2d_arrays_on_the_pull_endpoint(server):
     assert (
         m[metrics_mod.LAUNCH_H2D_ARRAYS + "_total"]
         == profile["launch_h2d_arrays"]
+    )
+
+
+def test_encode_python_strings_on_the_pull_endpoint(server):
+    """The native encoder's mirror of the intern table, counted where the
+    benchmark reads it: a string it has not seen is Python's once (the
+    counter moves, the mirror grows), and the same string in a later
+    request is the native call's (rows move, the counter does not)."""
+    env = server.server.environment
+
+    def post(uid: str, image: str) -> dict:
+        doc = json.loads(_review_body(uid, False))
+        doc["request"]["object"]["spec"]["containers"][0]["image"] = image
+        r = requests.post(
+            server.url("/validate/pod-privileged"), data=json.dumps(doc),
+            headers={"Content-Type": "application/json"}, timeout=30,
+        )
+        assert r.status_code == 200
+        time.sleep(0.1)
+        return env.host_profile
+
+    before = env.host_profile
+    first = post("u-mirror-1", "registry.example/never-seen:1")
+    again = post("u-mirror-2", "registry.example/never-seen:1")
+    assert first["encode_rows"] > before["encode_rows"]
+    assert again["encode_rows"] > first["encode_rows"]
+    # met once, resolved in Python once, published once
+    assert first["encode_python_strings"] > before["encode_python_strings"]
+    assert (
+        first["encode_mirror_entries"] == before["encode_mirror_entries"] + 1
+    )
+    assert again["encode_python_strings"] == first["encode_python_strings"]
+    assert again["encode_mirror_entries"] == first["encode_mirror_entries"]
+    m = _scrape(server)
+    assert (
+        m[metrics_mod.HOST_ENCODE_PYTHON_STRINGS]
+        == again["encode_python_strings"]
+    )
+    assert (
+        m[metrics_mod.HOST_ENCODE_MIRROR_ENTRIES]
+        == again["encode_mirror_entries"]
     )
 
 

@@ -311,7 +311,7 @@ def _fetched(env, requests: list[ValidateRequest]) -> np.ndarray:
     """The array the device returns for these requests, one row each."""
     schema = env.schemas[0]
     blobs = [r.payload_json() for r in requests]
-    features, status = schema.native.encode_batch(
+    features, status, _ = schema.native.encode_batch(
         blobs, env.bucket_for(len(blobs)), env.table
     )
     assert not np.asarray(status)[: len(blobs)].any()
