@@ -156,7 +156,11 @@ def _flag_specs() -> list[tuple[str, str | None, dict[str, Any]]]:
          dict(type=int, default=64, metavar="N",
               help="Micro-batches with at most N requests are answered by "
                    "the bit-exact host oracle instead of a device dispatch "
-                   "(latency fast-path; 0 disables)")),
+                   "(latency fast-path; 0 disables). The verdict cache is "
+                   "asked first there too, and every answer is counted by "
+                   "one source: a cache hit by the cache's own hit counter, "
+                   "a request the oracle evaluated by "
+                   "policy_server_host_fastpath_requests")),
         ("--latency-budget-ms", "KUBEWARDEN_LATENCY_BUDGET_MS",
          dict(type=float, default=50.0, metavar="MS",
               help="Soft per-request latency target for deadline-aware "
@@ -164,7 +168,9 @@ def _flag_specs() -> list[tuple[str, str | None, dict[str, Any]]]:
                    "would exceed the oldest queued request's remaining "
                    "budget, the batch is answered by the bit-exact host "
                    "oracle instead (0 disables; distinct from "
-                   "--policy-timeout, the hard in-band deadline)")),
+                   "--policy-timeout, the hard in-band deadline). Such a "
+                   "batch counts in policy_server_budget_routed_batches, "
+                   "its requests as the host fast path's are counted")),
         ("--columnar", "KUBEWARDEN_COLUMNAR",
          dict(default="on", metavar="MODE", choices=["on", "off"],
               help="Columnar device transport (round 12): ship encoded "

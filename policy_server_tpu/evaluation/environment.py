@@ -2805,9 +2805,21 @@ class EvaluationEnvironment:
         transfer, no device round-trip. The reference's per-request sync
         path (src/api/handlers.rs:256-286) answers one request in ~1 ms on
         CPU; this is the build's equivalent for batches too small to
-        amortize the device dispatch."""
+        amortize the device dispatch.
+
+        One answer, one source: a request a dedup tier answers here is
+        counted by that tier's own hit counter (``VerdictCache.get``) and
+        by nothing else; ``host_fastpath_requests`` counts the requests
+        whose row the targeted oracle produced. So rows dispatched + host
+        fast path + row tier + blob tier + in-batch duplicates = answers
+        with every routing flag at its default, as with the fast path
+        off. The breaker's short circuit enters here too and counts the
+        same way. The whole item loop is the ``host_eval`` ring phase:
+        one clock pair a batch."""
         results: list[AdmissionResponse | Exception | None] = [None] * len(items)
         n_host = 0
+        rec = flightrec.recorder()
+        t0 = time.perf_counter_ns() if rec is not None else 0
         for i, (policy_id, request) in enumerate(items):
             try:
                 target = self._fast_target(policy_id)
@@ -2830,17 +2842,23 @@ class EvaluationEnvironment:
                         self._blob_of(target, request, payload),
                         self._packed_row_of,
                     )
-                if row is None:
+                from_oracle = row is None
+                if from_oracle:
                     row = self._oracle_outputs_for(target, payload)
                     if learn is not None:
                         self._tiers.put_one(learn, row)
                 results[i] = self._materialize(target, request, row)
-                n_host += 1
+                n_host += from_oracle
             except Exception as e:  # noqa: BLE001 — per-item error channel
                 results[i] = e
         if n_host:
             with self._fallback_lock:
                 self._host_fastpath_requests += n_host
+        if rec is not None:
+            rec.record_phase(
+                flightrec.PH_HOST_EVAL, t0, time.perf_counter_ns(),
+                rows=len(items), batch=flightrec.current_batch(),
+            )
         return results  # type: ignore[return-value]
 
     def _validate_batch_native(

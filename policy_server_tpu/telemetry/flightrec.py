@@ -88,6 +88,7 @@ PH_DEVICE_EXECUTE = "device_execute"      # device_get on the drain pool
 PH_FETCH = "fetch"                        # materialize blocked on the drain future
 PH_MATERIALIZE = "materialize"            # outputs → AdmissionResponse rows
 PH_BOOKKEEPING = "bookkeeping"            # row dedup tiers + slot/LRU bookkeeping
+PH_HOST_EVAL = "host_eval"                # host fast path: a batch's whole item loop
 PH_DELIVER = "deliver"                    # phase-3 post-process + completion fan-out
 PH_NATIVE_SERIALIZE = "native_serialize"  # verdict bulk fill to the native frontend
 PH_GC = "gc"                              # one collector pass (GIL held; batch -1)
@@ -117,6 +118,7 @@ PHASES = (
     PH_FETCH,
     PH_MATERIALIZE,
     PH_BOOKKEEPING,
+    PH_HOST_EVAL,
     PH_DELIVER,
     PH_NATIVE_SERIALIZE,
     PH_GC,
@@ -131,7 +133,7 @@ _PHASE_INDEX = {name: i for i, name in enumerate(PHASES)}
 # would double-attribute the device wall.
 _DISPATCH_NESTED = (
     PH_HANDOFF, PH_PREPARE, PH_ENCODE, PH_BLOB_DEDUP, PH_LAUNCH, PH_FETCH,
-    PH_MATERIALIZE, PH_BOOKKEEPING,
+    PH_MATERIALIZE, PH_BOOKKEEPING, PH_HOST_EVAL,
 )
 
 # event kinds

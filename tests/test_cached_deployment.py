@@ -71,6 +71,9 @@ MANIFEST = json.loads((ROOT / "BENCHMARK.json").read_text())
 CONFIG = json.loads((BENCH / "configs" / "flagship32-cached.json").read_text())
 CELLS = ("flagship32-cached.rollout-saturate",
          "flagship32-cached.unique-saturate")
+# PR 35's cell runs the same tiers under the documented routing defaults
+# and reports their metrics too (tests/test_defaults_deployment.py)
+TIER_CELLS = [*CELLS, "flagship32-defaults.unique-saturate"]
 NEW_METRICS = ("device_answered_share", "row_tier_hit_share",
                "batch_duplicate_share", "launched_batch_share",
                "bookkeeping_ms_mean", "cache_evictions_in_window",
@@ -417,12 +420,13 @@ def _per_layer(name: str) -> dict:
 
 
 def test_the_manifest_with_the_python_strings_metric_is_sound():
-    """PR 34 adds one per-layer entry, last in its list, and one data
-    file: strings a row that the native encoder's mirror of the intern
-    table had not seen, in every cell, by the program's own counters."""
+    """PR 34 adds one per-layer entry (last in its list until PR 35's
+    four) and one data file: strings a row that the native encoder's
+    mirror of the intern table had not seen, in every cell, by the
+    program's own counters."""
     assert check_manifest.problems(MANIFEST, ROOT) == []
     encode = _per_layer("encode_us_per_row")
-    assert MANIFEST["per_layer"][-1] == {
+    assert _per_layer("encode_python_strings_per_row") == {
         "name": "encode_python_strings_per_row", "unit": "strings/row",
         "better": "lower", "source": "program_counter",
         "layer": encode["layer"], "moves": "reviews_per_s",
@@ -443,7 +447,7 @@ def test_the_manifest_with_the_bytes_per_put_metric_is_sound():
         "source": "program_counter",
         "layer": "dedup tiers in front of the device (evaluation/"
                  "verdict_cache.py, environment.py _native_schema_pass)",
-        "moves": "reviews_per_s", "workloads": list(CELLS)}
+        "moves": "reviews_per_s", "workloads": TIER_CELLS}
     spec = json.loads(
         (BENCH / "layer_metrics" / "cache_bytes_per_put.json").read_text())
     assert spec["reader"] == "counter_ratio" and "module" not in spec
@@ -548,7 +552,7 @@ def test_a_new_layer_metric_reads_the_programs_counters(name, want):
     assert reduce.read_layer_metric(name, ctx) == pytest.approx(want)
     entry = next(m for m in MANIFEST["per_layer"] if m["name"] == name)
     assert entry["moves"] == "reviews_per_s"
-    assert entry["workloads"] == list(CELLS)
+    assert entry["workloads"] == TIER_CELLS
     # a program without the counters (the eviction counter: the parent)
     # gives nothing to read, and nothing is raised
     assert reduce.read_layer_metric(name, {"before": {}, "after": {}}) is None

@@ -498,3 +498,102 @@ def test_attribution_since_cursor_excludes_old_batches():
     att = rec.attribution(since=cursor)
     assert att["batches_complete"] == 1
     assert att["rows"] == 5
+
+
+# ---------------------------------------------------------------------------
+# the host route's phase (PR 35)
+# ---------------------------------------------------------------------------
+
+
+def _host_route_batches(env, rec: FlightRecorder, rows: int) -> list[dict]:
+    """Batches of ``rows`` under the documented default threshold of 64,
+    so the router answers each on the host; → each batch's ring events."""
+    b = MicroBatcher(
+        env, max_batch_size=128, batch_timeout_ms=1.0, policy_timeout=10.0,
+        host_fastpath_threshold=64, latency_budget_ms=50.0,
+    ).start()
+    try:
+        for burst in range(4):
+            futs = b.submit_many(
+                [("priv", _review(f"host-{burst}-{i}")) for i in range(rows)],
+                RequestOrigin.VALIDATE,
+            )
+            for f in futs:
+                assert f.result(timeout=15).uid
+        assert b.host_fastpath_batches == 4
+    finally:
+        b.shutdown()
+    batches: dict[int, dict] = {}
+    for e in rec.snapshot():
+        if e["kind"] == "batch" and e["batch"] >= 0:
+            batches.setdefault(e["batch"], {}).setdefault(
+                e["phase"], []).append(e)
+    return [phs for phs in batches.values() if PH_DISPATCH in phs]
+
+
+def test_host_eval_is_a_phase_with_one_stamp_site_and_a_panel():
+    from pathlib import Path
+
+    from tools.graftcheck import observability as ob
+
+    root = Path(__file__).resolve().parent.parent
+    assert flightrec.PH_HOST_EVAL == "host_eval" in PHASES
+    assert flightrec.PH_HOST_EVAL in flightrec._DISPATCH_NESTED
+    consts, members = ob._flightrec_phases(
+        root / "policy_server_tpu" / "telemetry" / "flightrec.py")
+    assert "PH_HOST_EVAL" in members
+    sites = ob._phase_record_sites(root / "policy_server_tpu", consts)
+    (site,) = sites["host_eval"]
+    assert site[0].endswith("evaluation/environment.py")
+    dashboard = json.loads((root / "kubewarden-dashboard.json").read_text())
+    exprs = [t["expr"] for p in dashboard["panels"] for t in p["targets"]]
+    assert any('phase_latency_seconds_sum{phase="host_eval"}' in e
+               for e in exprs)
+
+
+def test_a_host_path_batch_stamps_host_eval_once_and_reaches_metrics(env):
+    from policy_server_tpu.telemetry import metrics as metrics_mod
+
+    metrics_mod.reset_metrics_for_tests()
+    try:
+        registry = metrics_mod.default_registry()
+        rec = flightrec.install(
+            FlightRecorder(capacity=4096, registry=registry))
+        batches = _host_route_batches(env, rec, rows=12)
+        assert len(batches) == 4
+        for phs in batches:
+            (stamp,) = phs[flightrec.PH_HOST_EVAL]  # one clock pair a batch
+            assert stamp["rows"] == 12
+            # no device phase: the batch never left the host
+            assert not {"encode", "launch", "fetch"} & set(phs)
+        line = next(
+            ln for ln in registry.exposition().decode().splitlines()
+            if ln.startswith(
+                'policy_server_phase_latency_seconds_count{phase="host_eval"}'))
+        assert float(line.split()[-1]) == 4
+    finally:
+        flightrec.install(None)
+        metrics_mod.reset_metrics_for_tests()
+
+
+def test_a_host_path_batchs_dispatch_is_attributed(env):
+    """The nested phases of a host-path batch (``host_eval`` and the two
+    hand-offs) sum to its ``dispatch`` window within the residual the
+    phase report tolerates (a quarter of the wall); without the phase
+    the whole evaluation read as unattributed dispatch time."""
+    rec = flightrec.install(FlightRecorder(capacity=4096))
+    batches = _host_route_batches(env, rec, rows=48)
+
+    def dur(phs: dict, name: str) -> int:
+        return sum(e["end_ns"] - e["start_ns"] for e in phs.get(name, ()))
+
+    dispatch = sum(dur(phs, PH_DISPATCH) for phs in batches)
+    nested = sum(dur(phs, p) for phs in batches
+                 for p in flightrec._DISPATCH_NESTED)
+    host_eval = sum(dur(phs, flightrec.PH_HOST_EVAL) for phs in batches)
+    assert 0 < nested <= dispatch
+    assert dispatch - nested < 0.25 * dispatch
+    assert host_eval > 0.5 * dispatch  # and it is most of the window
+    att = rec.attribution()
+    assert att["phase_us_per_row"]["host_eval"] > 0
+    assert att["residual_fraction_of_wall"] < 0.25
