@@ -10,6 +10,13 @@
 // predicate bits, published by Python after it interned them); only strings
 // the mirror has not seen go back to Python, as records over an arena.
 //
+// The batch call also does the LAUNCH's host half when asked (write_wire):
+// while it still holds every row and not the GIL it ORs the rows into the
+// liveness words and writes the one wire buffer of the wire form the launch
+// handed in, byte for byte what evaluation/environment.py _live_words and
+// _WireForm.wire make in numpy — so a warm launch makes no numpy call over
+// the batch, and hands the interpreter away for none (PERF.md, PR 37).
+//
 // Semantics mirror ops/codec.py bit for bit:
 //   * dtype mismatches are "missing" (mask stays 0): ID wants a JSON string;
 //     F32 wants a number (bool excluded); I32 wants a syntactic integer
@@ -774,6 +781,58 @@ bool build_node(const SVal& desc, Node& out) {
   return true;
 }
 
+// ------------------------------------------------------------- the wire --
+// The launch's host half, done where the rows already are (the encode call,
+// GIL released): the liveness words and the wire buffer of ONE wire form.
+// The form is the launch's (evaluation/environment.py _WireForm) and comes
+// in as plain arrays; nothing of its layout rule is written here beyond
+// "gather these bytes, pack those":
+//   live[w]     = OR over the batch's rows of the wide row's uint32 word w
+//                 (environment._live_words)
+//   wire[r][j]  = wide[r][take[j]]                      for j <  n_plain
+//   the bytes take[n_plain..n_take) name are lanes: lane k is bit k % 8
+//   of wire[r][n_plain + k / 8], set when its byte is non-zero (numpy's
+//   packbits, bitorder little); the rest of the wire row, and every row
+//   from n_rows up to wire_rows (the bucket's padding), is zero
+//                 (environment._WireForm.wire, byte for byte).
+// Mirrored field for field by ops/fastenc.py _WireRequest.
+struct WireRequest {
+  int64_t row_width;    // bytes of a wide row (a multiple of 4)
+  const int64_t* take;  // the form's gather vector
+  int64_t n_take;
+  int64_t n_plain;      // leading entries of take copied as bytes
+  int64_t wire_width;   // bytes of a wire row
+  int64_t wire_rows;    // rows of the wire buffer (the batch bucket)
+  uint8_t* wire;        // out: uint8[wire_rows, wire_width]
+  uint32_t* live;       // out: uint32[row_width / 4]
+};
+
+// live[i] |= word i of one wide row (read through memcpy: the row is bytes).
+inline void or_words(uint32_t* live, const uint8_t* row, int64_t words) {
+  for (int64_t i = 0; i < words; i++) {
+    uint32_t word;
+    memcpy(&word, row + 4 * i, sizeof word);
+    live[i] |= word;
+  }
+}
+
+void write_wire(const WireRequest& w, const uint8_t* base, int64_t n_rows) {
+  int64_t words = w.row_width / 4;
+  memset(w.live, 0, (size_t)words * sizeof(uint32_t));
+  memset(w.wire, 0, (size_t)(w.wire_rows * w.wire_width));
+  int64_t n_lanes = w.n_take - w.n_plain;
+  for (int64_t r = 0; r < n_rows; r++) {
+    const uint8_t* row = base + r * w.row_width;
+    or_words(w.live, row, words);
+    uint8_t* out = w.wire + r * w.wire_width;
+    for (int64_t j = 0; j < w.n_plain; j++) out[j] = row[w.take[j]];
+    const int64_t* lane = w.take + w.n_plain;
+    uint8_t* bits = out + w.n_plain;
+    for (int64_t k = 0; k < n_lanes; k++)
+      if (row[lane[k]]) bits[k >> 3] |= (uint8_t)(1u << (k & 7));
+  }
+}
+
 }  // namespace
 
 // ------------------------------------------------------------------ C ABI --
@@ -850,13 +909,17 @@ int64_t fastenc_encode(void* handle, const char* json, int64_t len,
 //                -(1000+array_id) axis overflow (those rows are re-tried
 //                host-side on a wider bucket / the oracle)
 //   records gain ABSOLUTE flat offsets (row * prod(caps) + local).
+//   wire       — null, or the launch's host half asked for in the same call
+//                (WireRequest above): written only when the batch left no
+//                record, since a record means Python rewrites id columns
+//                of the wide rows after this call returns
 // Returns number of string records, or -2 on arena/records overflow.
 int64_t fastenc_encode_batch(void* handle, const char** jsons,
                              const int64_t* lens, int64_t n_rows,
                              uint8_t* base, int32_t use_mirror,
                              uint8_t* arena, int64_t arena_cap,
                              int32_t* records, int64_t records_cap,
-                             int32_t* row_status) {
+                             int32_t* row_status, const WireRequest* wire) {
   Schema* schema = (Schema*)handle;
   size_t n_arrays = schema->arrays.size();
   std::vector<int64_t> stride_elems(n_arrays), block_bytes(n_arrays);
@@ -924,7 +987,31 @@ int64_t fastenc_encode_batch(void* handle, const char** jsons,
   if (!records_acc.empty())
     memcpy(records, records_acc.data(),
            records_acc.size() * sizeof(StringRecord));
+  else if (wire != nullptr)
+    write_wire(*wire, base, n_rows);
   return (int64_t)records_acc.size();
+}
+
+// What compacting a chunk does to the launch half its encode call wrote:
+// rows pos[0..n) of the wire buffer go to the first n rows of dst, the
+// rows after them zeroed, and live becomes the OR of the same rows of the
+// WIDE buffer (the shipped rows' own liveness words, not the chunk's: the
+// column set learns exactly what it learnt from a wide copy of them).
+// 52 + 1,064 bytes a row read, small enough to run with the GIL held
+// (ops/fastenc.py binds it through PyDLL): nothing is handed away.
+void fastenc_take_rows(const uint8_t* wire, int64_t wire_width,
+                       const uint8_t* wide, int64_t row_width,
+                       const int64_t* pos, int64_t n, uint8_t* dst,
+                       int64_t dst_rows, uint32_t* live) {
+  int64_t words = row_width / 4;
+  memset(live, 0, (size_t)words * sizeof(uint32_t));
+  for (int64_t r = 0; r < n; r++) {
+    memcpy(dst + r * wire_width, wire + pos[r] * wire_width,
+           (size_t)wire_width);
+    or_words(live, wide + pos[r] * row_width, words);
+  }
+  if (dst_rows > n)
+    memset(dst + n * wire_width, 0, (size_t)((dst_rows - n) * wire_width));
 }
 
 // Publish n strings Python has interned: string i is the len[i] bytes at

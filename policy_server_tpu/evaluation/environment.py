@@ -89,6 +89,7 @@ from policy_server_tpu.ops.codec import (
     SchemaOverflow,
     ensure_unique_packed_widths,
 )
+from policy_server_tpu.ops import fastenc
 from policy_server_tpu.ops.compiler import compile_program
 from policy_server_tpu.policies import resolve_builtin
 from policy_server_tpu.utils.interning import InternTable
@@ -238,7 +239,11 @@ class _WireForm:
     so it keys the compiled program. ``take`` gathers every shipped
     byte straight out of the encoder's wide row (composed once, here: a
     launch pays ONE gather for all three planes, and every numpy call it
-    saves is a hand-off of the GIL it saves); ``cols`` are the positions
+    saves is a hand-off of the GIL it saves — since PR 37 the native
+    encode call makes that gather itself, told ``gather``,
+    whenever this is the settled form and the batch met no cold string;
+    ``wire`` below is then the reference it is tested against, and the
+    path of every other launch); ``cols`` are the positions
     the columns scatter to on the device, placed there once
     (``resident``, filled at the first launch) and not shipped with
     every batch."""
@@ -279,6 +284,14 @@ class _WireForm:
     @property
     def width(self) -> int:
         return self.regions[-1]
+
+    @property
+    def gather(self) -> tuple[np.ndarray, int, int]:
+        """``wire`` as plain values, for the native writer
+        (ops/fastenc.py encode_batch): the gather vector, how many of its
+        leading bytes are copied (the rest pack a bit each), the wire
+        row's width."""
+        return self.take, self.regions[2][0], self.width
 
     def wire(self, buf: np.ndarray) -> np.ndarray:
         """The wire buffer of one wide packed batch: one C-contiguous
@@ -394,6 +407,45 @@ def _live_words(buf: np.ndarray) -> np.ndarray:
     left to look at afterwards is small enough that numpy keeps the
     GIL."""
     return np.bitwise_or.reduce(buf.view(np.uint32), axis=0)
+
+
+class _NativeWire:
+    """What a chunk's encode call wrote of its launch's host half
+    (ops/fastenc.py encode_batch, csrc/fastenc.cpp write_wire): the
+    liveness words and the wire buffer, in ``form``, the form its schema
+    had settled on when the encode began. The launch ships the buffer
+    when that is still the form it decides on (_plane_dispatch) and
+    builds its own otherwise. A compacted chunk (_launch_chunk) takes its
+    shipped rows out of both (``compact``: 52 bytes a row where a wide
+    copy is a thousand) and keeps their positions for a launch that has
+    to fall back (``wide``)."""
+
+    __slots__ = ("form", "live", "wire", "ship_pos")
+
+    def __init__(
+        self, form: _WireForm, live: np.ndarray, wire: np.ndarray
+    ) -> None:
+        self.form = form
+        self.live = live
+        self.wire = wire
+        self.ship_pos: np.ndarray | None = None
+
+    def compact(self, wide: np.ndarray, pos: np.ndarray, batch: int) -> None:
+        self.wire, self.live = fastenc.take_rows(self.wire, wide, pos, batch)
+        self.ship_pos = pos
+
+    def wide(self, buf: np.ndarray) -> np.ndarray:
+        """The wide rows to ship, for a launch that builds its own wire."""
+        if self.ship_pos is None:
+            return buf
+        return _compacted(buf, self.ship_pos, self.wire.shape[0])
+
+
+def _compacted(rows: np.ndarray, pos: np.ndarray, bucket: int) -> np.ndarray:
+    """``rows[pos]`` at the head of a zeroed bucket."""
+    out = np.zeros((bucket, rows.shape[1]), rows.dtype)
+    out[: pos.size] = rows[pos]
+    return out
 
 
 def pre_eval_hooks_of(target: "BoundPolicy | BoundGroup") -> list:
@@ -749,8 +801,6 @@ class EvaluationEnvironment:
         # slower server that still answers 200.
         self.native_encoding = backend == "jax"
         if self.native_encoding:
-            from policy_server_tpu.ops import fastenc
-
             for schema in self.schemas:
                 fastenc.attach_native(schema, self.table)
         if self.optimization is not None:
@@ -914,6 +964,8 @@ class EvaluationEnvironment:
             # -- columnar transport (round 12) ----------------------------
             "wire_bytes_shipped": 0,     # bytes actually transferred
             "launch_h2d_arrays": 0,      # host arrays launches handed over
+            # launches whose wire buffer the encode call had written
+            "launch_native_wire": 0,
             "wire_bytes_packed_equiv": 0,  # what the packed transport
             "wire_rows": 0,                # form would have shipped
             "delta_cols_shipped": 0,   # 32-bit columns shipped (delta)
@@ -2126,11 +2178,15 @@ class EvaluationEnvironment:
         return self._fused_planes(spec, shipped, resident)
 
     def _plane_dispatch(
-        self, schema_idx: int, features: Mapping[str, Any], rows: int = 0
+        self,
+        schema_idx: int,
+        features: Mapping[str, Any],
+        rows: int = 0,
+        native: _NativeWire | None = None,
     ) -> Any:
-        """Columnar device dispatch: build the one wire buffer, account
-        wire bytes / delta columns, and launch the columnar program
-        (async — caller fetches through _device_fetch).
+        """Columnar device dispatch: settle the form, account wire bytes /
+        delta columns, and launch the columnar program on the one wire
+        buffer (async — caller fetches through _device_fetch).
 
         A batch with no live column ships nothing (the all-elided
         program). Every other batch ships its schema's settled column set
@@ -2140,10 +2196,19 @@ class EvaluationEnvironment:
         every batch bucket since boot, bit-exact by construction — and
         the set compiles off the serving path for every warm batch bucket
         (_compile_columns). Only a batch size warm-up never saw compiles
-        inside the dispatch, watchdog-bounded like any cold bucket."""
+        inside the dispatch, watchdog-bounded like any cold bucket.
+
+        Who writes the wire: the chunk's encode call did (``native``,
+        _NativeWire) whenever it left no string record — then the launch
+        reads its liveness words, and ships its buffer if the form decided
+        here, under the lock, is still the one it was written in. Every
+        other launch (a cold string, a set that grew or is compiling, the
+        all-elided batch, any caller with no encode call behind it)
+        computes _live_words and _WireForm.wire here, in numpy: the same
+        bytes, the reference the native writer is tested against, at a
+        hand-off of the GIL a call (PERF.md section 6, PRs 28 and 37)."""
         buf = np.ascontiguousarray(features[PACKED_KEY])
         playout = self.schemas[schema_idx].packed_layout()
-        batch = buf.shape[0]
         narrow = self._narrow(schema_idx)
         layout = self._wire_layout(schema_idx, narrow)
         shipped: dict[str, Any] = {}
@@ -2156,7 +2221,10 @@ class EvaluationEnvironment:
         side_bytes = sum(a.nbytes for a in shipped.values())
         # the liveness check is what keeps a superset exact: a byte no
         # batch had non-zero before grows the set before this one ships
-        live = _live_words(buf)
+        if native is None:
+            batch, live = buf.shape[0], _live_words(buf)
+        else:
+            batch, live = native.wire.shape[0], native.live
         any_live = live.any()
         form, version, schedule = layout.elided, 0, False
         with self._profile_lock:
@@ -2177,6 +2245,7 @@ class EvaluationEnvironment:
                     form, spec = layout.dense, dense
                 else:
                     self._note_plane_program(spec)
+            written = native is not None and native.form is form
             hp = self._host_profile
             hp["wire_bytes_shipped"] += batch * form.width + side_bytes
             hp["wire_bytes_packed_equiv"] += batch * (
@@ -2187,10 +2256,15 @@ class EvaluationEnvironment:
             hp["delta_cols_shipped"] += form.shape[0][0] + form.shape[1][0]
             hp["delta_cols_total"] += playout.total32
             hp["launch_h2d_arrays"] += len(shipped) + bool(form.width)
+            hp["launch_native_wire"] += written
         if schedule:
             self._compile_columns_async(schema_idx, narrow, version)
-        if form.width:
-            shipped[WIRE_KEY] = form.wire(buf)
+        if written:
+            shipped[WIRE_KEY] = native.wire
+        elif form.width:
+            shipped[WIRE_KEY] = form.wire(
+                buf if native is None else native.wide(buf)
+            )
         return self._device_call(
             self._launch_planes, spec, form, shipped, rows=rows
         )
@@ -2268,7 +2342,10 @@ class EvaluationEnvironment:
                 self._plane_jobs_pending -= 1
 
     def _dispatch_features(
-        self, features: Mapping[str, Any], rows: int = 0
+        self,
+        features: Mapping[str, Any],
+        rows: int = 0,
+        native: _NativeWire | None = None,
     ) -> Any:
         """The one device-dispatch funnel for full batches: columnar when
         enabled and the features are a wide packed buffer — including
@@ -2276,11 +2353,17 @@ class EvaluationEnvironment:
         elided planes come back as NamedSharding-placed resident zero
         constants); otherwise the packed (row-major, bit-packed
         transport) path. Multi-process meshes keep the packed path (see
-        _columnar_mesh_ok)."""
+        _columnar_mesh_ok). ``native`` is what the chunk's encode call
+        wrote for a columnar launch."""
         schema_idx = self._schema_index_for(features)
         if self.columnar and self._columnar_mesh_ok():
             if schema_idx is not None:
-                return self._plane_dispatch(schema_idx, features, rows)
+                return self._plane_dispatch(
+                    schema_idx, features, rows, native
+                )
+        # _wire_form_for asks the encode call for a wire only where the
+        # test above holds, and its wide rows are a schema's own
+        assert native is None
         features = self._transport(features)
         if self._mesh is not None:
             from policy_server_tpu.parallel import mesh as mesh_mod
@@ -3084,7 +3167,7 @@ class EvaluationEnvironment:
                             _rec, _bid,
                         )
             try:
-                chunk_blobs, (features, status) = (
+                chunk_blobs, (features, status), native = (
                     self._encode_chunk(schema, chunk, blobs, _rec, _bid)
                     if single
                     else encode_futs.pop(ci).result()
@@ -3107,7 +3190,7 @@ class EvaluationEnvironment:
             if not plan.slot_rows:
                 continue  # all overflowed, or answered by the tiers
             fetch, stash = self._launch_chunk(
-                features, plan, chunk, wasm_infos, single, _rec, _bid
+                features, native, plan, chunk, wasm_infos, single, _rec, _bid
             )
             land = functools.partial(
                 self._land_chunk, fetch, plan, stash, chunk, items,
@@ -3124,6 +3207,19 @@ class EvaluationEnvironment:
             land()
         return overflowed
 
+    def _wire_form_for(self, schema: FeatureSchema) -> _WireForm | None:
+        """The wire form a chunk of this schema should expect to launch
+        in — the one its column set has settled on — or None where the
+        launch builds its own wire whatever the encode call does: before
+        any batch taught the set a column, and off the columnar path."""
+        if not (self.columnar and self._columnar_mesh_ok()):
+            return None
+        schema_idx = self.schemas.index(schema)
+        key = (schema_idx, self._narrow(schema_idx))
+        with self._profile_lock:
+            settled = self._plane_columns.get(key)
+            return settled.form if settled and settled.version else None
+
     def _encode_chunk(
         self,
         schema: FeatureSchema,
@@ -3131,10 +3227,17 @@ class EvaluationEnvironment:
         blobs: list[bytes | None],
         rec: Any,
         bid: int,
-    ) -> tuple[list, tuple[dict[str, np.ndarray], np.ndarray]]:
+    ) -> tuple[
+        list, tuple[dict[str, np.ndarray], np.ndarray], _NativeWire | None
+    ]:
         """Step 1: the chunk's blobs, and their rows and per-row status
-        out of ONE native call."""
+        out of ONE native call — which, told the schema's settled wire
+        form, also writes the launch's host half (the liveness words and
+        the wire buffer, _NativeWire) while it has the rows and not the
+        GIL; None when it was not asked or met a string its mirror had
+        not seen."""
         failpoints.fire("encode.batch")
+        form = self._wire_form_for(schema)
         # the CPU clock is read inside the wall clock's interval, so
         # encode_cpu_ns never exceeds encode_ns; the difference is
         # time this thread was off a core (GIL wait, descheduled)
@@ -3142,7 +3245,8 @@ class EvaluationEnvironment:
         c0 = time.thread_time_ns()
         bl = [blobs[i] for i in chunk]
         features, status, python_strings = schema.native.encode_batch(
-            bl, self.bucket_for(len(bl)), self.table
+            bl, self.bucket_for(len(bl)), self.table,
+            None if form is None else form.gather,
         )
         c1 = time.thread_time_ns()
         t1 = time.perf_counter_ns()
@@ -3154,7 +3258,11 @@ class EvaluationEnvironment:
             rec.record_phase(
                 flightrec.PH_ENCODE, t0, t1, rows=len(chunk), batch=bid,
             )
-        return bl, (features, status)
+        live = features.pop(fastenc.LIVE_KEY, None)
+        native = None if live is None else _NativeWire(
+            form, live, features.pop(fastenc.WIRE_KEY)
+        )
+        return bl, (features, status), native
 
     def _plan_chunk(
         self,
@@ -3211,6 +3319,7 @@ class EvaluationEnvironment:
     def _launch_chunk(
         self,
         features: dict[str, np.ndarray],
+        native: _NativeWire | None,
         plan: DedupTiers.Plan,
         chunk: list[int],
         wasm_infos: dict[int, dict],
@@ -3221,17 +3330,21 @@ class EvaluationEnvironment:
         """Step 3: ship the plan's rows (async). Returns the fetch of the
         result — run inline by whoever asks for a single chunk's, on the
         drain pool otherwise — and the wasm stash."""
+        batch = features[PACKED_KEY].shape[0]
         if plan.ship_pos is not None:
-            # compact: only the rows a request waits for cross the wire
-            # (a copy of the batch: kept out of the bookkeeping span)
-            packed = features[PACKED_KEY]
-            rows = np.zeros(
-                (self.bucket_for(plan.n_rows), packed.shape[1]), packed.dtype
-            )
-            rows[: plan.n_rows] = packed[plan.ship_pos]
-            features = {PACKED_KEY: rows}
+            # compact: only the rows a request waits for cross the wire.
+            # Where the encode call wrote the wire, the launch takes them
+            # out of that; else a copy of the wide batch here (kept out
+            # of the bookkeeping span)
+            batch = self.bucket_for(plan.n_rows)
+            if native is None:
+                features = {PACKED_KEY: _compacted(
+                    features[PACKED_KEY], plan.ship_pos, batch
+                )}
+            else:
+                native.compact(features[PACKED_KEY], plan.ship_pos, batch)
         stash = self._add_wasm_bits(
-            features, features[PACKED_KEY].shape[0],
+            features, batch,
             [
                 (slot, wasm_infos[chunk[pos]])
                 for slot, pos in plan.slot_rows
@@ -3239,7 +3352,9 @@ class EvaluationEnvironment:
             ] if wasm_infos else None,
         )
         t_launch = time.perf_counter_ns() if rec is not None else 0
-        dev_out = self._dispatch_features(features, rows=plan.n_rows)
+        dev_out = self._dispatch_features(
+            features, rows=plan.n_rows, native=native
+        )
         if rec is not None:
             # plane selection, the jit call's host-to-device copies
             # and the enqueue: on the chip, milliseconds a batch

@@ -18,6 +18,17 @@ serving process another thread always takes it (PERF.md section 6, PR 34).
 The mirror is bounded (constants in fastenc.cpp); past its cap nothing more
 is published and the record path answers, exactly, as before.
 
+Who writes the wire: told the wire form its schema's launches have settled
+on as plain values (``encode_batch(..., wire=(take, n_plain, width))``;
+evaluation/environment.py _WireForm.gather),
+the same native call also writes the launch's host half — the batch's
+liveness words and its one wire buffer, LIVE_KEY and WIRE_KEY beside
+PACKED_KEY — whenever the batch left no record. The launch ships them if
+that is still its form and otherwise builds its own with ``_live_words`` and
+``_WireForm.wire``, the numpy reference the native writer is held to byte
+for byte (tests/test_fastenc.py). ``take_rows`` compacts such a buffer
+with the GIL kept (PERF.md section 6, PR 37).
+
 Build model: compiled on demand with g++ into ``build/`` under a name that
 hashes its source and flags (utils/nativebuild.py). A failed build or load
 raises — the pure-Python trie (ops/codec.py) stays as the differential
@@ -56,8 +67,33 @@ _DTYPE = {"id": 0, "f32": 1, "bool": 2, "i32": 3}
 
 _I32P = ctypes.POINTER(ctypes.c_int32)
 
+# Where encode_batch leaves what it wrote for the launch, beside PACKED_KEY
+# in the feature dict it returns (only when asked, and only for a batch
+# that left no record): the batch's liveness words and its wire buffer.
+LIVE_KEY = "__live_words__"
+WIRE_KEY = "__wire__"
+
+
+class _WireRequest(ctypes.Structure):
+    """csrc/fastenc.cpp WireRequest, field for field."""
+
+    _fields_ = [
+        ("row_width", ctypes.c_int64),
+        ("take", ctypes.c_void_p),
+        ("n_take", ctypes.c_int64),
+        ("n_plain", ctypes.c_int64),
+        ("wire_width", ctypes.c_int64),
+        ("wire_rows", ctypes.c_int64),
+        ("wire", ctypes.c_void_p),
+        ("live", ctypes.c_void_p),
+    ]
+
+
 _lib_lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
+# the same library bound again with the GIL KEPT across a call: for the
+# calls too small to be worth handing the interpreter away (take_rows)
+_pylib: ctypes.PyDLL | None = None
 _lib_error: str | None = None  # guarded-by: _lib_lock
 
 
@@ -69,14 +105,16 @@ def _load() -> ctypes.CDLL:
     """The loaded library; raises NativeBuildError when it cannot be built
     or loaded (the failure is remembered: one compile attempt per
     process)."""
-    global _lib, _lib_error
+    global _lib, _pylib, _lib_error
     with _lib_lock:
         if _lib is not None:
             return _lib
         if _lib_error is not None:
             raise NativeBuildError(_lib_error)
         try:
-            lib = ctypes.CDLL(str(_build_library()))
+            path = str(_build_library())
+            lib = ctypes.CDLL(path)
+            pylib = ctypes.PyDLL(path)
         except (NativeBuildError, OSError) as e:
             _lib_error = f"native encoder unavailable: {e}"
             raise NativeBuildError(_lib_error) from e
@@ -99,6 +137,13 @@ def _load() -> ctypes.CDLL:
             ctypes.c_char_p, ctypes.c_int64,
             ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
             ctypes.POINTER(ctypes.c_int32),
+            ctypes.POINTER(_WireRequest),
+        ]
+        pylib.fastenc_take_rows.restype = None
+        pylib.fastenc_take_rows.argtypes = [
+            ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
+            ctypes.c_void_p, ctypes.c_int64, ctypes.POINTER(ctypes.c_uint32),
         ]
         lib.fastenc_learn.restype = ctypes.c_int32
         lib.fastenc_learn.argtypes = [
@@ -109,7 +154,7 @@ def _load() -> ctypes.CDLL:
         ]
         lib.fastenc_mirror_entries.restype = ctypes.c_int64
         lib.fastenc_mirror_entries.argtypes = [ctypes.c_void_p]
-        _lib = lib
+        _lib, _pylib = lib, pylib
         return _lib
 
 
@@ -119,6 +164,37 @@ def native_available() -> bool:
     except NativeBuildError:
         return False
     return True
+
+
+def take_rows(
+    wire: np.ndarray, wide: np.ndarray, pos: np.ndarray, rows: int
+) -> tuple[np.ndarray, np.ndarray]:
+    """A compacted chunk's launch half out of the one its encode call
+    wrote: rows ``pos`` of ``wire`` at the head of a new buffer of
+    ``rows`` rows, the others zero, and the liveness words of the same
+    rows of ``wide`` (the chunk's wide packed buffer) — what
+    ``_WireForm.wire`` and ``_live_words`` make of a wide copy of those
+    rows. One native call that KEEPS the GIL (a few KB read; numpy would
+    hand the interpreter away for each of its calls)."""
+    _load()
+    pos = np.ascontiguousarray(pos, np.int64)
+    for matrix in (wire, wide):
+        if not (matrix.flags.c_contiguous and matrix.dtype == np.uint8):
+            raise ValueError("take_rows: C-contiguous uint8 matrices")
+    if pos.size > rows or (
+        pos.size
+        and not 0 <= pos.min() <= pos.max() < min(len(wire), len(wide))
+    ):
+        raise IndexError("take_rows: positions outside the chunk or bucket")
+    out = np.empty((rows, wire.shape[1]), np.uint8)
+    live = np.empty(wide.shape[1] // 4, np.uint32)
+    _pylib.fastenc_take_rows(
+        wire.ctypes.data, wire.shape[1], wide.ctypes.data, wide.shape[1],
+        pos.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)), pos.size,
+        out.ctypes.data, rows,
+        live.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
+    )
+    return out, live
 
 
 # ---------------------------------------------------------------------------
@@ -300,10 +376,23 @@ class NativeEncoder:
         payload_jsons: list[bytes],
         batch_size: int,
         table: InternTable,
+        wire: tuple[np.ndarray, int, int] | None = None,
     ) -> tuple[dict[str, np.ndarray], np.ndarray, int]:
         """Encode a whole batch in ONE native call, rows written directly
         into the packed batch buffer (codec.PackedLayout) — a dispatch is
         O(1) host→device transfers regardless of schema width.
+
+        ``wire`` is the wire form the launch expects to ship this batch
+        in, as plain values ``(take, n_plain, width)``: a wire row is
+        ``width`` bytes, the wide row's bytes ``take[:n_plain]`` copied,
+        then the bytes ``take[n_plain:]`` packed a bit each, little-endian
+        (evaluation/environment.py _WireForm.gather). The same call then
+        also writes the launch's host half — the batch's liveness words
+        (LIVE_KEY, what environment._live_words computes) and its wire
+        buffer in that form (WIRE_KEY, what _WireForm.wire returns), byte
+        for byte — unless the batch left a record: Python then rewrites id
+        columns of the wide rows after the call, and the launch computes
+        both itself.
 
         → ({PACKED_KEY: buffer} feature dict,
            per-row status: 0 ok, <0 failed — failed rows are all-missing
@@ -314,6 +403,17 @@ class NativeEncoder:
         assert n <= batch_size
         out = self._schema.empty_batch_packed(batch_size)
         buf = out[PACKED_KEY]
+        request = None
+        if wire is not None:
+            take, n_plain, width = wire
+            take = np.ascontiguousarray(take, np.int64)
+            # np.empty: the native call writes every byte of both
+            wire_buf = np.empty((batch_size, width), np.uint8)
+            live = np.empty(buf.shape[1] // 4, np.uint32)
+            request = _WireRequest(
+                buf.shape[1], take.ctypes.data, take.size, n_plain,
+                width, batch_size, wire_buf.ctypes.data, live.ctypes.data,
+            )
         blob_lens = [len(b) for b in payload_jsons]
         jsons = (ctypes.c_char_p * n)(*payload_jsons)
         lens = (ctypes.c_int64 * n)(*blob_lens)
@@ -336,7 +436,7 @@ class NativeEncoder:
             buf.ctypes.data, mirrored,
             arena, len(arena),
             scratch.records_ptr, len(records) // 6,
-            status.ctypes.data_as(_I32P),
+            status.ctypes.data_as(_I32P), request,
         )
         if n_rec == -2:
             raise ValueError("fastenc: arena/records overflow")
@@ -351,6 +451,8 @@ class NativeEncoder:
             )
             if mirrored and self._mirror_open:
                 self._learn(arena, learned, table)
+        elif request is not None:
+            out[LIVE_KEY], out[WIRE_KEY] = live, wire_buf
         return out, status, int(n_rec)
 
     def _scatter_strings(

@@ -1219,6 +1219,14 @@ class PolicyServer:
                 profile.get("launch_h2d_arrays", 0),
             )
             yield (
+                metrics_names.LAUNCH_NATIVE_WIRE, "counter",
+                "Columnar launches that shipped the wire buffer their "
+                "chunk's native encode call wrote (the others built it in "
+                "numpy: a cold string, a column set that grew or is "
+                "compiling, an all-zero batch)",
+                profile.get("launch_native_wire", 0),
+            )
+            yield (
                 metrics_names.WIRE_BYTES_PACKED_EQUIV, "counter",
                 "Bytes the row-packed transport form would have shipped "
                 "for the same dispatches",

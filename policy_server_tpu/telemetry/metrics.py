@@ -137,6 +137,10 @@ WIRE_BYTES_SHIPPED = "policy_server_wire_bytes_shipped"
 # wire buffer a launch since PR 28; per launch against
 # policy_server_phase_latency_seconds_count{phase="launch"})
 LAUNCH_H2D_ARRAYS = "policy_server_launch_h2d_arrays"
+# launches whose wire buffer the chunk's encode call had written
+# (csrc/fastenc.cpp write_wire) and not numpy in the launch (PR 37; per
+# launch against the same count)
+LAUNCH_NATIVE_WIRE = "policy_server_launch_native_wire"
 WIRE_BYTES_PACKED_EQUIV = "policy_server_wire_bytes_packed_equivalent"
 WIRE_ROWS = "policy_server_wire_rows"
 DELTA_COLS_SHIPPED = "policy_server_delta_columns_shipped"

@@ -22,6 +22,7 @@ from policy_server_tpu.api.service import RequestOrigin
 from policy_server_tpu.evaluation.environment import (
     WIRE_KEY,
     EvaluationEnvironmentBuilder,
+    _live_words,
 )
 from policy_server_tpu.models import AdmissionReviewRequest, ValidateRequest
 from policy_server_tpu.models.policy import parse_policy_entry
@@ -518,6 +519,243 @@ class TestOneWireBuffer:
             assert env.host_profile["launch_h2d_arrays"] == before
         finally:
             env.close()
+
+def _watch_dispatches(env, monkeypatch) -> list:
+    """Every later columnar dispatch of the serving path, in order, as
+    (the form launched, whether the encode call had written a wire for
+    it, whether the chunk was compacted) — after holding the buffer it
+    shipped against the launch's own ``_WireForm.wire`` of the wide rows
+    it stood for, byte for byte, whoever wrote it."""
+    seen: list = []
+    launched = _record_launches(env, monkeypatch)
+    dispatch = env._plane_dispatch
+
+    def checking(schema_idx, features, rows=0, native=None):
+        out = dispatch(schema_idx, features, rows, native)
+        _spec, form, sent, _served = launched[-1]
+        wide = np.ascontiguousarray(features[next(iter(features))])
+        if native is not None:
+            wide = native.wide(wide)
+            # the words it read are the shipped rows' own, compacted or not
+            assert native.live.tobytes() == _live_words(wide).tobytes()
+        if form.width:
+            assert sent[WIRE_KEY].tobytes() == form.wire(wide).tobytes()
+            assert sent[WIRE_KEY].shape == (wide.shape[0], form.width)
+        seen.append((
+            form, native is not None,
+            native is not None and native.ship_pos is not None,
+        ))
+        return out
+
+    monkeypatch.setattr(env, "_plane_dispatch", checking)
+    return seen
+
+
+def _move_forms_after_encode(env, monkeypatch) -> None:
+    """From now on every schema's column set moves on right after a chunk
+    is encoded, as if another batch had grown it meanwhile: the same
+    columns in a new form object (so the same compiled program)."""
+    from policy_server_tpu.evaluation.environment import _WireForm
+
+    encode_chunk = env._encode_chunk
+
+    def encode_then_move(*args):
+        out = encode_chunk(*args)
+        with env._profile_lock:
+            for columns in env._plane_columns.values():
+                columns.form = _WireForm(columns.layout, columns.cols)
+                columns.version += 1
+        return out
+
+    monkeypatch.setattr(env, "_encode_chunk", encode_then_move)
+
+
+def _native_launches(env, fn) -> tuple[int, int]:
+    """(launches, launches that shipped the encode call's wire) of fn."""
+    before = env.host_profile
+    fn()
+    after = env.host_profile
+    return (
+        after["dispatched_chunks"] - before["dispatched_chunks"],
+        after["launch_native_wire"] - before["launch_native_wire"],
+    )
+
+
+def _cold_items(capability: str, n: int = 24):
+    """Requests that add a capability no encoder has met: a string the
+    schema reads, which its mirror answers with a record."""
+    reqs = []
+    for d in synthetic_firehose(n, seed=11):
+        first = d["request"]["object"]["spec"]["containers"][0]
+        first.setdefault("securityContext", {})["capabilities"] = {
+            "add": [capability]
+        }
+        reqs.append(ValidateRequest.from_admission(
+            AdmissionReviewRequest.from_dict(d).request
+        ))
+    return _items(reqs)
+
+
+class TestNativeWire:
+    """The encode call writes the launch's wire buffer and liveness words
+    (PR 37); the launch ships them when what it can observe allows, and
+    otherwise does what it always did. Every case is held to the oracle,
+    and every shipped buffer to ``_WireForm.wire`` (_watch_dispatches)."""
+
+    @pytest.fixture()
+    def env(self):
+        env = EvaluationEnvironmentBuilder(
+            backend="jax", verdict_cache_size=0
+        ).build(_parsed())
+        env.warmup((8, 32, 256))
+        yield env
+        env.close()
+
+    @pytest.fixture(scope="class")
+    def oracle(self):
+        env = EvaluationEnvironmentBuilder(backend="oracle").build(_parsed())
+        yield lambda items: _dicts(env.validate_batch(items))
+        env.close()
+
+    @pytest.mark.parametrize("narrow", [True, False], ids=["u16", "i32"])
+    def test_a_settled_batch_ships_the_encode_calls_wire(
+        self, env, oracle, corpus, narrow, monkeypatch
+    ):
+        if not narrow:
+            monkeypatch.setattr(env, "_narrow", lambda schema_idx: False)
+        _settle(env, corpus)
+        seen = _watch_dispatches(env, monkeypatch)
+        for items in (corpus, corpus[:1], corpus[:4], corpus[:67]):
+            n, native = _native_launches(
+                env, lambda items=items: _check(env, oracle, items)
+            )
+            assert n >= 1 and native == n
+        assert all(wrote and not compacted for _f, wrote, compacted in seen)
+        assert not any(form.resident is None for form, *_ in seen)
+
+    def test_a_cold_string_leaves_the_wire_to_the_launch(
+        self, env, oracle, corpus, monkeypatch
+    ):
+        _settle(env, corpus)
+        seen = _watch_dispatches(env, monkeypatch)
+        cold = _cold_items("NEVER_SEEN_37")
+        n, native = _native_launches(env, lambda: _check(env, oracle, cold))
+        assert n >= 1 and native == 0
+        assert not any(wrote for _f, wrote, _c in seen)
+        # the string is known now: the same requests are the native path's
+        _wait_compiled(env)
+        n, native = _native_launches(env, lambda: _check(env, oracle, cold))
+        assert n >= 1 and native == n
+
+    def test_a_batch_that_grows_the_column_set(
+        self, env, oracle, corpus, monkeypatch
+    ):
+        """Settled on part of the corpus, then a batch with a column that
+        part never had live: its wire was written in the old form, the
+        launch decides on another (the dense one while the grown set
+        compiles) and builds its own."""
+        first = [it for it in corpus if it[0] == "pod-privileged"][:8]
+        _settle(env, first)
+        # every string of the corpus met (the mirror learns at encode):
+        # what is left to fall back for is the column set alone
+        env.schemas[0].native.encode_batch(
+            [req.payload_json() for _pid, req in corpus], 256, env.table
+        )
+        version = next(iter(env._plane_columns.values())).version
+        seen = _watch_dispatches(env, monkeypatch)
+        n, native = _native_launches(env, lambda: _check(env, oracle, corpus))
+        assert next(iter(env._plane_columns.values())).version > version
+        assert n >= 1 and native == 0
+        assert [wrote for _f, wrote, _c in seen] == [True] * n
+        _wait_compiled(env)
+        n, native = _native_launches(env, lambda: _check(env, oracle, corpus))
+        assert native == n
+
+    def test_a_form_that_moved_between_encode_and_launch(
+        self, env, oracle, corpus, monkeypatch
+    ):
+        """The set's version moves after the chunk was encoded (another
+        batch grew it meanwhile): the wire written for the old form is
+        dropped, whatever the new form looks like."""
+        _settle(env, corpus)
+        with monkeypatch.context() as moving:
+            _move_forms_after_encode(env, moving)
+            seen = _watch_dispatches(env, moving)
+            n, native = _native_launches(
+                env, lambda: _check(env, oracle, corpus)
+            )
+            assert n >= 1 and native == 0
+            assert all(wrote for _f, wrote, _c in seen)
+        n, native = _native_launches(env, lambda: _check(env, oracle, corpus))
+        assert native == n
+
+    def test_a_program_not_compiled_yet_ships_the_dense_form(
+        self, env, oracle, corpus, monkeypatch
+    ):
+        """The settled set's program is not in _plane_combos for this
+        batch bucket: the launch ships the dense form, which is not the
+        form the encode call wrote."""
+        monkeypatch.setattr(env, "_compile_columns_async", lambda *a: None)
+        env.validate_batch(corpus)            # teaches the set; no compile
+        seen = _watch_dispatches(env, monkeypatch)
+        n, native = _native_launches(env, lambda: _check(env, oracle, corpus))
+        assert n >= 1 and native == 0
+        layout = env._wire_layout(0, True)
+        assert all(
+            form is layout.dense and wrote for form, wrote, _c in seen
+        )
+
+    @pytest.mark.parametrize("moved", [False, True], ids=["wire", "fallback"])
+    def test_a_compacted_chunk_takes_rows_of_the_wire(
+        self, oracle, corpus, moved, monkeypatch
+    ):
+        """With the tiers on, a chunk with in-batch duplicates ships only
+        its distinct rows: the same rows of the encode call's wire (52
+        bytes each, not a wide copy) — and, when the form moved
+        meanwhile, the wide copy and the launch's own wire of it."""
+        env = EvaluationEnvironmentBuilder(backend="jax").build(_parsed())
+        try:
+            env.warmup((8, 32, 256))
+            _settle(env, corpus)
+            env.reset_verdict_cache()
+            if moved:
+                _move_forms_after_encode(env, monkeypatch)
+            seen = _watch_dispatches(env, monkeypatch)
+            doubled = corpus[:40] + corpus[:40]
+            n, native = _native_launches(
+                env, lambda: _check(env, oracle, doubled)
+            )
+            assert n >= 1 and native == (0 if moved else n)
+            assert all(wrote and compacted for _f, wrote, compacted in seen)
+        finally:
+            env.close()
+
+    def test_columnar_off_asks_for_no_wire(self, oracle, corpus):
+        env = EvaluationEnvironmentBuilder(
+            backend="jax", columnar=False, verdict_cache_size=0
+        ).build(_parsed())
+        try:
+            for _ in range(2):
+                n, native = _native_launches(
+                    env, lambda: _check(env, oracle, corpus)
+                )
+                assert n >= 1 and native == 0
+            assert env._wire_form_for(env.schemas[0]) is None
+        finally:
+            env.close()
+
+    def test_an_all_elided_batch_ships_nothing(self, env, corpus):
+        _settle(env, corpus)
+        n, native = _native_launches(
+            env, lambda: env.run_batch(env.schemas[0].empty_batch_packed(8))
+        )
+        assert native == 0
+
+
+def _check(env, oracle, items) -> None:
+    env.reset_verdict_cache()
+    assert _dicts(env.validate_batch(items)) == oracle(items)
+
 
 class TestSubmitMany:
     @pytest.fixture()

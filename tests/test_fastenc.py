@@ -445,3 +445,152 @@ def test_string_met_under_one_predicate_then_another(env):
     pod["spec"]["initContainers"] = [{"name": "i", "image": word}]
     pod["metadata"]["annotations"] = {word: word}
     assert _assert_exact(schema, env.table, enc, parent, [elsewhere]) == 0
+
+
+# -- the launch's host half, written by the encode call (PR 37) -----------
+
+
+def _wire_forms(env, which: int, narrow: bool, packed: np.ndarray) -> dict:
+    """The three kinds of wire form a launch can ship in, for one schema
+    and id width: the all-elided one, the one a column set settles on
+    after seeing ``packed``, and the dense one."""
+    from policy_server_tpu.evaluation.environment import (
+        _live_words,
+        _PlaneColumns,
+    )
+
+    schema_idx = range(len(env.schemas))[which]
+    layout = env._wire_layout(schema_idx, narrow)
+    columns = _PlaneColumns(layout)
+    columns.admit(_live_words(packed), env._select_delta_cols)
+    assert columns.version and columns.form.width
+    return {
+        "elided": layout.elided, "settled": columns.form,
+        "dense": layout.dense,
+    }
+
+
+@pytest.mark.parametrize("rows", [1, 4, 67, 128])
+@pytest.mark.parametrize("kind", ["elided", "settled", "dense"])
+@pytest.mark.parametrize("narrow", [True, False], ids=["u16", "i32"])
+def test_the_native_wire_is_the_launchs_own_byte_for_byte(
+    env, narrow, kind, rows
+):
+    """The wire buffer and the liveness words the encode call writes are
+    what _WireForm.wire and _live_words make of the rows it wrote:
+    narrow and full-width ids, every kind of form, a bucket with no
+    padding (1, 4, 128 rows) and with it (67 of 128)."""
+    from policy_server_tpu.evaluation.environment import (
+        _live_words,
+        bucket_size,
+    )
+
+    schema = env.schemas[0]
+    enc = fastenc.NativeEncoder(schema, env.table)
+    blobs = _blobs(synthetic_firehose(rows, seed=37))
+    bucket = bucket_size(rows)
+    cold, status, met = enc.encode_batch(blobs, bucket, env.table)
+    assert met and not status.any()
+    form = _wire_forms(env, 0, narrow, cold[PACKED_KEY])[kind]
+    got, status, met = enc.encode_batch(
+        blobs, bucket, env.table, form.gather
+    )
+    assert met == 0 and not status.any()
+    packed = got[PACKED_KEY]
+    assert packed.tobytes() == cold[PACKED_KEY].tobytes()
+    wire, live = got[fastenc.WIRE_KEY], got[fastenc.LIVE_KEY]
+    want = form.wire(packed)
+    assert wire.flags.c_contiguous and wire.dtype == np.uint8
+    assert wire.shape == want.shape == (bucket, form.width)
+    assert wire.tobytes() == want.tobytes()
+    assert live.dtype == np.uint32
+    assert live.tobytes() == _live_words(packed).tobytes()
+    assert not wire[rows:].any()
+
+
+def test_a_batch_with_a_record_leaves_the_wire_to_the_launch(env):
+    """A string the mirror has not seen comes back as a record, and
+    Python rewrites id columns after the native call: such a batch
+    carries neither words nor wire, whatever form it was told."""
+    schema = env.schemas[0]
+    enc = fastenc.NativeEncoder(schema, env.table)
+    docs = synthetic_firehose(8, seed=38)
+    warm = enc.encode_batch(_blobs(docs), 8, env.table)[0]
+    form = _wire_forms(env, 0, True, warm[PACKED_KEY])["settled"]
+    cold_docs = _renamed(docs, "ns-wire-37", "registry.example/wire:37")
+    cold, _, met = enc.encode_batch(
+        _blobs(cold_docs), 8, env.table, form.gather
+    )
+    assert met and fastenc.WIRE_KEY not in cold
+    assert fastenc.LIVE_KEY not in cold
+    # met once: the same batch again is the native call's, wire and all
+    again, _, met = enc.encode_batch(
+        _blobs(cold_docs), 8, env.table, form.gather
+    )
+    assert met == 0
+    assert again[PACKED_KEY].tobytes() == cold[PACKED_KEY].tobytes()
+    assert (
+        again[fastenc.WIRE_KEY].tobytes()
+        == form.wire(again[PACKED_KEY]).tobytes()
+    )
+    # and no form asked for, none written
+    assert set(enc.encode_batch(_blobs(docs), 8, env.table)[0]) == {
+        PACKED_KEY
+    }
+
+
+def test_the_native_wire_of_a_batch_with_a_failed_row(env):
+    """A row that fails to parse is wiped to all-missing in the wide
+    buffer before the wire is written: its wire row is zero and its bytes
+    are in no liveness word."""
+    from policy_server_tpu.evaluation.environment import _live_words
+
+    schema = env.schemas[0]
+    enc = fastenc.NativeEncoder(schema, env.table)
+    blobs = _blobs(synthetic_firehose(5, seed=39))
+    enc.encode_batch(blobs, 8, env.table)
+    good = enc.encode_batch(blobs, 8, env.table)[0][PACKED_KEY]
+    form = _wire_forms(env, 0, True, good)["settled"]
+    blobs[2] = blobs[2][: len(blobs[2]) // 2]  # truncated JSON
+    got, status, met = enc.encode_batch(blobs, 8, env.table, form.gather)
+    assert met == 0 and status[2] < 0 and not status[[0, 1, 3, 4]].any()
+    packed = got[PACKED_KEY]
+    assert not packed[2].any() and not got[fastenc.WIRE_KEY][2].any()
+    assert got[fastenc.WIRE_KEY].tobytes() == form.wire(packed).tobytes()
+    assert got[fastenc.LIVE_KEY].tobytes() == _live_words(packed).tobytes()
+
+
+@pytest.mark.parametrize("picked, rows", [(0, 1), (1, 1), (3, 4), (5, 8)])
+def test_take_rows_is_the_launch_half_of_a_wide_copy(picked, rows):
+    """Compacting what the encode call wrote: the picked rows of the wire
+    at the head of a zeroed bucket, and the liveness words of the picked
+    wide rows alone."""
+    from policy_server_tpu.evaluation.environment import _live_words
+
+    rng = np.random.default_rng(40)
+    wire = rng.integers(0, 256, (16, 52), dtype=np.uint8)
+    wide = rng.integers(0, 2, (16, 1064), dtype=np.uint8)
+    pos = rng.permutation(16)[:picked].astype(np.intp)
+    want = np.zeros((rows, 52), np.uint8)
+    want[:picked] = wire[pos]
+    got, live = fastenc.take_rows(wire, wide, pos, rows)
+    assert got.flags.c_contiguous and got.dtype == np.uint8
+    assert got.tobytes() == want.tobytes()
+    assert live.dtype == np.uint32
+    assert live.tobytes() == _live_words(
+        np.ascontiguousarray(wide[pos])
+    ).tobytes()
+
+
+def test_take_rows_refuses_what_it_cannot_copy():
+    wire, wide = np.zeros((4, 8), np.uint8), np.zeros((4, 16), np.uint8)
+    with pytest.raises(IndexError):
+        fastenc.take_rows(wire, wide, np.array([4]), 2)
+    with pytest.raises(IndexError):
+        fastenc.take_rows(wire, wide, np.array([-1]), 2)
+    with pytest.raises(IndexError):
+        fastenc.take_rows(wire, wide, np.array([0, 1, 2]), 2)
+    with pytest.raises(ValueError):
+        fastenc.take_rows(wire[:, ::2], wide, np.array([0]), 2)
+    with pytest.raises(ValueError):
+        fastenc.take_rows(wire, wide[:, ::2], np.array([0]), 2)

@@ -204,6 +204,71 @@ def test_launch_h2d_arrays_on_the_pull_endpoint(server):
     )
 
 
+def test_launch_native_wire_on_the_pull_endpoint(server):
+    """The launches whose wire buffer the encode call wrote, counted
+    where the benchmark reads them: one more for a launch of a settled
+    shape whose strings are all known, none for a launch that fell back
+    (a string the encoder's mirror has not seen), and the family's value
+    is the environment's own count."""
+    env = server.server.environment
+
+    def post(uid: str, image: str) -> tuple[int, int]:
+        doc = json.loads(_review_body(uid, False))
+        doc["request"]["object"]["spec"]["containers"][0]["image"] = image
+        # every tier forgets: the same pod shape reaches the device again
+        env.reset_verdict_cache()
+        before = env.host_profile
+        r = requests.post(
+            server.url("/validate/latest-tag"), data=json.dumps(doc),
+            headers={"Content-Type": "application/json"}, timeout=30,
+        )
+        assert r.status_code == 200
+        deadline = time.monotonic() + 120
+        while env.plane_programs_pending:  # a grown set compiles off-path
+            assert time.monotonic() < deadline
+            time.sleep(0.05)
+        after = env.host_profile
+        return (
+            after["dispatched_chunks"] - before["dispatched_chunks"],
+            after["launch_native_wire"] - before["launch_native_wire"],
+        )
+
+    assert post("u-wire-1", "registry.example/wire:1") == (1, 0)  # cold
+    assert post("u-wire-2", "registry.example/wire:1")[0] == 1    # settles
+    assert post("u-wire-3", "registry.example/wire:1") == (1, 1)
+    assert post("u-wire-4", "registry.example/wire:2") == (1, 0)  # cold
+    assert post("u-wire-5", "registry.example/wire:2") == (1, 1)
+    m = _scrape(server)
+    assert (
+        m[metrics_mod.LAUNCH_NATIVE_WIRE + "_total"]
+        == env.host_profile["launch_native_wire"]
+        >= 2
+    )
+
+
+def test_the_dashboard_shows_native_wire_beside_h2d_arrays():
+    from pathlib import Path
+
+    dashboard = json.loads(
+        (Path(__file__).parent.parent / "kubewarden-dashboard.json")
+        .read_text()
+    )
+    panels = [
+        [t["expr"] for t in p.get("targets", [])]
+        for p in dashboard["panels"]
+    ]
+    launch = 'policy_server_phase_latency_seconds_count{phase="launch"}'
+    both = [
+        exprs for exprs in panels
+        if any(metrics_mod.LAUNCH_H2D_ARRAYS + "_total" in e for e in exprs)
+    ]
+    assert len(both) == 1
+    assert any(
+        metrics_mod.LAUNCH_NATIVE_WIRE + "_total" in e and launch in e
+        for e in both[0]
+    )
+
+
 def test_encode_python_strings_on_the_pull_endpoint(server):
     """The native encoder's mirror of the intern table, counted where the
     benchmark reads it: a string it has not seen is Python's once (the
