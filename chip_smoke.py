@@ -17,7 +17,8 @@ backend: a chip belongs to one process, and that process is the server
 under test. Without ``--platform cpu`` a missing accelerator is a failure,
 never a fallback. A phase that fails raises; nothing is caught.
 
-    python chip_smoke.py                   # one chip, --mesh auto
+    python chip_smoke.py                   # one chip, --mesh auto; then
+                                           # the audit stage (scanner on)
     python chip_smoke.py --chips 4         # data:4, then data:2,policy:2
     python chip_smoke.py --platform cpu --requests 256    # rehearsal
 
@@ -440,6 +441,87 @@ def run_case(
         server.stop()
 
 
+AUDIT_REQUESTS = 1000  # reviews the audit stage serves
+
+
+def run_audit_stage(
+    args: argparse.Namespace, base_args: list[str], env: dict[str, str],
+    log_dir: Path, tls: ssl.SSLContext, requests: list[bytes],
+    reference: list, policy_count: int,
+) -> None:
+    """The compliance scanner beside live admission (PR 38): boot with
+    ``--audit-mode interval``, serve 1,000 reviews, wait for the scanner to
+    sweep what they left dirty, and hold the lane to its counts: every
+    object the store holds has a report row of every policy, no sweep
+    failed, and the audit rows were counted by the lane and not as
+    requests the device answered."""
+    requests, reference = requests[:AUDIT_REQUESTS], reference[:AUDIT_REQUESTS]
+    say(f"stage audit: booting with --audit-mode interval, "
+        f"{len(requests)} reviews")
+    server = Server(
+        "under-test-audit",
+        [*base_args, "--audit-mode", "interval",
+         "--audit-interval-seconds", "1"],
+        env, log_dir,
+    )
+    done = False
+    try:
+        server.wait_ready(args.ready_timeout)
+        boot = server.metrics()
+        got = send_pass(server, tls, requests, args.connections, args.depth)
+        check_responses("under-test-audit", got, reference)
+        deadline = time.monotonic() + args.ready_timeout
+        while True:
+            snap = server.metrics()
+            resources = scalar(snap, "policy_server_audit_snapshot_resources")
+            resident = scalar(snap, "policy_server_audit_reports_resident")
+            if (resources > 0 and resident == resources * policy_count
+                    and scalar(snap, "policy_server_audit_lane_depth") == 0):
+                break
+            require(
+                time.monotonic() < deadline,
+                f"the scanner holds {resident:.0f} report rows for "
+                f"{resources:.0f} objects x {policy_count} policies after "
+                f"{args.ready_timeout:.0f}s",
+            )
+            time.sleep(0.5)
+
+        def moved(family: str) -> float:
+            return scalar(snap, family) - scalar(boot, family)
+
+        say(f"  {resources:.0f} objects in the snapshot store, "
+            f"{resident:.0f} report rows, "
+            f"{moved('policy_server_audit_batches_dispatched'):.0f} audit "
+            "batches, "
+            f"{moved('policy_server_audit_preemptions'):.0f} handed back")
+        require(
+            moved("policy_server_audit_rows_dispatched") >= resident,
+            "the lane counted "
+            f"{moved('policy_server_audit_rows_dispatched'):.0f} rows, the "
+            f"reports hold {resident:.0f}",
+        )
+        require(
+            moved("policy_server_dispatched_rows") == len(requests),
+            "rows dispatched for a caller: "
+            f"{moved('policy_server_dispatched_rows'):.0f}, requests: "
+            f"{len(requests)} - audit rows were counted as answers",
+        )
+        require(
+            scalar(snap, "policy_server_audit_sweep_errors") == 0,
+            "an audit sweep failed",
+        )
+        for family in MUST_STAY_ZERO:
+            require(scalar(snap, family) == 0,
+                    f"{family} = {scalar(snap, family):.0f} with the "
+                    "scanner on")
+        done = True
+    finally:
+        if not done:
+            print(f"--- under-test-audit log tail ---\n{server.log_tail()}",
+                  file=sys.stderr, flush=True)
+        server.stop()
+
+
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=0)
@@ -542,6 +624,9 @@ def main(argv: list[str] | None = None) -> int:
         for mesh in meshes:
             info = run_case(args, mesh, base_args, env, log_dir, tls,
                             requests, reference, cache_dir)
+        if meshes == [None]:  # one chip: the scanner's lane has a stage
+            run_audit_stage(args, base_args, env, log_dir, tls, requests,
+                            reference[0], len(specs))
         ok = True
     finally:
         if not ok:

@@ -25,6 +25,26 @@ memoized, and the live path computed it already), so a sweep re-submits
 pre-encoded rows and the verdict-cache/dedup tiers make re-scans of
 unchanged objects nearly free. Memory is bounded by
 ``--audit-max-snapshot-bytes`` with LRU eviction on the recording order.
+
+A request that can ``freeze()`` itself (the native front-end's zero-parse
+request: payload bytes and four header strings) is kept as that tuple of
+atoms and made a request again by the class that froze it when a sweep
+reaches it (:meth:`SnapshotStore.request_of`). The collector stops
+tracking such a tuple at its first pass, where the request and its
+header are five objects every full pass walks, GIL held, for as long as
+the store keeps them: with ~76,000 of them and a sweep's rows as a list
+beside them the passes took 4.5 s of a 20 s saturated window on the chip
+and the cell's runs spread 7-10%; this way 1.2-1.3 s and 2-3% (PR 38).
+
+The budget also bounds what the scanner can cover. An object it pushes
+out is gone for the scanner as a deleted one is: the scanner drops its
+report rows (:meth:`SnapshotStore.take_evictions`) and a sweep that had
+collected it skips it (:meth:`SnapshotStore.holds`), so the reports speak
+of resident objects only. One pushed out while still dirty was never
+re-judged at all, and is counted (``evicted_dirty``): when admissions
+outrun the lane for longer than the budget lasts, that is most of them
+(PR 38, on the chip: ~76,600 pods fit in 64Mi, a saturated server
+records ~5,500 a second and re-judges ~25).
 """
 
 from __future__ import annotations
@@ -39,6 +59,17 @@ from policy_server_tpu.models import (
     ValidateRequest,
 )
 from policy_server_tpu.telemetry.tracing import logger
+
+
+def _payload_json(stored: Any) -> bytes:
+    """The payload bytes of a stored entry: a frozen request's first
+    item, else the request's own (memoized)."""
+    return stored[0] if type(stored) is tuple else stored.payload_json()
+
+
+def _nbytes(stored: Any) -> int:
+    """What a stored entry counts against the byte budget."""
+    return len(_payload_json(stored))
 
 
 def synthesize_review(
@@ -100,18 +131,26 @@ class SnapshotStore:
     def __init__(self, max_bytes: int = 64 * 1024 * 1024):
         self.max_bytes = max(0, int(max_bytes))
         self._lock = threading.Lock()
-        # key -> (request, nbytes); insertion order is the LRU axis
+        # key -> the request as stored (itself, or what it froze to);
+        # insertion order is the LRU axis
         self._rows: collections.OrderedDict[
-            str, tuple[ValidateRequest, int]
+            str, Any
         ] = collections.OrderedDict()  # guarded-by: _lock
+        # the class whose requests froze themselves into the store: it
+        # thaws them (one front-end a process, so one class)
+        self._thaw: Any = None
         self._dirty: set[str] = set()  # guarded-by: _lock
         # keys evicted by an observed DELETE since the last sweep — the
         # scanner drains these to prune the objects' report rows
         self._pending_deletions: set[str] = set()  # guarded-by: _lock
+        # keys the byte budget pushed out since the last sweep: their
+        # report rows go too, but nothing was deleted from the cluster
+        self._pending_evictions: set[str] = set()  # guarded-by: _lock
         self._bytes = 0  # guarded-by: _lock
         self._recorded = 0  # guarded-by: _lock
         self._superseded = 0  # guarded-by: _lock
         self._evicted = 0  # guarded-by: _lock
+        self._evicted_dirty = 0  # guarded-by: _lock
         self._deleted = 0  # guarded-by: _lock
         # bumps on every mutating observe — the /audit/reports ETag axis
         self._generation = 0  # guarded-by: _lock
@@ -123,7 +162,7 @@ class SnapshotStore:
         per formed batch from the dispatch worker — sizes are computed
         OUTSIDE the lock (payload_json is memoized; the encoder reuses
         it, so this is not wasted work)."""
-        prepared: list[tuple[str, ValidateRequest | None, int]] = []
+        prepared: list[tuple[str, Any, int]] = []
         for request in requests:
             key = resource_key(request)
             if key is None:
@@ -132,26 +171,31 @@ class SnapshotStore:
             if adm is not None and (adm.operation or "").upper() == "DELETE":
                 prepared.append((key, None, 0))
                 continue
-            prepared.append((key, request, len(request.payload_json())))
+            freeze = getattr(request, "freeze", None)
+            if freeze is None:
+                stored = request
+            else:
+                stored, self._thaw = freeze(), type(request).thaw
+            prepared.append((key, stored, len(request.payload_json())))
         if not prepared:
             return
         with self._lock:
             self._generation += 1
-            for key, request, nbytes in prepared:
-                if request is None:
-                    old = self._rows.pop(key, None)
+            for key, stored, nbytes in prepared:
+                old = self._rows.pop(key, None)
+                if old is not None:
+                    self._bytes -= _nbytes(old)
+                if stored is None:
                     if old is not None:
-                        self._bytes -= old[1]
                         self._deleted += 1
                     self._dirty.discard(key)
                     self._pending_deletions.add(key)
                     continue
                 self._pending_deletions.discard(key)  # re-created object
-                old = self._rows.pop(key, None)
+                self._pending_evictions.discard(key)
                 if old is not None:
-                    self._bytes -= old[1]
                     self._superseded += 1
-                self._rows[key] = (request, nbytes)
+                self._rows[key] = stored
                 self._bytes += nbytes
                 self._recorded += 1
                 self._dirty.add(key)
@@ -162,10 +206,19 @@ class SnapshotStore:
         if self.max_bytes <= 0:
             return
         while self._bytes > self.max_bytes and self._rows:
-            key, (_req, nbytes) = self._rows.popitem(last=False)
-            self._bytes -= nbytes
-            self._dirty.discard(key)
+            key, stored = self._rows.popitem(last=False)
+            self._bytes -= _nbytes(stored)
             self._evicted += 1
+            if key in self._dirty:  # no sweep ever collected it
+                self._dirty.discard(key)
+                self._evicted_dirty += 1
+            self._pending_evictions.add(key)
+
+    def request_of(self, stored: Any) -> ValidateRequest:
+        """The request a stored entry stands for: itself, or, for one
+        that froze, a new request each time, so keep it no longer than
+        the work that needs it."""
+        return self._thaw(stored) if type(stored) is tuple else stored
 
     # -- durable spill (round 17, statestore.py) ---------------------------
 
@@ -175,7 +228,7 @@ class SnapshotStore:
         is serialization-free for rows the live path already encoded)."""
         with self._lock:
             items = list(self._rows.items())
-        return [(key, req.payload_json()) for key, (req, _n) in items]
+        return [(key, _payload_json(stored)) for key, stored in items]
 
     def restore_rows(self, pairs: Iterable[tuple[str, bytes]]) -> int:
         """Rebuild inventory rows from a spill's pre-encoded payloads (a
@@ -238,9 +291,10 @@ class SnapshotStore:
 
     def collect(
         self, dirty_only: bool = False
-    ) -> list[tuple[str, ValidateRequest]]:
+    ) -> list[tuple[str, Any]]:
         """Snapshot the sweep corpus and clear the dirty set: the FULL
-        inventory, or only the keys touched since the last collect.
+        inventory, or only the keys touched since the last collect, each
+        with its entry as stored (:meth:`request_of` gives the request).
         A failed sweep re-marks its unscanned keys via
         :meth:`remark_dirty` so the next sweep picks them back up."""
         with self._lock:
@@ -249,7 +303,7 @@ class SnapshotStore:
             else:
                 keys = list(self._rows)
             self._dirty.clear()
-            return [(k, self._rows[k][0]) for k in keys]
+            return [(k, self._rows[k]) for k in keys]
 
     def remark_dirty(self, keys: Iterable[str]) -> None:
         with self._lock:
@@ -271,7 +325,8 @@ class SnapshotStore:
         boot payload-hash validation read this; :meth:`collect` remains
         the only consumer that claims the dirty set)."""
         with self._lock:
-            return [(k, row[0]) for k, row in self._rows.items()]
+            items = list(self._rows.items())
+        return [(k, self.request_of(stored)) for k, stored in items]
 
     def dirty_keys(self) -> set[str]:
         with self._lock:
@@ -285,6 +340,22 @@ class SnapshotStore:
             self._pending_deletions = set()
             return out
 
+    def take_evictions(self) -> set[str]:
+        """Drain the keys the byte budget pushed out since the last call:
+        the scanner drops their report rows, as it does a deleted
+        object's (the matrix keeps its own bound: a full sweep's
+        ``retain``)."""
+        with self._lock:
+            out = self._pending_evictions
+            self._pending_evictions = set()
+            return out
+
+    def holds(self, keys: Iterable[str]) -> set[str]:
+        """Of ``keys``, those still in the store: a sweep asks before it
+        spends a lane job on objects collected a while ago."""
+        with self._lock:
+            return {k for k in keys if k in self._rows}
+
     # -- introspection -----------------------------------------------------
 
     def stats(self) -> dict[str, int]:
@@ -297,6 +368,7 @@ class SnapshotStore:
                 "recorded": self._recorded,
                 "superseded": self._superseded,
                 "evicted": self._evicted,
+                "evicted_dirty": self._evicted_dirty,
                 "deleted": self._deleted,
             }
 

@@ -274,8 +274,14 @@ def _flag_specs() -> list[tuple[str, str | None, dict[str, Any]]]:
                    "continuously re-scans a snapshot of cluster resources "
                    "(seeded from --audit-resources-file and from every "
                    "object served through /validate) through the live "
-                   "policy epoch on the micro-batcher's best-effort lane "
-                   "— live traffic strictly preempts audit work. "
+                   "policy epoch on the micro-batcher's best-effort lane: "
+                   "an audit batch goes out only when the live queue came "
+                   "up empty, one at a time, in live-sized slices; a batch "
+                   "already out is not recalled, and it shares the "
+                   "interpreter with the live threads. Not free: see "
+                   "PERF.md (on one v5e chip, the flagship set: about two "
+                   "fifths of a 32-caller closed loop's throughput, about a "
+                   "tenth at saturation). "
                    "'interval' sweeps the dirty set on a cadence and "
                    "fully on every policy-epoch promotion; 'on-promote' "
                    "sweeps fully on epoch flips only; 'off' disables the "
@@ -287,21 +293,43 @@ def _flag_specs() -> list[tuple[str, str | None, dict[str, Any]]]:
                    "sweep are re-judged)")),
         ("--audit-batch-size", "KUBEWARDEN_AUDIT_BATCH_SIZE",
          dict(type=int, default=256, metavar="N",
-              help="Rows per best-effort audit-lane batch (audit rides "
-                   "idle device slots in large batches; at most one "
-                   "audit dispatch is ever in flight)")),
+              help="Rows per best-effort audit-lane batch (at most one "
+                   "audit dispatch is ever in flight; the environment "
+                   "takes it in slices of at most --max-batch-size, so no "
+                   "value compiles a program warm-up did not)")),
         ("--audit-max-snapshot-bytes", "KUBEWARDEN_AUDIT_MAX_SNAPSHOT_BYTES",
          dict(default="64Mi", metavar="BYTES",
               help="Byte budget of the audit snapshot store holding "
                    "cluster resources as pre-encoded admission rows "
                    "(accepts K/M/G[i] suffixes; least-recently-recorded "
-                   "rows evict beyond it)")),
+                   "rows evict beyond it). It bounds coverage too: the "
+                   "reports list resident resources only, one evicted "
+                   "loses its report rows, and one evicted before a "
+                   "sweep had judged it counts under "
+                   "policy_server_audit_objects_unjudged_total — size "
+                   "the budget to the cluster (64Mi holds ~76,000 pods), "
+                   "not to the admission rate")),
         ("--audit-resources-file", "KUBEWARDEN_AUDIT_RESOURCES_FILE",
          dict(default=None, metavar="RESOURCES_FILE",
               help="YAML/JSON file of Kubernetes objects (a list or a "
                    "List document) seeding the audit snapshot store at "
                    "boot — the stand-in for the companion audit "
                    "scanner's cluster LIST")),
+        ("--audit-observe-admissions",
+         "KUBEWARDEN_AUDIT_OBSERVE_ADMISSIONS",
+         dict(default="on", metavar="MODE", choices=["on", "off"],
+              help="Record every object served through /validate in the "
+                   "audit snapshot store, on the dispatch path of its "
+                   "batch (~10 us a request on one v5e chip's host; "
+                   "policy_server_audit_observe_seconds_total). 'off' "
+                   "leaves the store to --audit-resources-file and "
+                   "--audit-watch, as the reference's companion scanner "
+                   "lists the cluster and never sees an admission, and "
+                   "the live path pays nothing. A deployment whose only "
+                   "source is its admissions names 'on': a build "
+                   "without this flag (its native front-end fed the "
+                   "store nothing) then refuses the command line "
+                   "instead of sweeping an empty store")),
         ("--audit-watch", "KUBEWARDEN_AUDIT_WATCH",
          dict(action="store_true",
               help="Feed the audit snapshot store from the Kubernetes "

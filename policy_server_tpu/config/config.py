@@ -252,6 +252,10 @@ class Config:
     # optional YAML/JSON resources file seeding the snapshot store at
     # boot (the stand-in for the companion scanner's cluster LIST)
     audit_resources_file: str | None = None
+    # record every object served through /validate in the snapshot
+    # store (the batcher's dispatch path); off leaves the store to the
+    # resources file and the watch feed
+    audit_observe_admissions: bool = True
     # live-cluster watch feed (audit/watch_feed.py, round 13): list+watch
     # events populate the audit snapshot store directly, so the scanner
     # audits the LIVE cluster instead of only /validate traffic + a seed
@@ -630,6 +634,7 @@ class Config:
             audit_batch_size=int(args.audit_batch_size),
             audit_max_snapshot_bytes=parse_size(args.audit_max_snapshot_bytes),
             audit_resources_file=args.audit_resources_file or None,
+            audit_observe_admissions=args.audit_observe_admissions == "on",
             audit_watch=args.audit_watch,
             audit_watch_resources=args.audit_watch_resources,
             audit_watch_max_queue_events=int(

@@ -960,6 +960,7 @@ class EvaluationEnvironment:
             "bookkeeping_rows": 0,
             "dispatch_wait_ns": 0,   # blocked in device_get at materialize
             "dispatched_rows": 0,    # unique rows actually shipped
+            "audit_rows": 0,         # ... by the audit lane, for nobody
             "dispatched_chunks": 0,
             # -- columnar transport (round 12) ----------------------------
             "wire_bytes_shipped": 0,     # bytes actually transferred
@@ -2812,6 +2813,7 @@ class EvaluationEnvironment:
         items: list[tuple[str, ValidateRequest]],
         run_hooks: bool = True,
         prefer_host: bool = False,
+        audit: bool = False,
     ) -> list[AdmissionResponse | Exception]:
         """Evaluate many (policy_id, request) pairs in ONE device dispatch.
 
@@ -2832,6 +2834,11 @@ class EvaluationEnvironment:
         (prefer_host=False, the default) always exercises the device, so
         differential tests comparing this environment against the oracle
         backend stay non-circular.
+
+        ``audit=True`` (the batcher's best-effort lane) marks rows that
+        answer nobody: what they ship counts as ``audit_rows``, not as
+        ``dispatched_rows``, which is how many requests the device
+        answered.
         """
         if self._closed:
             raise RuntimeError("environment closed")
@@ -2850,7 +2857,7 @@ class EvaluationEnvironment:
             return self._validate_batch_hostpath(items, run_hooks)
         if self.backend == "jax":
             # chunks to max_dispatch_batch internally, with pipelining
-            return self._validate_batch_native(items, run_hooks)
+            return self._validate_batch_native(items, run_hooks, audit=audit)
         # the oracle backend: every row by the host interpreter
         results: list[AdmissionResponse | Exception] = []
         for policy_id, request in items:
@@ -2949,6 +2956,7 @@ class EvaluationEnvironment:
         items: list[tuple[str, ValidateRequest]],
         run_hooks: bool,
         defer_sink: list | None = None,
+        audit: bool = False,
     ) -> list[AdmissionResponse | Exception]:
         """The native fast path: JSON bytes → batch arrays in one C++ call
         per shape bucket, rows written in place (no per-request arrays, no
@@ -3048,7 +3056,7 @@ class EvaluationEnvironment:
                 break
             pending = self._native_schema_pass(
                 schema, items, targets, results, pending, wasm_infos,
-                blobs, defer_sink,
+                blobs, defer_sink, audit,
             )
 
         for i in pending:  # beyond the widest schema → oracle
@@ -3123,6 +3131,7 @@ class EvaluationEnvironment:
         wasm_infos: dict[int, dict],
         blobs: list[bytes | None],
         defer_sink: list | None,
+        audit: bool = False,
     ) -> list[int]:
         """Encode+dispatch all ``pending`` rows against one schema, a
         chunk at a time in four steps: encode (_encode_chunk) → plan, who
@@ -3190,7 +3199,8 @@ class EvaluationEnvironment:
             if not plan.slot_rows:
                 continue  # all overflowed, or answered by the tiers
             fetch, stash = self._launch_chunk(
-                features, native, plan, chunk, wasm_infos, single, _rec, _bid
+                features, native, plan, chunk, wasm_infos, single, _rec,
+                _bid, audit,
             )
             land = functools.partial(
                 self._land_chunk, fetch, plan, stash, chunk, items,
@@ -3326,6 +3336,7 @@ class EvaluationEnvironment:
         single: bool,
         rec: Any,
         bid: int,
+        audit: bool = False,
     ) -> tuple[Any, dict[str, list]]:
         """Step 3: ship the plan's rows (async). Returns the fetch of the
         result — run inline by whoever asks for a single chunk's, on the
@@ -3364,7 +3375,12 @@ class EvaluationEnvironment:
                 flightrec.PH_LAUNCH, t_launch, time.perf_counter_ns(),
                 rows=plan.n_rows, batch=bid,
             )
-        self._profile_add(dispatched_rows=plan.n_rows, dispatched_chunks=1)
+        # one answer, one source: dispatched_rows counts the requests the
+        # device answered, and an audit row answers nobody
+        self._profile_add(
+            **{"audit_rows" if audit else "dispatched_rows": plan.n_rows},
+            dispatched_chunks=1,
+        )
         fetch = (_InlineFetch if single else self._drain_pool.submit)(
             self._scoped_device_fetch, failpoints.current_scope(), dev_out,
             bid, plan.n_rows,

@@ -360,6 +360,7 @@ class PolicyShardedEvaluator:
         items: list[tuple[str, ValidateRequest]],
         run_hooks: bool = True,
         prefer_host: bool = False,
+        audit: bool = False,
     ) -> list[AdmissionResponse | Exception]:
         """Partition the batch by owning shard, dispatch every shard's fused
         program, merge in submission order.
@@ -390,7 +391,8 @@ class PolicyShardedEvaluator:
             def run_shard(idx: int, indices: list[int]):
                 shard_items = [items[i] for i in indices]
                 return shards[idx].validate_batch(
-                    shard_items, run_hooks=run_hooks, prefer_host=prefer_host
+                    shard_items, run_hooks=run_hooks,
+                    prefer_host=prefer_host, audit=audit,
                 )
 
             if len(per_shard) > 1:

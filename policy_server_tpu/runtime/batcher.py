@@ -446,6 +446,10 @@ class MicroBatcher:
         # eligible requests the matrix could not answer (no cell, stale
         # column fingerprint, payload drift, ineligible template)
         self.matrix_lookup_misses = 0  # guarded-by: _stats_lock
+        # wall time of audit_tracker.observe on the dispatch path: what
+        # feeding the scanner's snapshot costs every live batch
+        self.audit_observe_ns = 0  # guarded-by: _stats_lock
+        self._audit_observe_failed = False  # the failure was logged once
         # audit batches popped for dispatch but re-queued because live
         # work arrived first (the preemption contract in action)
         self.audit_preemptions = 0  # guarded-by: _stats_lock
@@ -603,6 +607,7 @@ class MicroBatcher:
                 "audit_batches_dispatched": self.audit_batches_dispatched,
                 "audit_rows_dispatched": self.audit_rows_dispatched,
                 "audit_preemptions": self.audit_preemptions,
+                "audit_observe_ns": self.audit_observe_ns,
                 "matrix_lookup_hits": self.matrix_lookup_hits,
                 "matrix_lookup_misses": self.matrix_lookup_misses,
             }
@@ -1183,15 +1188,26 @@ class MicroBatcher:
     def _maybe_dispatch_audit(self) -> None:
         """Called by the dispatch loop ONLY when the live queue came up
         empty: admit at most one audit batch onto the (width-1) audit
-        pool. Slack is evaluated before taking the lane lock — it reads
-        the environment's breaker state, and lock-order discipline keeps
-        _audit_lock innermost."""
+        pool, and only while a pipeline worker is free. Slack is evaluated
+        before taking the lane lock — it reads the environment's breaker
+        state, and lock-order discipline keeps _audit_lock innermost."""
         if self._stopping:
             return
         with self._audit_lock:
             if self._audit_inflight or not self._audit_jobs:
                 return
             head_rows = len(self._audit_jobs[0].pairs)
+        if not self._inflight.acquire(blocking=False):
+            # the queue is empty because every pipeline worker holds a
+            # live batch, not because the load let up: an audit job now
+            # could only take the interpreter from them. On the chip at
+            # saturation the lane sent ~200 jobs in a 20 s window through
+            # exactly these moments, each ~47 ms on the wall, a quarter
+            # of all rows dispatched (PR 38). Only this thread, the
+            # dispatch loop, ever acquires, so the probe takes no slot
+            # from a live batch.
+            return
+        self._inflight.release()
         if not self._audit_slack_ok(head_rows):
             return
         with self._audit_lock:
@@ -1255,13 +1271,7 @@ class MicroBatcher:
                     return
             try:
                 try:
-                    # raw verdicts (audit-origin semantics: constraints
-                    # never applied); run_hooks=False — the scan judges
-                    # policy logic, not hook latency, exactly like the
-                    # reload canary
-                    results = self._scoped(
-                        self.env.validate_batch, job.pairs, run_hooks=False
-                    )
+                    results = self._dispatch_audit(job.pairs)
                 except Exception as e:  # noqa: BLE001 — the job carries it
                     job.future.set_exception(e)
                     return
@@ -1275,6 +1285,41 @@ class MicroBatcher:
         finally:
             with self._audit_lock:
                 self._audit_inflight = False
+
+    def _dispatch_audit(self, pairs: list) -> list:
+        """One audit batch through the environment: raw verdicts
+        (audit-origin semantics: constraints never applied); audit=True —
+        its rows are the lane's to count (audit_rows_dispatched), not a
+        live answer's. Pre-evaluation hooks run as they do for a live
+        request: a hook is not only latency, the signature policy's is
+        what verifies an image, and a row judged without it reports every
+        image no live request had verified yet as unsigned (PR 38: the
+        reports differed from the plain reference exactly there).
+
+        It goes a live-sized slice at a time, as _audit_slack_ok prices
+        it: --audit-batch-size may exceed --max-batch-size, and a launch
+        wider than any live batch is a program warm-up never compiled —
+        it would compile inside a dispatch, with live traffic behind it.
+        The whole job is one ring phase under a batch id of its own, so
+        the environment's phases inside it (encode, launch, fetch) have
+        a batch to belong to."""
+        rec = flightrec.recorder()
+        bid = rec.next_batch() if rec is not None else -1
+        t0 = time.perf_counter_ns()
+        results: list = []
+        step = self.max_batch_size
+        with flightrec.batch_scope(bid):
+            for at in range(0, len(pairs), step):
+                results.extend(self._scoped(
+                    self.env.validate_batch, pairs[at : at + step],
+                    audit=True,
+                ))
+        if rec is not None:
+            rec.record_phase(
+                flightrec.PH_AUDIT_DISPATCH, t0, time.perf_counter_ns(),
+                rows=len(pairs), batch=bid,
+            )
+        return results
 
     def _drain_audit_rejecting(self) -> None:
         while True:
@@ -1575,6 +1620,7 @@ class MicroBatcher:
             except Exception:  # noqa: BLE001 — recording must not fail
                 pass  # the batch (canary corpus just stays smaller)
         if self.audit_tracker is not None:
+            t_observe = time.perf_counter_ns()
             try:
                 # dirty-set tracking for the background audit scanner:
                 # only objects ADMITTED through /validate belong in the
@@ -1587,7 +1633,19 @@ class MicroBatcher:
                     ]
                 )
             except Exception:  # noqa: BLE001 — tracking must not fail
-                pass  # the batch (the scan corpus just stays smaller)
+                # the batch (the scan corpus just stays smaller) — but
+                # say so once: until PR 38 every native-path batch
+                # failed here in silence and the snapshot stayed empty
+                if not self._audit_observe_failed:
+                    self._audit_observe_failed = True
+                    from policy_server_tpu.telemetry.tracing import logger
+
+                    logger.exception(
+                        "audit snapshot tracking failed; the scanner "
+                        "will not see these objects"
+                    )
+            with self._stats_lock:
+                self.audit_observe_ns += time.perf_counter_ns() - t_observe
 
         # Phase 1 (host): pre-evaluation — id parse, namespace shortcut,
         # bounded pre-eval hooks. Items that short-circuit or fail resolve

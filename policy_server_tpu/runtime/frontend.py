@@ -30,8 +30,11 @@ from __future__ import annotations
 
 import asyncio
 import json
+import re
 import struct
 from typing import Any, Mapping
+
+from policy_server_tpu.models import GroupVersionKind
 
 _REQ_HEADER = struct.Struct("<QBH")
 _PARSED_EXTRA = struct.Struct("<I")  # header-json length, parsed frames only
@@ -94,19 +97,96 @@ class _WireKind:
         self.kind = kind
 
 
+# The head of the canonical payload both parsed-frame producers write
+# (csrc/httpfront.cpp canon_admission_review, AdmissionRequest.to_dict):
+# uid, kind, resource, then the optional sub-resource / request-kind keys,
+# then name. Group 1-3: the review's kind; group 4: its name, None when
+# the review carries none. A payload laid out any other way does not
+# match and is parsed whole.
+_JSTR = rb'"[^"\\]*(?:\\.[^"\\]*)*"'
+_JGVK = (
+    rb'\{"group":' + _JSTR + rb',"version":' + _JSTR + rb',"%s":' + _JSTR
+    + rb"\}"
+)
+_IDENTITY = re.compile(
+    rb'\{"uid":' + _JSTR
+    + rb',"kind":\{"group":(' + _JSTR + rb'),"version":(' + _JSTR
+    + rb'),"kind":(' + _JSTR + rb")\}"
+    + rb',"resource":' + _JGVK % b"resource"
+    + rb'(?:,"subResource":' + _JSTR + rb")?"
+    + rb'(?:,"requestKind":' + _JGVK % b"kind" + rb")?"
+    + rb'(?:,"requestResource":' + _JGVK % b"resource" + rb")?"
+    + rb'(?:,"requestSubResource":' + _JSTR + rb")?"
+    + rb'(?:,"name":(' + _JSTR + rb'))?,"(?:namespace|operation)":'
+)
+
+
+def _unquote(literal: bytes) -> str:
+    """A JSON string literal's value; only one with an escape in it costs
+    a parse."""
+    if b"\\" in literal:
+        return json.loads(literal)
+    return literal[1:-1].decode()
+
+
+# the kinds met so far, by their three literals as the payload spells
+# them: a cluster has a few hundred, so this stays small
+_KINDS: dict[tuple, GroupVersionKind] = {}
+
+
+def _identity_of(payload: bytes) -> tuple[GroupVersionKind, Any]:
+    """(kind, name) of the object a canonical request payload targets,
+    read off the payload's head without parsing the object."""
+    m = _IDENTITY.match(payload)
+    if m is None:
+        d = json.loads(payload)
+        return (
+            GroupVersionKind.from_dict(d.get("kind")) or GroupVersionKind(),
+            d.get("name"),
+        )
+    spelled = m.group(1, 2, 3)
+    kind = _KINDS.get(spelled)
+    if kind is None:
+        kind = GroupVersionKind(*map(_unquote, spelled))
+        if len(_KINDS) < 4096:
+            _KINDS[spelled] = kind
+    name = m.group(4)
+    return kind, None if name is None else _unquote(name)
+
+
 class _WireAdmission:
     """The slice of AdmissionRequest the service layer reads (namespace
-    shortcut + metric labels); everything else lives in the payload
-    bytes."""
+    shortcut + metric labels) off the header; everything else lives in
+    the payload bytes. ``kind`` and ``name`` — the object's identity,
+    which the audit snapshot store keys on — are read from the payload's
+    head on first use."""
 
-    __slots__ = ("uid", "namespace", "operation", "request_kind")
+    __slots__ = (
+        "uid", "namespace", "operation", "request_kind", "_payload",
+        "_identity",
+    )
 
-    def __init__(self, header: Mapping[str, Any]):
+    def __init__(self, header: Mapping[str, Any], payload: bytes):
         self.uid = str(header.get("uid") or "")
         self.namespace = header.get("namespace")
         self.operation = header.get("operation")
         kind = header.get("kind")
         self.request_kind = _WireKind(str(kind)) if kind else None
+        self._payload = payload
+        self._identity = None
+
+    def _ident(self) -> tuple[GroupVersionKind, Any]:
+        if self._identity is None:
+            self._identity = _identity_of(self._payload)
+        return self._identity
+
+    @property
+    def kind(self) -> GroupVersionKind:
+        return self._ident()[0]
+
+    @property
+    def name(self) -> Any:
+        return self._ident()[1]
 
 
 class WireValidateRequest:
@@ -122,7 +202,7 @@ class WireValidateRequest:
     raw = None
 
     def __init__(self, header: Mapping[str, Any], payload_bytes: bytes):
-        self.admission_request = _WireAdmission(header)
+        self.admission_request = _WireAdmission(header, payload_bytes)
         self._payload_bytes = payload_bytes
         self._payload_cache = None
 
@@ -136,6 +216,23 @@ class WireValidateRequest:
 
     def payload_json(self) -> bytes:
         return self._payload_bytes
+
+    def freeze(self) -> tuple:
+        """This request as a tuple of bytes, strings and None: what a
+        store that keeps tens of thousands of them holds in its place
+        (audit/snapshot.py). The collector stops tracking such a tuple
+        the first time it meets it, where this object and its header
+        would be walked by every full pass for as long as they live."""
+        adm = self.admission_request
+        kind = adm.request_kind
+        return (self._payload_bytes, adm.uid, adm.namespace, adm.operation,
+                None if kind is None else kind.kind)
+
+    @classmethod
+    def thaw(cls, frozen: tuple) -> "WireValidateRequest":
+        payload, uid, namespace, operation, kind = frozen
+        return cls({"uid": uid, "namespace": namespace,
+                    "operation": operation, "kind": kind}, payload)
 
 
 # ---------------------------------------------------------------------------

@@ -472,7 +472,9 @@ class PolicyServer:
                 request_timeout_ms=request_timeout,
                 degraded_mode=degraded,
                 shadow_recorder=tenant_recorder,
-                audit_tracker=tracker,
+                audit_tracker=(
+                    tracker if config.audit_observe_admissions else None
+                ),
                 # lookup admission stays scoped like the audit scanner:
                 # only the DEFAULT tenant (the one feeding the snapshot
                 # store) consults the matrix
@@ -1067,9 +1069,21 @@ class PolicyServer:
                 bstats["audit_batches_dispatched"],
             )
             yield (
+                metrics_names.AUDIT_ROWS_DISPATCHED, "counter",
+                "Rows of the audit-lane batches dispatched: they answer "
+                "no request and count under no answer source",
+                bstats["audit_rows_dispatched"],
+            )
+            yield (
                 metrics_names.AUDIT_PREEMPTIONS, "counter",
                 "Audit batches re-queued because live work arrived first",
                 bstats["audit_preemptions"],
+            )
+            yield (
+                metrics_names.AUDIT_OBSERVE_SECONDS, "counter",
+                "Wall time the dispatch path spent recording served "
+                "objects into the audit snapshot store",
+                bstats["audit_observe_ns"] / 1e9,
             )
             yield (
                 metrics_names.AUDIT_LANE_DEPTH, "gauge",
@@ -1129,6 +1143,19 @@ class PolicyServer:
                 metrics_names.AUDIT_SNAPSHOT_BYTES, "gauge",
                 "Resident bytes of the audit snapshot store",
                 astats.get("snapshot_bytes", 0),
+            )
+            yield (
+                metrics_names.AUDIT_SNAPSHOT_EVICTIONS, "counter",
+                "Resources the byte budget pushed out of the audit "
+                "snapshot store, oldest first",
+                astats.get("snapshot_evictions", 0),
+            )
+            yield (
+                metrics_names.AUDIT_OBJECTS_UNJUDGED, "counter",
+                "Of those, resources pushed out before a sweep had "
+                "judged them under every policy: what the byte budget "
+                "costs in coverage",
+                astats.get("objects_unjudged", 0),
             )
             # Native HTTP front-end (round 11): framing throughput, parse
             # fallbacks (Python stays the parse oracle), serialization

@@ -101,6 +101,13 @@ AUDIT_REPORTS_RESIDENT = "policy_server_audit_reports_resident"
 AUDIT_REPORTS_STALE = "policy_server_audit_reports_stale"
 AUDIT_SNAPSHOT_RESOURCES = "policy_server_audit_snapshot_resources"
 AUDIT_SNAPSHOT_BYTES = "policy_server_audit_snapshot_bytes"
+# PR 38 — what the scanner costs the live path, and the lane's own row
+# count (an audit row answers nobody: it is counted here and under no
+# answer source)
+AUDIT_ROWS_DISPATCHED = "policy_server_audit_rows_dispatched"
+AUDIT_OBSERVE_SECONDS = "policy_server_audit_observe_seconds_total"
+AUDIT_SNAPSHOT_EVICTIONS = "policy_server_audit_snapshot_evictions"
+AUDIT_OBJECTS_UNJUDGED = "policy_server_audit_objects_unjudged"
 # round 11 — native HTTP front-end (csrc/httpfront.cpp +
 # runtime/native_frontend.py): GIL-free framing counters, plus the
 # batcher queue-wait leg of the framing/queue/device decomposition

@@ -342,8 +342,9 @@ def test_the_oracle_answers_what_no_tier_may_cache():
 
 def test_the_manifest_with_the_deployment_is_sound():
     assert check_manifest.problems(MANIFEST, ROOT) == []
-    entry = MANIFEST["configs"][-1]
-    assert entry["name"] == "flagship32-defaults" == CONFIG["name"]
+    entry = next(c for c in MANIFEST["configs"]
+                 if c["name"] == "flagship32-defaults")
+    assert entry["name"] == CONFIG["name"]
     assert entry["file"] == "benchmarks/configs/flagship32-defaults.json"
     assert entry["reduced"] == [] and len(entry["source"]) <= 200
     assert entry["source"] == CONFIG["source"]
@@ -373,7 +374,7 @@ def test_the_manifest_with_the_deployment_is_sound():
 
 
 def test_the_cell_is_the_issues_letter_for_letter():
-    entry = MANIFEST["workloads"][-1]
+    entry = next(w for w in MANIFEST["workloads"] if w["name"] == CELL)
     assert {k: entry[k] for k in ("name", "config", "traffic", "chips")} == {
         "name": CELL, "config": "flagship32-defaults",
         "traffic": "unique-saturate", "chips": 1}
