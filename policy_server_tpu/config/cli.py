@@ -154,9 +154,14 @@ def _flag_specs() -> list[tuple[str, str | None, dict[str, Any]]]:
               help="Maximum time a request waits for its micro-batch to fill")),
         ("--host-fastpath-threshold", "KUBEWARDEN_HOST_FASTPATH_THRESHOLD",
          dict(type=int, default=64, metavar="N",
-              help="Micro-batches with at most N requests are answered by "
+              help="Micro-batches with at most N requests, and only while "
+                   "the batch pipeline has a free slot, are answered by "
                    "the bit-exact host oracle instead of a device dispatch "
-                   "(latency fast-path; 0 disables). The verdict cache is "
+                   "(latency fast-path; 0 disables). A batch that small "
+                   "which waited for a pipeline slot or took the last one "
+                   "is throughput traffic: it rides the device and counts "
+                   "in policy_server_host_fastpath_declined_batches. The "
+                   "verdict cache is "
                    "asked first there too, and every answer is counted by "
                    "one source: a cache hit by the cache's own hit counter, "
                    "a request the oracle evaluated by "

@@ -347,9 +347,12 @@ class MicroBatcher:
         # with the device program by the differential suite) instead of
         # paying a device round-trip — the batched analog of the
         # reference's per-request sync path (src/api/handlers.rs:256-286).
-        # 0 disables. Under load the queue is deep, batches form at
-        # max_batch_size, and everything rides the device; the fast-path
-        # engages exactly when occupancy is low and latency dominates.
+        # 0 disables. The count is an upper bound, not the test for low
+        # occupancy: in a closed loop at saturation batches form at ~60
+        # rows with every pipeline slot taken (their size is how fast the
+        # loop drains, not how shallow the queue is), so the route is
+        # taken only while the pipeline has a slot to spare
+        # (_launch_batch, _evaluate_runnable).
         self.host_fastpath_threshold = max(0, int(host_fastpath_threshold))
         self._env_fastpath = bool(
             getattr(env, "supports_host_fastpath", False)
@@ -396,6 +399,9 @@ class MicroBatcher:
             max_workers=self._batch_workers, thread_name_prefix="batch"
         )
         self._inflight = threading.BoundedSemaphore(self._batch_workers)
+        # live batches holding a slot of _inflight (the semaphore's own
+        # value is private): what the host fast-path reads as occupancy
+        self._batches_inflight = 0  # guarded-by: _stats_lock
         # _dispatch runs on concurrent batch-pool workers: counter updates
         # must be locked (+= is a racy read-modify-write).
         self._stats_lock = threading.Lock()
@@ -403,6 +409,9 @@ class MicroBatcher:
         self.requests_dispatched = 0  # guarded-by: _stats_lock
         self.deadline_abandoned_batches = 0  # guarded-by: _stats_lock
         self.host_fastpath_batches = 0  # guarded-by: _stats_lock
+        # batches at or under the threshold that went to the device
+        # because the pipeline was full when they were handed to it
+        self.host_fastpath_declined_batches = 0  # guarded-by: _stats_lock
         # batches routed host-side by the latency-budget check (a strict
         # subset of host_fastpath_batches)
         self.budget_routed_batches = 0  # guarded-by: _stats_lock
@@ -597,6 +606,9 @@ class MicroBatcher:
                 "requests_dispatched": self.requests_dispatched,
                 "deadline_abandoned_batches": self.deadline_abandoned_batches,
                 "host_fastpath_batches": self.host_fastpath_batches,
+                "host_fastpath_declined_batches": (
+                    self.host_fastpath_declined_batches
+                ),
                 "budget_routed_batches": self.budget_routed_batches,
                 "shed_requests": self.shed_requests,
                 "expired_dropped": self.expired_dropped,
@@ -1401,30 +1413,42 @@ class MicroBatcher:
 
     def _launch_batch(self, batch: list[_Pending]) -> None:
         """Hand a formed batch to the pipeline pool (bounded in-flight)."""
-        acquired = False
-        while not acquired:
-            acquired = self._inflight.acquire(timeout=0.05)
-            if not acquired and (self._stopping or self._stop.is_set()):
-                for p in batch:
-                    self._reject_stopping(p)
-                return
+        # a batch that has to wait for a slot is throughput traffic
+        waited = not self._inflight.acquire(blocking=False)
+        if waited:
+            while not self._inflight.acquire(timeout=0.05):
+                if self._stopping or self._stop.is_set():
+                    for p in batch:
+                        self._reject_stopping(p)
+                    return
+        with self._stats_lock:
+            self._batches_inflight += 1
+            # room: it did not wait, and a slot is free beyond its own
+            has_room = (
+                not waited and self._batches_inflight < self._batch_workers
+            )
         try:
-            self._batch_pool.submit(self._process_batch, batch)
+            self._batch_pool.submit(self._process_batch, batch, has_room)
         except RuntimeError:  # pool shut down (stop race)
-            self._inflight.release()
+            self._release_slot()
             for p in batch:
                 self._reject_stopping(p)
 
-    def _process_batch(self, batch: list[_Pending]) -> None:
+    def _release_slot(self) -> None:
+        with self._stats_lock:
+            self._batches_inflight -= 1
+        self._inflight.release()
+
+    def _process_batch(self, batch: list[_Pending], has_room: bool) -> None:
         try:
             # the tenant failpoint scope rides the batch worker thread
             # (tenant-scoped chaos, failpoints.scope)
-            self._scoped(self._dispatch, batch)
+            self._scoped(self._dispatch, batch, has_room)
         except Exception as e:  # noqa: BLE001 — last-resort guard
             for p in batch:
                 self._fail(p, e)
         finally:
-            self._inflight.release()
+            self._release_slot()
 
     # -- batch evaluation --------------------------------------------------
 
@@ -1589,7 +1613,11 @@ class MicroBatcher:
             error=DEADLINE_MESSAGE,
         )
 
-    def _dispatch(self, batch: list[_Pending]) -> None:
+    def _dispatch(
+        self, batch: list[_Pending], has_room: bool = True
+    ) -> None:
+        """One formed batch, phases 1-3. ``has_room``: the pipeline had a
+        slot to spare when the batch was handed to it (_launch_batch)."""
         formed_at = time.perf_counter()
         with self._stats_lock:
             self.batches_dispatched += 1
@@ -1738,7 +1766,7 @@ class MicroBatcher:
         sched = self.scheduler
         if sched is None:
             # single-tenant: no slot gate — the round-15 path, unchanged
-            self._evaluate_runnable(runnable, brec)
+            self._evaluate_runnable(runnable, brec, has_room)
             return
         from policy_server_tpu.runtime import scheduler as _fair
 
@@ -1754,12 +1782,15 @@ class MicroBatcher:
                 self._reject_stopping(p)
             return
         try:
-            self._evaluate_runnable(runnable, brec)
+            self._evaluate_runnable(runnable, brec, has_room)
         finally:
             sched.release(self.tenant)
 
     def _evaluate_runnable(
-        self, runnable: list[_Pending], brec: "_BatchRec | None" = None
+        self,
+        runnable: list[_Pending],
+        brec: "_BatchRec | None" = None,
+        has_room: bool = True,
     ) -> None:
         """Phases 2-3 for a formed batch's runnable rows: degraded-mode
         gate, host/device dispatch under the watchdog, service-layer
@@ -1787,10 +1818,15 @@ class MicroBatcher:
         # (src/lib.rs:176-190, tests/integration_test.rs:417).
         pairs = [(p.policy_id, p.request) for p in runnable]
         # Latency fast-path decision, two tiers:
-        # 1. occupancy: a small batch means the queue was shallow when it
-        #    formed — the requests are latency-critical, not throughput
-        #    traffic — so answer on the host;
-        # 2. budget: for larger batches, compare the
+        # 1. occupancy: a small batch handed to a pipeline with a slot to
+        #    spare is latency-critical, not throughput traffic, so answer
+        #    it on the host. One that waited for a slot or took the last
+        #    is throughput traffic whatever its size (a saturated closed
+        #    loop forms ~60-row batches, PR 40): the host route is Python
+        #    under the GIL, ~5 x the device route's wall time a request
+        #    there, so it rides the device and counts as declined;
+        # 2. budget: for larger batches (every batch at threshold 0: what
+        #    this tier saw before tier 1 could decline), compare the
         #    MEASURED device round-trip estimate against the oldest
         #    item's remaining latency budget; when the device would blow
         #    the budget and the host estimate would not, route host-side.
@@ -1798,11 +1834,10 @@ class MicroBatcher:
         #    reading re-probes the device instead of pinning traffic.
         n = len(runnable)
         bucket = bucket_size(n)
-        use_host = (
-            self._env_fastpath and 0 < n <= self.host_fastpath_threshold
-        )
+        small = self._env_fastpath and 0 < n <= self.host_fastpath_threshold
+        use_host = small and has_room
         if (
-            not use_host
+            not small
             and self._env_fastpath
             and self.latency_budget is not None
             and n > 0
@@ -1828,6 +1863,9 @@ class MicroBatcher:
         if use_host:
             with self._stats_lock:
                 self.host_fastpath_batches += 1
+        elif small:
+            with self._stats_lock:
+                self.host_fastpath_declined_batches += 1
         # RTT samples whose dispatch window traced a NEW columnar plane
         # structure paid a one-time XLA compile (seconds on a multi-device
         # mesh) — snapshot the environment's compile counter so

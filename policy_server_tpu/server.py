@@ -834,6 +834,12 @@ class PolicyServer:
                 bstats["host_fastpath_batches"],
             )
             yield (
+                metrics_names.HOST_FASTPATH_DECLINED_BATCHES, "counter",
+                "Micro-batches small enough for the host fast-path that "
+                "rode the device because the pipeline was full",
+                bstats["host_fastpath_declined_batches"],
+            )
+            yield (
                 metrics_names.HOST_FASTPATH_REQUESTS, "counter",
                 "Requests answered by the host latency fast-path",
                 getattr(environment, "host_fastpath_requests", 0) or 0,

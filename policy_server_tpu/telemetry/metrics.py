@@ -54,6 +54,11 @@ DEADLINE_ABANDONED_BATCHES = "policy_server_deadline_abandoned_batches"
 QUEUE_DEPTH = "policy_server_queue_depth"
 ORACLE_FALLBACKS = "policy_server_oracle_fallbacks"
 HOST_FASTPATH_BATCHES = "policy_server_host_fastpath_batches"
+# batches at or under --host-fastpath-threshold that rode the device
+# because the pipeline had no slot to spare (PR 40)
+HOST_FASTPATH_DECLINED_BATCHES = (
+    "policy_server_host_fastpath_declined_batches"
+)
 HOST_FASTPATH_REQUESTS = "policy_server_host_fastpath_requests"
 DEDUP_BLOB_HITS = "policy_server_dedup_blob_hits"
 DEDUP_BLOB_MISSES = "policy_server_dedup_blob_misses"

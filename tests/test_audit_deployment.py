@@ -562,7 +562,12 @@ def test_the_cell_is_the_issues_letter_for_letter():
 
 
 def test_the_new_entries_come_last_and_share_a_layer():
-    last = MANIFEST["per_layer"][-len(NEW_METRICS):]
+    """Last as PR 38 left the list: what later PRs appended (PR 40's
+    host_declined_batch_share) comes after them, never between."""
+    names = [m["name"] for m in MANIFEST["per_layer"]]
+    at = names.index(NEW_METRICS[0])
+    assert at >= 34  # nothing that was there moved behind them
+    last = MANIFEST["per_layer"][at : at + len(NEW_METRICS)]
     assert tuple(m["name"] for m in last) == NEW_METRICS
     assert len({m["layer"] for m in last}) == 1
     for m in last:

@@ -19,7 +19,7 @@ guarantees (benchmarks/configs/flagship32-defaults.json).
 * the benchmark's data files for the deployment: the manifest is sound,
   the cell is the issue's letter for letter, each of the four new layer
   metrics reads the program's own counters and reads nothing on a program
-  without them.
+  without them; so does PR 40's ``host_declined_batch_share``.
 """
 
 from __future__ import annotations
@@ -27,6 +27,7 @@ from __future__ import annotations
 import json
 import sys
 import threading
+import time
 from pathlib import Path
 
 import pytest
@@ -43,7 +44,7 @@ from policy_server_tpu.models import (
     ValidateRequest,
 )
 from policy_server_tpu.models.policy import parse_policy_entry
-from policy_server_tpu.runtime.batcher import MicroBatcher
+from policy_server_tpu.runtime.batcher import MicroBatcher, bucket_size
 from policy_server_tpu.telemetry import metrics as metrics_mod
 
 # the same 32 policies, built and planted as the cached deployment's tests do
@@ -87,11 +88,12 @@ MIXES = {
     "unique": {"generator": "pod_reviews", "pool_shapes": POOL,
                "arrival": "closed"},
 }
-# a stream of 512 as blocks. The first, over 64 rows and alone, meets a
-# router with no device estimate yet and goes to the device; the middle
+# a stream of 512 as blocks. The first, over 64 rows and alone, goes to
+# the device (the router's estimates are pinned: _batcher); the middle
 # ones are submitted together by every thread, whatever batches they
 # form; the block at 256 (a rollout's next shape), under 64 rows and
-# alone, goes to the host oracle; the rest as the middle.
+# alone in an empty pipeline, goes to the host oracle; the rest as the
+# middle.
 BLOCKS = ((0, 100), (100, 256), (256, 296), (296, 512))
 MIDDLE = (20, 100, 36, 60, 40)  # sizes the threads cut a stretch into
 
@@ -101,10 +103,21 @@ def _batcher(env) -> MicroBatcher:
     defaults = Config()
     assert (defaults.host_fastpath_threshold, defaults.latency_budget_ms,
             defaults.verdict_cache_size) == (64, 50.0, 256 * 1024 * 1024)
-    return MicroBatcher(
+    batcher = MicroBatcher(
         env, max_batch_size=128, batch_timeout_ms=1.0, policy_timeout=60.0,
         host_fastpath_threshold=64, latency_budget_ms=50.0,
-    ).start()
+    )
+    # the router's estimates pinned to an idle machine's and never learnt
+    # again (ROADMAP D15): under a CPU hog the CPU backend's round trip
+    # passed 50 ms, the budget tier kept every large batch on the host,
+    # nothing refreshed the estimate and no later case saw a device
+    # answer. With these a batch over the threshold always goes to the
+    # device: the host's estimate for 65 rows is over what the device's
+    # leaves of the budget
+    batcher._dev_rtt = {bucket_size(n): 1e-3 for n in range(1, 129)}
+    batcher._host_cost_per_row = 1e-4
+    batcher._observe_dispatch = lambda *args, **kwargs: None
+    return batcher.start()
 
 
 def _samples(env, batcher) -> reduce.Samples:
@@ -142,6 +155,13 @@ def _reference_body(traffic: Traffic, policies: dict, n: int) -> bytes:
     ).partition(b"\r\n\r\n")[2]
 
 
+def _wait_until(condition, timeout: float = 60.0) -> None:
+    until = time.perf_counter() + timeout
+    while not condition():
+        assert time.perf_counter() < until, "timed out"
+        time.sleep(0.002)
+
+
 def _serve(env, batcher, policies: dict, stream: str, threads: int,
            base: int) -> dict:
     """Requests ``base .. base + 512`` of the stream through the batcher
@@ -174,7 +194,9 @@ def _serve(env, batcher, policies: dict, stream: str, threads: int,
 
     for nth, (lo, hi) in enumerate(BLOCKS):
         # the even ones alone; each answered before the next is sent, so
-        # that no batch holds rows of two blocks
+        # that no batch holds rows of two blocks, and sent to a pipeline
+        # the batches before have left: a lone small batch has room
+        _wait_until(lambda: batcher._batches_inflight == 0)
         (submit if nth % 2 == 0 else together)(lo, hi)
         for future in futures[lo:hi]:
             future.result(timeout=120)
@@ -224,6 +246,9 @@ def test_every_answer_is_counted_by_exactly_one_source(served):
 
 
 def test_the_device_and_the_host_oracle_both_answered(served):
+    """Whatever the machine's load (ROADMAP D15): the router's estimates
+    are pinned, the block of 100 went out alone and over the threshold,
+    the block of 40 alone and under it into an empty pipeline."""
     moved = served["moved"]
     assert moved["device"] > 0 and moved["host_fastpath"] > 0, moved
     # the block of 40 that went to the host alone: a new pod shape under
@@ -437,6 +462,31 @@ def test_a_new_layer_metric_reads_the_programs_counters(name, want):
     # a program without the phase or the counters (host_eval: the parent)
     # gives nothing to read, and nothing is raised
     assert reduce.read_layer_metric(name, {"before": {}, "after": {}}) is None
+
+
+def test_the_declined_share_reads_the_programs_counter():
+    """PR 40: a data file only, appended after all that was there."""
+    name = "host_declined_batch_share"
+    before, after = _planted({
+        **WINDOW,
+        "policy_server_host_fastpath_declined_batches_total": 840})
+    ctx = {"before": before, "after": after}
+    assert reduce.read_layer_metric(name, ctx) == pytest.approx(35.0)
+    # a program without the counter (the parent) gives nothing to read
+    before, after = _planted(WINDOW)
+    parent = {"before": before, "after": after}
+    assert reduce.read_layer_metric(name, parent) is None
+    assert reduce.read_layer_metric(name, {"before": {}, "after": {}}) is None
+    by_name = {m["name"]: (at, m)
+               for at, m in enumerate(MANIFEST["per_layer"])}
+    at, entry = by_name[name]
+    assert at >= 41  # behind the 41 metrics PR 38 left
+    assert entry == {**by_name["host_batch_share"][1], "name": name,
+                     "better": "higher"}
+    spec = json.loads((BENCH / "layer_metrics" / f"{name}.json").read_text())
+    assert spec["reader"] == "counter_ratio" and "module" not in spec
+    assert spec["numerator"] == metrics_mod.HOST_FASTPATH_DECLINED_BATCHES
+    assert spec["denominator"] == metrics_mod.BATCHES_DISPATCHED
 
 
 def test_the_new_metrics_counters_are_the_programs_own():

@@ -8,13 +8,16 @@ two load-bearing properties:
 1. bit-exactness: ``validate_batch(prefer_host=True)`` must produce
    responses identical to the device path for every verdict shape
    (accept, reject, group causes, mutation);
-2. routing: the MicroBatcher takes the fast-path exactly when batch
-   occupancy is at or below the threshold, and never when disabled.
+2. routing: the MicroBatcher takes the fast-path for a batch at or under
+   the threshold while the pipeline has a slot to spare (PR 40: a small
+   batch that meets a full pipeline is throughput traffic and rides the
+   device), and never when disabled.
 """
 
 from __future__ import annotations
 
 import threading
+import time
 
 import pytest
 
@@ -233,6 +236,260 @@ def test_fastpath_bounded_by_watchdog(env):
         assert b.host_fastpath_batches >= 1  # it WAS the fast-path
     finally:
         env.validate_batch = real
+        b.shutdown()
+
+
+# -- the occupancy rule (PR 40): room in the pipeline, not rows in the batch ----
+
+
+class _Routes:
+    """Stands between a batcher and its two evaluators: notes the route
+    each batch took and, while ``gate`` is clear, holds the batch there,
+    so that it keeps its pipeline slot for as long as a test wants."""
+
+    def __init__(self, b: MicroBatcher, env, monkeypatch):
+        self.gate = threading.Event()
+        self.gate.set()
+        self.took: list[str] = []
+        device, host = b._fused_validate, env.validate_batch
+
+        def fused_validate(pairs):
+            self.took.append("device")
+            self.gate.wait(30)
+            return device(pairs)
+
+        def validate_batch(items, **kw):
+            if kw.get("prefer_host"):
+                self.took.append("host")
+                self.gate.wait(30)
+            return host(items, **kw)
+
+        monkeypatch.setattr(b, "_fused_validate", fused_validate)
+        monkeypatch.setattr(env, "validate_batch", validate_batch)
+
+
+def _small_batch() -> list:
+    from concurrent.futures import Future
+
+    from policy_server_tpu.runtime.batcher import _Pending
+
+    return [
+        _Pending("priv", pod_review("default", privileged),
+                 RequestOrigin.VALIDATE, Future())
+        for privileged in (False, True)
+    ]
+
+
+def _wait_for(condition, timeout: float = 10.0) -> None:
+    until = time.perf_counter() + timeout
+    while not condition():
+        assert time.perf_counter() < until, "timed out"
+        time.sleep(0.005)
+
+
+def _answered(batch: list) -> None:
+    allowed = [p.future.result(timeout=30).allowed for p in batch]
+    assert allowed == [True, False]
+
+
+@pytest.fixture
+def pipeline(env, monkeypatch):
+    """A batcher whose dispatch loop is the test: batches are handed to
+    the four-slot pipeline one by one (``_launch_batch``)."""
+    b = MicroBatcher(env, max_batch_size=8, policy_timeout=30.0,
+                     host_fastpath_threshold=4)
+    routes = _Routes(b, env, monkeypatch)
+    yield b, routes
+    routes.gate.set()
+    b.shutdown()
+
+
+def test_a_small_batch_that_fills_the_pipeline_rides_the_device(pipeline):
+    b, routes = pipeline
+    routes.gate.clear()
+    batches = [_small_batch() for _ in range(4)]
+    for batch in batches[:3]:
+        b._launch_batch(batch)
+    _wait_for(lambda: len(routes.took) == 3)
+    # three found a slot to spare beyond their own: the host's
+    assert routes.took == ["host"] * 3 and b._batches_inflight == 3
+    assert b.host_fastpath_declined_batches == 0
+    b._launch_batch(batches[3])  # every other slot is held
+    _wait_for(lambda: len(routes.took) == 4)
+    assert routes.took[3] == "device"
+    assert b.host_fastpath_declined_batches == 1
+    assert b.host_fastpath_batches == 3
+    routes.gate.set()
+    for batch in batches:
+        _answered(batch)
+    # the same batch once the slots have drained: the host's again
+    _wait_for(lambda: b._batches_inflight == 0)
+    again = _small_batch()
+    b._launch_batch(again)
+    _answered(again)
+    assert routes.took[4] == "host"
+    stats = b.stats_snapshot()
+    assert stats["host_fastpath_batches"] == 4
+    assert stats["host_fastpath_declined_batches"] == 1
+
+
+def test_a_small_batch_that_waited_for_a_slot_rides_the_device(pipeline):
+    b, routes = pipeline
+    routes.gate.clear()
+    batches = [_small_batch() for _ in range(5)]
+    for batch in batches[:4]:
+        b._launch_batch(batch)
+    _wait_for(lambda: len(routes.took) == 4)
+    waiting = threading.Thread(target=b._launch_batch, args=(batches[4],))
+    waiting.start()  # the loop's thread, blocked on the semaphore
+    waiting.join(timeout=0.2)
+    assert waiting.is_alive() and b._batches_inflight == 4
+    routes.gate.set()
+    waiting.join(timeout=30)
+    for batch in batches:
+        _answered(batch)
+    # whatever had drained by the time it got its slot, it had queued
+    # behind a full pipeline: throughput traffic
+    assert routes.took == ["host"] * 3 + ["device"] * 2
+    assert b.host_fastpath_declined_batches == 2
+    _wait_for(lambda: b._batches_inflight == 0)
+
+
+def test_a_batch_that_raises_gives_its_slot_back(pipeline, monkeypatch):
+    b, routes = pipeline
+
+    def broken(batch, has_room):
+        raise RuntimeError("planted")
+
+    monkeypatch.setattr(b, "_dispatch", broken)
+    batches = [_small_batch() for _ in range(6)]  # more than the slots
+    for batch in batches:
+        b._launch_batch(batch)
+        with pytest.raises(RuntimeError, match="planted"):
+            batch[0].future.result(timeout=30)
+    _wait_for(lambda: b._batches_inflight == 0)
+    monkeypatch.undo()  # the evaluators and _dispatch as they were
+    lone = _small_batch()
+    b._launch_batch(lone)
+    _answered(lone)
+    assert b.host_fastpath_batches == 1  # the route was not switched off
+    assert b.host_fastpath_declined_batches == 0
+
+
+def test_a_batch_the_watchdog_abandons_gives_its_slot_back(env, monkeypatch):
+    from policy_server_tpu.runtime.batcher import DEADLINE_MESSAGE
+
+    b = MicroBatcher(env, max_batch_size=8, policy_timeout=0.3,
+                     host_fastpath_threshold=4)
+    routes = _Routes(b, env, monkeypatch)
+    try:
+        routes.gate.clear()
+        batches = [_small_batch() for _ in range(4)]
+        for batch in batches:
+            b._launch_batch(batch)
+        for batch in batches:
+            for p in batch:
+                response = p.future.result(timeout=30)
+                assert response.status.code == 500
+                assert DEADLINE_MESSAGE in response.status.message
+        # the evaluators still hold their pool threads; the slots are back
+        _wait_for(lambda: b._batches_inflight == 0)
+        assert b.deadline_abandoned_batches == 4
+        assert routes.took == ["host"] * 3 + ["device"]
+        routes.gate.set()
+        lone = _small_batch()
+        b._launch_batch(lone)
+        _answered(lone)
+        assert routes.took[4] == "host"
+    finally:
+        routes.gate.set()
+        b.shutdown()
+
+
+def test_threshold_zero_never_counts_a_decline(env, monkeypatch):
+    b = MicroBatcher(env, max_batch_size=8, policy_timeout=30.0,
+                     host_fastpath_threshold=0)
+    routes = _Routes(b, env, monkeypatch)
+    try:
+        routes.gate.clear()
+        batches = [_small_batch() for _ in range(4)]
+        for batch in batches:
+            b._launch_batch(batch)
+        _wait_for(lambda: len(routes.took) == 4)
+        routes.gate.set()
+        for batch in batches:
+            _answered(batch)
+        assert routes.took == ["device"] * 4
+        assert b.host_fastpath_batches == 0
+        assert b.host_fastpath_declined_batches == 0
+    finally:
+        routes.gate.set()
+        b.shutdown()
+
+
+def test_a_declined_batch_is_not_the_budget_tiers(env, monkeypatch):
+    """The budget tier keeps the batches it had, those over the threshold:
+    a small batch the full pipeline sent to the device goes there, even
+    with a device estimate that blows the budget (``declined`` counts
+    batches that rode the device, and ``budget_routed_batches`` stays
+    the batches over the threshold)."""
+    from policy_server_tpu.runtime.batcher import bucket_size
+
+    b = MicroBatcher(env, max_batch_size=8, policy_timeout=None,
+                     host_fastpath_threshold=4, latency_budget_ms=100.0)
+    routes = _Routes(b, env, monkeypatch)
+    try:
+        b._dev_rtt[bucket_size(2)] = 10.0  # the device as slow as can be
+        declined = _small_batch()
+        b._dispatch(declined, has_room=False)
+        _answered(declined)
+        assert b.host_fastpath_declined_batches == 1
+        assert b.budget_routed_batches == 0 and b.host_fastpath_batches == 0
+        lone = _small_batch()
+        b._dispatch(lone, has_room=True)
+        _answered(lone)
+        assert b.host_fastpath_batches == 1 and b.budget_routed_batches == 0
+        assert routes.took == ["device", "host"]
+    finally:
+        b.shutdown()
+
+
+def test_the_inflight_count_under_contention(env):
+    """The loop's thread adds to the count and four batch workers take
+    from it: after 300 small batches pushed through a live loop by more
+    submitters than cores, with the interpreter switching threads forty
+    times as often, no update is lost (the count is back at 0) and every
+    batch was counted once, by the route it took."""
+    import sys
+
+    b = _mk_batcher(env, threshold=64, max_batch_size=4, batch_timeout_ms=0.0)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(interval / 40)
+    try:
+        def submitter():
+            for _ in range(25):
+                b.evaluate("priv", pod_review("default", False),
+                           RequestOrigin.VALIDATE)
+
+        threads = [threading.Thread(target=submitter) for _ in range(12)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+        _wait_for(lambda: b._batches_inflight == 0)
+        stats = b.stats_snapshot()
+        assert stats["requests_dispatched"] == 300
+        assert (stats["host_fastpath_batches"]
+                + stats["host_fastpath_declined_batches"]
+                == stats["batches_dispatched"])
+        # every slot is back: four more can be taken at once, no fifth
+        assert all(b._inflight.acquire(blocking=False) for _ in range(4))
+        assert not b._inflight.acquire(blocking=False)
+        for _ in range(4):
+            b._inflight.release()
+    finally:
+        sys.setswitchinterval(interval)
         b.shutdown()
 
 

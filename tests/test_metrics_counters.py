@@ -140,6 +140,9 @@ def test_dedup_and_pipeline_counters_after_served_batch(server):
     # routing counters exist (0 is fine — no budget pressure here)
     assert "policy_server_budget_routed_batches_total" in m
     assert "policy_server_host_fastpath_batches_total" in m
+    # PR 40: the small batches a full pipeline sent to the device; none
+    # here, three requests one after another never fill four slots
+    assert m["policy_server_host_fastpath_declined_batches_total"] == 0
     # round-7 resilience surface: shedding / deadline drops / breaker /
     # degraded answers / fetch retries all scrape (zero on a healthy
     # server — the chaos suite moves them)
