@@ -2814,6 +2814,7 @@ class EvaluationEnvironment:
         run_hooks: bool = True,
         prefer_host: bool = False,
         audit: bool = False,
+        live: bool = False,
     ) -> list[AdmissionResponse | Exception]:
         """Evaluate many (policy_id, request) pairs in ONE device dispatch.
 
@@ -2839,11 +2840,17 @@ class EvaluationEnvironment:
         answer nobody: what they ship counts as ``audit_rows``, not as
         ``dispatched_rows``, which is how many requests the device
         answered.
+
+        ``live=True`` (the micro-batcher's batches of the front end's
+        requests) keeps the batch off the blob tier, whose key holds a
+        uid the API server mints anew for every webhook call: a live
+        review goes straight to the row tier (verdict_cache.py). The
+        default keeps the blob tier for the replays that can hit it.
         """
         if self._closed:
             raise RuntimeError("environment closed")
         if prefer_host and self.backend == "jax":
-            return self._validate_batch_hostpath(items, run_hooks)
+            return self._validate_batch_hostpath(items, run_hooks, live)
         if (
             self.backend == "jax"
             and self.breaker is not None
@@ -2854,10 +2861,12 @@ class EvaluationEnvironment:
             # probes re-enter through allow_device after the cooldown
             with self._fallback_lock:
                 self._breaker_short_circuited += len(items)
-            return self._validate_batch_hostpath(items, run_hooks)
+            return self._validate_batch_hostpath(items, run_hooks, live)
         if self.backend == "jax":
             # chunks to max_dispatch_batch internally, with pipelining
-            return self._validate_batch_native(items, run_hooks, audit=audit)
+            return self._validate_batch_native(
+                items, run_hooks, audit=audit, live=live
+            )
         # the oracle backend: every row by the host interpreter
         results: list[AdmissionResponse | Exception] = []
         for policy_id, request in items:
@@ -2888,6 +2897,7 @@ class EvaluationEnvironment:
         self,
         items: list[tuple[str, ValidateRequest]],
         run_hooks: bool,
+        live: bool = False,
     ) -> list[AdmissionResponse | Exception]:
         """The latency fast-path: per-item semantics identical to the device
         path (lookup, hooks, wasm routing, context snapshot), but IR
@@ -2905,9 +2915,10 @@ class EvaluationEnvironment:
         with every routing flag at its default, as with the fast path
         off. The breaker's short circuit enters here too and counts the
         same way. The whole item loop is the ``host_eval`` ring phase:
-        one clock pair a batch."""
+        one clock pair a batch. A ``live`` batch's requests go straight
+        to the row tier (validate_batch)."""
         results: list[AdmissionResponse | Exception | None] = [None] * len(items)
-        n_host = 0
+        n_host = n_bypassed = 0
         rec = flightrec.recorder()
         t0 = time.perf_counter_ns() if rec is not None else 0
         for i, (policy_id, request) in enumerate(items):
@@ -2930,8 +2941,9 @@ class EvaluationEnvironment:
                     row, learn = self._tiers.get_one(
                         self._cache_key_of(target),
                         self._blob_of(target, request, payload),
-                        self._packed_row_of,
+                        self._packed_row_of, live,
                     )
+                    n_bypassed += live
                 from_oracle = row is None
                 if from_oracle:
                     row = self._oracle_outputs_for(target, payload)
@@ -2944,6 +2956,8 @@ class EvaluationEnvironment:
         if n_host:
             with self._fallback_lock:
                 self._host_fastpath_requests += n_host
+        if n_bypassed:
+            self._tiers.count_blob_bypassed(n_bypassed)
         if rec is not None:
             rec.record_phase(
                 flightrec.PH_HOST_EVAL, t0, time.perf_counter_ns(),
@@ -2957,14 +2971,16 @@ class EvaluationEnvironment:
         run_hooks: bool,
         defer_sink: list | None = None,
         audit: bool = False,
+        live: bool = False,
     ) -> list[AdmissionResponse | Exception]:
         """The native fast path: JSON bytes → batch arrays in one C++ call
         per shape bucket, rows written in place (no per-request arrays, no
         re-stack). Rows that overflow a bucket cascade to the next; rows
         failing the widest bucket fall back to the host oracle.
 
-        The payload blob is built once per item up front, and the blob
-        tier (verdict_cache.py) answers exact payload replays before any
+        The payload blob is built once per item up front (the encoder's
+        input), and, unless the batch is ``live``, the blob tier
+        (verdict_cache.py) answers exact payload replays before any
         encoding happens. ``defer_sink``: see validate_batch_begin."""
         results: list[AdmissionResponse | Exception | None] = [None] * len(items)
         targets: list[Any] = [None] * len(items)
@@ -3023,8 +3039,10 @@ class EvaluationEnvironment:
             )
 
         # the blob tier: exact payload replays are answered here and never
-        # reach the encoder (wasm-involving targets are uncacheable)
-        if self._tiers is not None and pending:
+        # reach the encoder (wasm-involving targets are uncacheable). A
+        # live batch skips it: a live review's uid, so its blob, is new
+        # on every webhook call (verdict_cache.py module docstring)
+        if self._tiers is not None and pending and not live:
             t0 = time.perf_counter_ns()
             rows = self._tiers.get_blobs(
                 (
@@ -3056,7 +3074,7 @@ class EvaluationEnvironment:
                 break
             pending = self._native_schema_pass(
                 schema, items, targets, results, pending, wasm_infos,
-                blobs, defer_sink, audit,
+                blobs, defer_sink, audit, live,
             )
 
         for i in pending:  # beyond the widest schema → oracle
@@ -3077,12 +3095,14 @@ class EvaluationEnvironment:
         self,
         items: list[tuple[str, ValidateRequest]],
         run_hooks: bool = True,
+        live: bool = False,
     ) -> tuple | None:
         """Host half of the native batch pipeline: lookup, hooks, blob
-        dedup, native encode, row dedup, and the ASYNC device dispatch —
-        everything except blocking on device results. Returns an opaque
-        handle for validate_batch_finish, or None when the native
-        pipeline is unavailable (caller falls back to validate_batch).
+        dedup (not for a ``live`` batch: validate_batch), native encode,
+        row dedup, and the ASYNC device dispatch — everything except
+        blocking on device results. Returns an opaque handle for
+        validate_batch_finish, or None when the native pipeline is
+        unavailable (caller falls back to validate_batch).
 
         The split exists so the micro-batcher can double-buffer: batch
         N+1's host encode (this call, on an encode worker) overlaps batch
@@ -3099,7 +3119,7 @@ class EvaluationEnvironment:
             return None
         deferred: list = []
         results = self._validate_batch_native(
-            items, run_hooks, defer_sink=deferred
+            items, run_hooks, defer_sink=deferred, live=live
         )
         return (results, deferred)
 
@@ -3132,6 +3152,7 @@ class EvaluationEnvironment:
         blobs: list[bytes | None],
         defer_sink: list | None,
         audit: bool = False,
+        live: bool = False,
     ) -> list[int]:
         """Encode+dispatch all ``pending`` rows against one schema, a
         chunk at a time in four steps: encode (_encode_chunk) → plan, who
@@ -3194,7 +3215,7 @@ class EvaluationEnvironment:
                 )
             plan = self._plan_chunk(
                 items, targets, results, chunk, features[PACKED_KEY],
-                ok_mask, wasm_infos, chunk_blobs, _rec, _bid,
+                ok_mask, wasm_infos, chunk_blobs, _rec, _bid, live,
             )
             if not plan.slot_rows:
                 continue  # all overflowed, or answered by the tiers
@@ -3286,11 +3307,12 @@ class EvaluationEnvironment:
         chunk_blobs: list,
         rec: Any,
         bid: int,
+        live: bool = False,
     ) -> DedupTiers.Plan:
         """Step 2: who answers each row of an encoded chunk — with the
         tiers on, their plan (row identity, the locked lookup, slots and
-        keys laid out), its hits answered here; else every row its own
-        slot."""
+        keys laid out; a ``live`` chunk's off the blob tier), its hits
+        answered here; else every row its own slot."""
         tiers = self._tiers
         if tiers is None:
             return DedupTiers.passthrough(ok_mask)
@@ -3310,16 +3332,23 @@ class EvaluationEnvironment:
             dtype=np.intp, count=len(chunk),
         )
         tkeys = [self._cache_key_of(t) for t in distinct.values()]
-        plan = tiers.plan(packed, ok_mask, wasm_pos, tids, tkeys, chunk_blobs)
+        plan = tiers.plan(
+            packed, ok_mask, wasm_pos, tids, tkeys, chunk_blobs, live
+        )
         self._answer_hits(
             items, targets, results,
             ((chunk[pos], row) for pos, row in plan.hits),
         )
-        # ns only: these rows were already counted once by the blob-tier
-        # pre-pass (bookkeeping_rows must mean ROWS, not stage-passes, or
-        # the µs/row denominator doubles)
+        # bookkeeping_rows must mean ROWS, not stage-passes, or the
+        # µs/row denominator doubles: the blob-tier pre-pass counted a
+        # replay's rows already; a live batch has no pre-pass, so its
+        # rows are counted here, where this schema holds them (a row the
+        # ok mask refuses is counted by the schema that takes it)
         t1 = time.perf_counter_ns()
-        self._profile_add(bookkeeping_ns=t1 - t0)
+        self._profile_add(
+            bookkeeping_ns=t1 - t0,
+            bookkeeping_rows=int(ok_mask.sum()) if live else 0,
+        )
         if rec is not None:
             rec.record_phase(
                 flightrec.PH_BOOKKEEPING, t0, t1, rows=len(chunk), batch=bid,

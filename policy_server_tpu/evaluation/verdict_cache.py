@@ -50,6 +50,17 @@ interchangeable for correctness; they differ only in what they can prove
 equal and how early. Lookups go blob tier first (cheaper, earlier),
 then row tier; misses populate both.
 
+Who the blob tier is for: REPLAYS. The payload holds ``request.uid``,
+and the API server mints a fresh uid for every webhook call
+(audit/matrix.py relies on the same fact), so a live admission review's
+blob never recurs and the tier can only miss on it. Live batches (the
+micro-batcher's, on the device route and the host route alike) say
+``live=True``: they neither look the blob tier up nor fill it, and go
+straight to the row tier; ``blob_bypassed_rows`` counts them. What
+re-sends a stored review with its uid keeps the blob tier: the audit
+lane's sweeps, the canary and matrix replays, direct ``validate_batch``
+callers.
+
 Capacity is BYTES, not rows (round-6: the old 4,096-row default was
 smaller than the benchmark's own 12,500-template working set, so the
 cross-batch cache thrashed and the measured dedup was pure in-chunk
@@ -379,6 +390,11 @@ class DedupTiers:
     Keys: blob tier ``(target key, payload blob)``, row tier ``(target
     key, packed row bytes)``.
 
+    The blob tier serves replays only (module docstring): a ``live``
+    lookup, plan or put skips it, since a live review's uid, and so its
+    blob, is new on every webhook call. The row tier sees through the
+    uid and serves both.
+
     Back-fill of the blob tier is BOUNDED: on uid-varying rollout
     traffic nearly every blob never recurs, and a put a row (~4 us) would
     churn the byte-bounded tier out of its genuine exact-replay entries.
@@ -398,6 +414,8 @@ class DedupTiers:
         self._batch_dup_hits = 0  # guarded-by: _lock
         # tier hits the environment answered as pre-built fragments
         self._fragment_hits = 0  # guarded-by: _lock
+        # live rows that would have asked the blob tier and did not
+        self._blob_bypassed = 0  # guarded-by: _lock
 
     # -- before encode: exact payload replays ----------------------------
 
@@ -421,16 +439,21 @@ class DedupTiers:
         target_key: Hashable,
         blob: bytes,
         packed_row_of: Callable[[bytes], "bytes | None"],
+        live: bool = False,
     ) -> "tuple[bytes | Mapping[str, Any] | None, tuple | None]":
         """``(row, None)`` on a hit, else ``(None, keys)`` with what
         ``put_one`` files the evaluated row under. Blob tier first: its
         key is in hand, so an exact replay costs no encode; the row key
         (``packed_row_of``: a single-row encode, None where it cannot be
-        had) is only paid for on a blob miss."""
-        blob_key = (target_key, blob)
-        row = self.blob.get(blob_key)
-        if row is not None:
-            return row, None
+        had) is only paid for on a blob miss. A ``live`` request goes
+        straight to the row tier (class docstring); the caller counts it
+        (``count_blob_bypassed``)."""
+        blob_key = None
+        if not live:
+            blob_key = (target_key, blob)
+            row = self.blob.get(blob_key)
+            if row is not None:
+                return row, None
         row_key = None
         packed = packed_row_of(blob)
         if packed is not None:
@@ -444,7 +467,8 @@ class DedupTiers:
         row_key, blob_key = keys
         if row_key is not None:
             self.row.put(row_key, row)
-        self.blob.put(blob_key, row)
+        if blob_key is not None:
+            self.blob.put(blob_key, row)
 
     # -- after encode: one chunk -----------------------------------------
 
@@ -456,6 +480,7 @@ class DedupTiers:
         target_ids: np.ndarray,
         target_keys: Sequence[Hashable],
         blobs: Sequence[bytes],
+        live: bool = False,
     ) -> ChunkPlan:
         """Lay out one encoded chunk: a (target, row) combo the row tier
         holds is a hit for every row that shows it, in-chunk duplicates
@@ -467,7 +492,8 @@ class DedupTiers:
         no function of its bytes: never deduped or cached, they take the
         first slots of a compacted dispatch. ONE locked lookup and at
         most one locked back-fill; reads and writes the tiers and
-        nothing else."""
+        nothing else. A ``live`` chunk teaches the blob tier nothing and
+        counts its rows as ``blob_bypassed_rows``."""
         n_wasm = len(wasm_pos)
         dedup_pos = np.flatnonzero(ok_mask)
         if n_wasm:
@@ -476,6 +502,7 @@ class DedupTiers:
         slot_rows: list[tuple[int, int]] = []
         row_puts: list = []
         blob_puts: list = []
+        dup_hits = 0
         uncompacted = False
         keep_pos = miss_rows = dedup_pos[:0]
         if dedup_pos.size:
@@ -489,7 +516,7 @@ class DedupTiers:
                 for tid, at in zip(combo_tid.tolist(), combo_first.tolist())
             ]
             hits, hit_flags = self._row_tier_hits(
-                keys, combo_inverse, dedup_pos, blobs
+                keys, combo_inverse, dedup_pos, None if live else blobs
             )
             miss_rows = np.flatnonzero(~hit_flags[combo_inverse])
         if miss_rows.size:
@@ -497,9 +524,6 @@ class DedupTiers:
                 inverse[miss_rows], return_index=True
             )
             dup_hits = int(miss_rows.size - uniq_miss.size)
-            if dup_hits:
-                with self._lock:
-                    self._batch_dup_hits += dup_hits
             keep_pos = dedup_pos[miss_rows[miss_first]]
             # nothing collapsed: ship the encode buffer as it is
             uncompacted = (
@@ -523,13 +547,18 @@ class DedupTiers:
                 (keys[k], slot)
                 for k, slot in zip(miss_combos.tolist(), combo_slots)
             ]
-            blob_puts = [
+            blob_puts = [] if live else [
                 ((target_keys[tid], blobs[pos]), slot)
                 for tid, pos, slot in zip(
                     target_ids[keep_pos].tolist(), keep_pos.tolist(),
                     slot_of[uniq_miss].tolist(),
                 )
             ]
+        bypassed = int(dedup_pos.size) if live else 0
+        if dup_hits or bypassed:
+            with self._lock:
+                self._batch_dup_hits += dup_hits
+                self._blob_bypassed += bypassed
         slot_rows += enumerate(wasm_pos)
         ship_pos = None
         if not uncompacted:
@@ -553,12 +582,13 @@ class DedupTiers:
         keys: list[tuple[Hashable, bytes]],
         combo_inverse: np.ndarray,
         dedup_pos: np.ndarray,
-        blobs: Sequence[bytes],
+        blobs: "Sequence[bytes] | None",
     ) -> tuple[list, np.ndarray]:
         """ONE locked lookup of a chunk's combo keys: ``(position, cached
         row)`` of every row a held combo answers, and which combos were
         held. Counts rows, and back-fills the blob tier with one
-        representative blob a hit combo (class docstring)."""
+        representative blob a hit combo (class docstring); ``blobs``
+        None (a live chunk) back-fills nothing."""
         cached = self.row.get_many(keys)
         hit_flags = np.fromiter(
             (c is not None for c in cached), dtype=bool, count=len(cached)
@@ -578,7 +608,7 @@ class DedupTiers:
             dedup_pos[hit_rows].tolist(), combo_inverse[hit_rows].tolist()
         ):
             hits.append((pos, cached[k]))
-            if k not in backfill:
+            if blobs is not None and k not in backfill:
                 backfill[k] = ((keys[k][0], blobs[pos]), cached[k])
         if backfill:
             self.blob.put_many(backfill.values())
@@ -586,9 +616,10 @@ class DedupTiers:
 
     def learn(self, plan: ChunkPlan, fetched: np.ndarray) -> None:
         """File the fetched output rows of a planned dispatch under both
-        tiers' keys. A dispatched row's entry IS its bytes of the fetched
-        array: one copy of the batch, a slice a slot, the same object
-        under every key of both tiers."""
+        tiers' keys (a live plan's: the row tier's alone). A dispatched
+        row's entry IS its bytes of the fetched array: one copy of the
+        batch, a slice a slot, the same object under every key of both
+        tiers."""
         if not (plan.row_puts or plan.blob_puts):
             return
         width = fetched.shape[1] * fetched.itemsize
@@ -608,6 +639,12 @@ class DedupTiers:
     def count_fragment_hits(self, n: int) -> None:
         with self._lock:
             self._fragment_hits += n
+
+    def count_blob_bypassed(self, n: int) -> None:
+        """Live requests of the host fast path that went straight to the
+        row tier (a live chunk's ``plan`` counts its own)."""
+        with self._lock:
+            self._blob_bypassed += n
 
     @property
     def batch_dup_hits(self) -> int:
@@ -629,6 +666,7 @@ class DedupTiers:
         with self._lock:
             stats["batch_dup_hits"] = self._batch_dup_hits
             stats["fragment_hits"] = self._fragment_hits
+            stats["blob_bypassed_rows"] = self._blob_bypassed
         return stats
 
     @classmethod

@@ -361,6 +361,7 @@ class PolicyShardedEvaluator:
         run_hooks: bool = True,
         prefer_host: bool = False,
         audit: bool = False,
+        live: bool = False,
     ) -> list[AdmissionResponse | Exception]:
         """Partition the batch by owning shard, dispatch every shard's fused
         program, merge in submission order.
@@ -392,7 +393,7 @@ class PolicyShardedEvaluator:
                 shard_items = [items[i] for i in indices]
                 return shards[idx].validate_batch(
                     shard_items, run_hooks=run_hooks,
-                    prefer_host=prefer_host, audit=audit,
+                    prefer_host=prefer_host, audit=audit, live=live,
                 )
 
             if len(per_shard) > 1:

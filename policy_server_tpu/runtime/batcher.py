@@ -1937,7 +1937,7 @@ class MicroBatcher:
                 results = (
                     self._scoped_rec(
                         rec_bid, self.env.validate_batch,
-                        pairs, run_hooks=False, prefer_host=True,
+                        pairs, run_hooks=False, prefer_host=True, live=True,
                     )
                     if use_host
                     else self._scoped_rec(
@@ -1985,6 +1985,7 @@ class MicroBatcher:
                     pairs,
                     run_hooks=False,
                     prefer_host=True,
+                    live=True,
                 )
             else:
                 dev_future = self._device_pool.submit(
@@ -2195,16 +2196,18 @@ class MicroBatcher:
         instead of per-row AdmissionResponse construction. Environments
         without the native split (oracle backend, sharded evaluators,
         tripped breakers declining the pipeline) fall through to plain
-        validate_batch with identical semantics."""
+        validate_batch with identical semantics. Both say ``live``: these
+        are the front end's requests, whose uids are new on every call,
+        so the blob tier is skipped (verdict_cache.py)."""
         with environment.fragment_responses():
             begin_fn = getattr(self.env, "validate_batch_begin", None)
             if begin_fn is not None and getattr(
                 self.env, "native_encoding", False
             ):
-                handle = begin_fn(pairs, run_hooks=False)
+                handle = begin_fn(pairs, run_hooks=False, live=True)
                 if handle is not None:
                     return self.env.validate_batch_finish(handle)
-            return self.env.validate_batch(pairs, run_hooks=False)
+            return self.env.validate_batch(pairs, run_hooks=False, live=True)
 
     def _observe_dispatch(
         self,

@@ -865,6 +865,12 @@ class PolicyServer:
                 dedup.get("blob_cache_misses", 0),
             )
             yield (
+                metrics_names.DEDUP_BLOB_BYPASSED_ROWS, "counter",
+                "Live requests that skipped the blob tier (a fresh uid per "
+                "webhook call) and went straight to the row tier",
+                dedup.get("blob_bypassed_rows", 0),
+            )
+            yield (
                 metrics_names.VERDICT_CACHE_HITS, "counter",
                 "Row-tier verdict cache hits (post-encode, "
                 "uid-insensitive)",

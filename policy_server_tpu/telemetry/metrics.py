@@ -62,6 +62,9 @@ HOST_FASTPATH_DECLINED_BATCHES = (
 HOST_FASTPATH_REQUESTS = "policy_server_host_fastpath_requests"
 DEDUP_BLOB_HITS = "policy_server_dedup_blob_hits"
 DEDUP_BLOB_MISSES = "policy_server_dedup_blob_misses"
+# live requests that went straight to the row tier: a webhook call's uid
+# is new every time, so its blob could only miss (verdict_cache.py)
+DEDUP_BLOB_BYPASSED_ROWS = "policy_server_dedup_blob_bypassed_rows"
 VERDICT_CACHE_HITS = "policy_server_verdict_cache_hits"
 VERDICT_CACHE_MISSES = "policy_server_verdict_cache_misses"
 VERDICT_CACHE_BYTES = "policy_server_verdict_cache_bytes"

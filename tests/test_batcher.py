@@ -393,15 +393,18 @@ def test_budget_routing_keeps_latency_under_budget(env):
         def __getattr__(self, name):
             return getattr(self._inner, name)
 
-        def validate_batch(self, items, run_hooks=True, prefer_host=False):
+        def validate_batch(self, items, run_hooks=True, prefer_host=False,
+                           live=False):
             if prefer_host:
                 self.host_batches += 1
                 return self._inner.validate_batch(
-                    items, run_hooks=run_hooks, prefer_host=True
+                    items, run_hooks=run_hooks, prefer_host=True, live=live
                 )
             self.device_batches += 1
             time.sleep(SLOW_DEVICE_S)
-            return self._inner.validate_batch(items, run_hooks=run_hooks)
+            return self._inner.validate_batch(
+                items, run_hooks=run_hooks, live=live
+            )
 
         def validate_batch_finish(self, handle):
             # the split (double-buffered) pipeline blocks on device
@@ -585,7 +588,7 @@ def test_fragment_lane_serves_hits_with_metrics(env):
             ("ns", pod_review("blocked", privileged=False)),
         ] * 4
         frag_before = env.dedup_stats["fragment_hits"]
-        # wave 1 populates the blob tier (misses), wave 2 hits
+        # wave 1 populates the row tier (misses), wave 2 hits
         for _wave in range(2):
             sink = Sink()
             batcher.submit_many(
@@ -649,7 +652,7 @@ class _GatedEnv:
         self._lock = threading.Lock()
 
     def validate_batch(self, items, run_hooks=True, prefer_host=False,
-                       audit=False):
+                       audit=False, live=False):
         if not audit:
             with self._lock:
                 self.held += 1

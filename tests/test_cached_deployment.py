@@ -14,7 +14,8 @@ guarantees (benchmarks/configs/flagship32-cached.json).
 * a cache of a few KB evicts, ``policy_server_verdict_cache_evictions_total``
   counts it by tier on ``/metrics``, and the answers stay exact;
 * (PR 32) what the device path leaves in the tiers is the packed output
-  row: every entry of both tiers is ``bytes``, a rollout's hits answer
+  row: every entry of the row tier is ``bytes`` (live batches leave the
+  blob tier empty: verdict_cache.py), a rollout's hits answer
   from them through the fragment lane and through ``_materialize``
   (eighteen of the 32 policies are fragment-eligible, fourteen are not),
   256Mi holds 90,000 entries a tier, and
@@ -217,9 +218,10 @@ def test_a_rollout_is_answered_by_the_tiers_and_uniques_by_the_device(
 
 def test_hits_answer_from_packed_entries_through_both_lanes(
         deployment, policies):
-    """PR 32: after a rollout through the batcher every entry of both
-    tiers is the packed row the device returned (80 bytes here), put at
-    the constant-time cost, and the tiers' hits were served from them
+    """After a rollout through the batcher every entry of the
+    row tier is the packed row the device returned (80 bytes here), put
+    at the constant-time cost (the blob tier holds nothing: the batches
+    were live), and the tiers' hits were served from them
     through the fragment lane (eligible targets) AND through
     ``_materialize`` over the packed row (the others), byte for byte as
     the reference answers."""
@@ -244,13 +246,14 @@ def test_hits_answer_from_packed_entries_through_both_lanes(
     assert 0 < frag_hits < tier_hits  # some spliced, some materialized
     width = CONFIG["verdict_bytes_per_row"]["value"]
     assert width == 80 == len(env._out_layout.index)
-    for tier in (env._tiers.row, env._tiers.blob):
-        entries = list(tier._data.items())
-        assert entries
-        assert all(type(row) is bytes and len(row) == width
-                   for _key, row in entries)
-        assert tier.bytes_used == sum(
-            256 + len(key[1]) + width for key, _row in entries)
+    tier = env._tiers.row
+    entries = list(tier._data.items())
+    assert entries
+    assert all(type(row) is bytes and len(row) == width
+               for _key, row in entries)
+    assert tier.bytes_used == sum(
+        256 + len(key[1]) + width for key, _row in entries)
+    assert not env._tiers.blob._data and out["moved"]["blob_tier"] == 0
     # no template hangs on a row: they are in the environment, at most one
     # per target and verdict
     lanes = [lane for lane in env._frag_lanes.values() if lane]
@@ -278,13 +281,18 @@ def test_a_cache_of_a_few_kb_evicts_by_tier_and_stays_exact(policies, stream):
         env.close()
     assert out["got"] == out["want"]
     assert sum(out["moved"].values()) == 512
-    for tier in ("", "blob_"):
-        assert stats[tier + "cache_evictions"] > 0
-        assert stats[tier + "cache_bytes"] <= 8 * 1024
-        assert stats[tier + "cache_entries"] < 8
-        # (a key two batches in flight both missed is put twice)
-        assert stats[tier + "cache_puts"] >= (
-            stats[tier + "cache_evictions"] + stats[tier + "cache_entries"])
+    assert stats["cache_evictions"] > 0
+    assert stats["cache_bytes"] <= 8 * 1024
+    assert stats["cache_entries"] < 8
+    # (a key two batches in flight both missed is put twice)
+    assert stats["cache_puts"] >= (
+        stats["cache_evictions"] + stats["cache_entries"])
+    # live batches neither look the blob tier up nor fill it
+    assert {k: stats["blob_" + k] for k in (
+        "cache_puts", "cache_evictions", "cache_entries", "cache_hits",
+        "cache_misses")} == dict.fromkeys((
+            "cache_puts", "cache_evictions", "cache_entries", "cache_hits",
+            "cache_misses"), 0)
 
 
 @pytest.mark.parametrize("puts, capacity_entries, reputs", [
@@ -305,9 +313,10 @@ def test_the_cache_counts_what_its_byte_bound_pushes_out(
 
 
 def test_the_eviction_counter_is_on_metrics_by_tier():
-    """A served rollout of one pod under new uids: every request after the
-    first hits the row tier and backfills its never-recurring blob, so a
-    small blob tier churns; the layer metric's data file reads the move."""
+    """Forty distinct pods served into a row tier of 1 KiB: each new
+    encoded row is put there, so the row tier churns, and the live
+    requests leave the blob tier alone (verdict_cache.py); the layer
+    metric's data file reads the move."""
     metrics_mod.reset_metrics_for_tests()
     handle = ServerHandle(Config(
         addr="127.0.0.1", port=0, readiness_probe_port=0,
@@ -316,14 +325,14 @@ def test_the_eviction_counter_is_on_metrics_by_tier():
             "priv", {"module": "builtin://pod-privileged"})},
         policy_timeout_seconds=30.0, max_batch_size=8, batch_timeout_ms=1.0,
         host_fastpath_threshold=0, latency_budget_ms=0,
-        verdict_cache_size=16 * 1024, warmup_at_boot=True,
+        verdict_cache_size=2 * 1024, warmup_at_boot=True,
     ))
     try:
         def scrape() -> reduce.Samples:
             r = requests.get(handle.readiness_url("/metrics"), timeout=10)
             return reduce.parse_metrics(r.text)
 
-        traffic = Traffic(MIXES["rollout"], SEED, ["priv"])
+        traffic = Traffic(MIXES["unique"], SEED, ["priv"])
         before = scrape()
         for n in range(40):
             body = traffic.request(n).partition(b"\r\n\r\n")[2]
@@ -340,7 +349,7 @@ def test_the_eviction_counter_is_on_metrics_by_tier():
         for tier in ("blob", "row")}
     assert by_tier == {"blob": stats["blob_cache_evictions"],
                        "row": stats["cache_evictions"]}
-    assert by_tier["blob"] > 0 and by_tier["row"] == 0
+    assert by_tier["row"] > 0 and by_tier["blob"] == 0
     assert reduce.read_layer_metric("cache_evictions_in_window", {
         "before": before, "after": after}) == sum(by_tier.values())
 
@@ -378,9 +387,10 @@ def test_the_put_counters_are_on_metrics_and_read_as_bytes_per_put():
     puts = reduce.delta(before, after, metrics_mod.VERDICT_CACHE_PUTS)
     put_bytes = reduce.delta(before, after, metrics_mod.VERDICT_CACHE_PUT_BYTES)
     # a put a distinct encoded row in the row tier (a pod's name and uid
-    # are no features of this policy), a put a payload in the blob tier
+    # are no features of this policy); none in the blob tier, which live
+    # requests skip
     assert puts == stats["cache_puts"] + stats["blob_cache_puts"]
-    assert 0 < stats["cache_puts"] <= stats["blob_cache_puts"] == 40
+    assert stats["blob_cache_puts"] == 0 < stats["cache_puts"] <= 40
     assert put_bytes == stats["cache_put_bytes"] + stats["blob_cache_put_bytes"]
     # nothing was evicted, so what was put is what is resident
     assert put_bytes == stats["cache_bytes"] + stats["blob_cache_bytes"]

@@ -136,6 +136,8 @@ def _samples(env, batcher) -> reduce.Samples:
             env.breaker_short_circuited_requests,
         "policy_server_deadline_abandoned_batches":
             stats["deadline_abandoned_batches"],
+        "policy_server_dedup_blob_bypassed_rows": dedup["blob_bypassed_rows"],
+        "policy_server_requests_dispatched": stats["requests_dispatched"],
     }
     return {name + "_total": [({}, float(n))] for name, n in have.items()}
 
@@ -243,6 +245,18 @@ def test_every_answer_is_counted_by_exactly_one_source(served):
         CONFIG, served["before"], served["after"], len(served["got"]))
     assert held == {"answered_off_device": 0, "rows_not_dispatched": 0}, (
         served["moved"])
+
+
+def test_every_live_answer_skipped_the_blob_tier(served):
+    """Both routes are live: every request went straight to the row tier
+    (no policy of the set runs wasm), the blob tier answered none, and
+    the benchmark's bypass share reads 100 over the stream."""
+    ctx = {"before": served["before"], "after": served["after"]}
+    assert served["moved"]["blob_tier"] == 0
+    assert reduce.delta(ctx["before"], ctx["after"],
+                        "policy_server_dedup_blob_bypassed_rows") == 512
+    assert reduce.read_layer_metric(
+        "blob_tier_bypassed_share", ctx) == pytest.approx(100.0)
 
 
 def test_the_device_and_the_host_oracle_both_answered(served):

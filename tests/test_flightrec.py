@@ -234,7 +234,8 @@ def test_device_raise_leaves_no_dispatch_event():
     later phase dangles."""
 
     class RaisingEnv(_StubEnvBase):
-        def validate_batch(self, items, run_hooks=True, prefer_host=False):
+        def validate_batch(self, items, run_hooks=True, prefer_host=False,
+                           live=False):
             raise RuntimeError("device fault")
 
     rec = flightrec.install(FlightRecorder(capacity=256))
@@ -266,7 +267,8 @@ def test_device_raise_leaves_no_dispatch_event():
 
 def test_recorder_overhead_on_serving_path():
     class EchoEnv(_StubEnvBase):
-        def validate_batch(self, items, run_hooks=True, prefer_host=False):
+        def validate_batch(self, items, run_hooks=True, prefer_host=False,
+                           live=False):
             return [
                 AdmissionResponse(uid=req.uid(), allowed=True)
                 for _pid, req in items

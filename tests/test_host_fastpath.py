@@ -218,9 +218,11 @@ def test_fastpath_bounded_by_watchdog(env):
 
     real = env.validate_batch
 
-    def slow_validate_batch(items, run_hooks=True, prefer_host=False):
+    def slow_validate_batch(items, run_hooks=True, prefer_host=False,
+                            live=False):
         time.sleep(2.0)  # simulated runaway host-side evaluation
-        return real(items, run_hooks=run_hooks, prefer_host=prefer_host)
+        return real(items, run_hooks=run_hooks, prefer_host=prefer_host,
+                    live=live)
 
     env.validate_batch = slow_validate_batch
     b = _mk_batcher(env, threshold=64, policy_timeout=0.4)

@@ -95,11 +95,12 @@ def test_dedup_and_pipeline_counters_after_served_batch(server):
     r = requests.post(url, data=_review_body("u-1", False),
                       headers=headers, timeout=30)
     assert r.status_code == 200
-    # 2) exact replay (same uid, same payload) → BLOB-tier hit, no encode
+    # 2) exact replay (same uid, same payload): a webhook call is live, so
+    # no blob tier (its uid is new on every real call) → ROW-tier hit
     r = requests.post(url, data=_review_body("u-1", False),
                       headers=headers, timeout=30)
     assert r.status_code == 200
-    # 3) fresh uid, same pod spec → blob miss, ROW-tier hit post-encode
+    # 3) fresh uid, same pod spec → ROW-tier hit post-encode
     r = requests.post(url, data=_review_body("u-2", False),
                       headers=headers, timeout=30)
     assert r.status_code == 200
@@ -108,8 +109,9 @@ def test_dedup_and_pipeline_counters_after_served_batch(server):
     env = server.server.environment
     dedup = env.dedup_stats
     profile = env.host_profile
-    assert dedup["blob_cache_hits"] >= 1
-    assert dedup["cache_hits"] >= 1
+    assert dedup["blob_cache_hits"] == dedup["blob_cache_misses"] == 0
+    assert dedup["blob_bypassed_rows"] == 3
+    assert dedup["cache_hits"] >= 2
 
     m = _scrape(server)
     # two-tier dedup counters, values matching the environment's own
@@ -117,6 +119,10 @@ def test_dedup_and_pipeline_counters_after_served_batch(server):
     assert (
         m["policy_server_dedup_blob_misses_total"]
         == dedup["blob_cache_misses"]
+    )
+    assert (
+        m["policy_server_dedup_blob_bypassed_rows_total"]
+        == dedup["blob_bypassed_rows"]
     )
     assert m["policy_server_verdict_cache_hits_total"] == dedup["cache_hits"]
     assert (
@@ -128,10 +134,10 @@ def test_dedup_and_pipeline_counters_after_served_batch(server):
         m["policy_server_verdict_cache_bytes"]
         == dedup["cache_bytes"] + dedup["blob_cache_bytes"]
     )
-    # host-pipeline decomposition: encode ran for the two misses, the
-    # blob-tier hit skipped it; dispatch shipped at least one row
+    # host-pipeline decomposition: encode ran for all three (no blob
+    # tier before it); dispatch shipped at least one row
     assert m["policy_server_host_encode_rows_total"] == profile["encode_rows"]
-    assert profile["encode_rows"] >= 2
+    assert profile["encode_rows"] >= 3
     assert m["policy_server_dispatched_rows_total"] == profile["dispatched_rows"]
     assert profile["dispatched_rows"] >= 1
     assert m["policy_server_host_encode_seconds_total"] > 0

@@ -51,9 +51,9 @@ def wedge_device_half(env, gate_fn):
         return lambda: setattr(env, "validate_batch_finish", real)
     real = env.validate_batch
 
-    def wrapped(items, run_hooks=True):
+    def wrapped(items, run_hooks=True, live=False):
         gate_fn()
-        return real(items, run_hooks=run_hooks)
+        return real(items, run_hooks=run_hooks, live=live)
 
     env.validate_batch = wrapped
     return lambda: setattr(env, "validate_batch", real)
@@ -128,9 +128,9 @@ def test_cold_bucket_compile_stall_bounded_then_fast(env):
     if env.native_encoding:
         real_begin = env.validate_batch_begin
 
-        def stalling_begin(items, run_hooks=True):
+        def stalling_begin(items, run_hooks=True, live=False):
             gate()
-            return real_begin(items, run_hooks=run_hooks)
+            return real_begin(items, run_hooks=run_hooks, live=live)
 
         env.validate_batch_begin = stalling_begin
         undo = lambda: setattr(env, "validate_batch_begin", real_begin)  # noqa: E731
@@ -159,9 +159,9 @@ def test_timeout_disabled_keeps_unbounded_execution(env):
     a slow device call then completes normally instead of being cut."""
     real = env.validate_batch
 
-    def slow_validate_batch(items, run_hooks=True):
+    def slow_validate_batch(items, run_hooks=True, live=False):
         time.sleep(0.3)
-        return real(items, run_hooks=run_hooks)
+        return real(items, run_hooks=run_hooks, live=live)
 
     env.validate_batch = slow_validate_batch
     batcher = MicroBatcher(
