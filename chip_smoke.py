@@ -495,9 +495,9 @@ def run_audit_stage(
             "batches, "
             f"{moved('policy_server_audit_preemptions'):.0f} handed back")
         require(
-            moved("policy_server_audit_rows_dispatched") >= resident,
+            moved("policy_server_audit_rows_judged") >= resident,
             "the lane counted "
-            f"{moved('policy_server_audit_rows_dispatched'):.0f} rows, the "
+            f"{moved('policy_server_audit_rows_judged'):.0f} rows, the "
             f"reports hold {resident:.0f}",
         )
         require(

@@ -243,8 +243,12 @@ def _flag_specs() -> list[tuple[str, str | None, dict[str, Any]]]:
                    "size). Size it to hold the live (policy, admission "
                    "template) pairs; a tier that outgrows its half "
                    "evicts oldest-first, counted by "
-                   "policy_server_verdict_cache_evictions_total{tier}. 0 "
-                   "disables caching AND in-batch row dedup")),
+                   "policy_server_verdict_cache_evictions_total{tier}. "
+                   "The audit lane's rows (--audit-mode) ask both tiers and "
+                   "fill them as any replay does; a lane row a tier answers "
+                   "answers nobody and is counted as the lane's "
+                   "(policy_server_audit_tier_hits), never as a tier's hit. "
+                   "0 disables caching AND in-batch row dedup")),
         ("--policy-reload-mode", "KUBEWARDEN_POLICY_RELOAD_MODE",
          dict(default="auto", metavar="MODE",
               choices=["off", "auto", "manual"],
@@ -286,7 +290,14 @@ def _flag_specs() -> list[tuple[str, str | None, dict[str, Any]]]:
                    "interpreter with the live threads. Not free: see "
                    "PERF.md (on one v5e chip, the flagship set: about two "
                    "fifths of a 32-caller closed loop's throughput, about a "
-                   "tenth at saturation). "
+                   "tenth at saturation). With the verdict cache on (its "
+                   "default) the lane asks the dedup tiers, and an object's "
+                   "rows under every policy ship as one row; a lane row a "
+                   "tier answers is the lane's and answers nobody "
+                   "(policy_server_audit_tier_hits, by tier in "
+                   "policy_server_audit_tier_hits_by_tier): every lane row "
+                   "is counted in policy_server_audit_rows_judged, the "
+                   "device's in policy_server_audit_rows_dispatched. "
                    "'interval' sweeps the dirty set on a cadence and "
                    "fully on every policy-epoch promotion; 'on-promote' "
                    "sweeps fully on epoch flips only; 'off' disables the "

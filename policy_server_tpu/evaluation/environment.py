@@ -2839,7 +2839,8 @@ class EvaluationEnvironment:
         ``audit=True`` (the batcher's best-effort lane) marks rows that
         answer nobody: what they ship counts as ``audit_rows``, not as
         ``dispatched_rows``, which is how many requests the device
-        answered.
+        answered, and what a dedup tier answers of them counts as the
+        lane's tier hits, not as the tier's (verdict_cache.py).
 
         ``live=True`` (the micro-batcher's batches of the front end's
         requests) keeps the batch off the blob tier, whose key holds a
@@ -3052,6 +3053,7 @@ class EvaluationEnvironment:
                     for i in pending
                 ),
                 (blobs[i] for i in pending),
+                audit,
             )
             self._answer_hits(
                 items, targets, results,
@@ -3215,7 +3217,7 @@ class EvaluationEnvironment:
                 )
             plan = self._plan_chunk(
                 items, targets, results, chunk, features[PACKED_KEY],
-                ok_mask, wasm_infos, chunk_blobs, _rec, _bid, live,
+                ok_mask, wasm_infos, chunk_blobs, _rec, _bid, live, audit,
             )
             if not plan.slot_rows:
                 continue  # all overflowed, or answered by the tiers
@@ -3308,11 +3310,14 @@ class EvaluationEnvironment:
         rec: Any,
         bid: int,
         live: bool = False,
+        audit: bool = False,
     ) -> DedupTiers.Plan:
         """Step 2: who answers each row of an encoded chunk — with the
         tiers on, their plan (row identity, the locked lookup, slots and
         keys laid out; a ``live`` chunk's off the blob tier), its hits
-        answered here; else every row its own slot."""
+        answered here; else every row its own slot. An ``audit`` chunk's
+        plan is the ring phase ``audit_bookkeeping``, so ``bookkeeping``
+        stays the live chunks' alone."""
         tiers = self._tiers
         if tiers is None:
             return DedupTiers.passthrough(ok_mask)
@@ -3333,7 +3338,7 @@ class EvaluationEnvironment:
         )
         tkeys = [self._cache_key_of(t) for t in distinct.values()]
         plan = tiers.plan(
-            packed, ok_mask, wasm_pos, tids, tkeys, chunk_blobs, live
+            packed, ok_mask, wasm_pos, tids, tkeys, chunk_blobs, live, audit
         )
         self._answer_hits(
             items, targets, results,
@@ -3349,7 +3354,12 @@ class EvaluationEnvironment:
             bookkeeping_ns=t1 - t0,
             bookkeeping_rows=int(ok_mask.sum()) if live else 0,
         )
-        if rec is not None:
+        if rec is not None and audit:
+            rec.record_phase(
+                flightrec.PH_AUDIT_BOOKKEEPING, t0, t1, rows=len(chunk),
+                batch=bid,
+            )
+        elif rec is not None:
             rec.record_phase(
                 flightrec.PH_BOOKKEEPING, t0, t1, rows=len(chunk), batch=bid,
             )

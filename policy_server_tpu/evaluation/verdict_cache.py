@@ -61,6 +61,13 @@ re-sends a stored review with its uid keeps the blob tier: the audit
 lane's sweeps, the canary and matrix replays, direct ``validate_batch``
 callers.
 
+Who a hit answers: a tier's hit counters (``cache_hits``,
+``blob_cache_hits``, ``batch_dup_hits``) count answers to callers, one
+source an answer. The audit lane's rows answer nobody, so an ``audit``
+lookup or plan counts its hits apart, by tier (``audit_tier_hits_*``),
+and moves none of those: the lane still asks both tiers and fills them,
+as any replay does.
+
 Capacity is BYTES, not rows (round-6: the old 4,096-row default was
 smaller than the benchmark's own 12,500-template working set, so the
 cross-batch cache thrashed and the measured dedup was pure in-chunk
@@ -102,6 +109,10 @@ _ENTRY_OVERHEAD = 256
 # Per row-dict item: dict slot + boxed Python scalar (keys are interned
 # strings shared across every row of an environment, so not counted).
 _ROW_ITEM_COST = 80
+
+# Where an audit-lane row a tier answered came from: the row tier, the
+# blob tier, or another identical row of its chunk (``audit_tier_hits_*``).
+AUDIT_TIERS = ("row", "blob", "duplicate")
 
 # The uint8 wire form of the rule index -1 ("allowed"): the fused program
 # casts int32 to uint8, which wraps it (rule indices are bounded < 255
@@ -234,11 +245,12 @@ class VerdictCache:
             return row
 
     def get_many(
-        self, keys: Iterable[Hashable | None]
+        self, keys: Iterable[Hashable | None], counted: bool = True
     ) -> "list[bytes | Mapping[str, Any] | None]":
         """Batched get under ONE lock; ``None`` keys pass through as
         ``None`` without counting as misses (callers use them for
-        uncacheable rows to keep index alignment)."""
+        uncacheable rows to keep index alignment). ``counted=False``
+        leaves the hit and miss counters alone (the caller counts)."""
         out: list = []
         with self._lock:
             data = self._data
@@ -254,8 +266,9 @@ class VerdictCache:
                     data.move_to_end(key)
                     hits += 1
                 out.append(row)
-            self.hits += hits
-            self.misses += misses
+            if counted:
+                self.hits += hits
+                self.misses += misses
         return out
 
     def adjust_counts(self, hits: int = 0, misses: int = 0) -> None:
@@ -416,6 +429,8 @@ class DedupTiers:
         self._fragment_hits = 0  # guarded-by: _lock
         # live rows that would have asked the blob tier and did not
         self._blob_bypassed = 0  # guarded-by: _lock
+        # audit-lane rows a tier answered, by tier: the lane's, no answer
+        self._audit_hits = dict.fromkeys(AUDIT_TIERS, 0)  # guarded-by: _lock
 
     # -- before encode: exact payload replays ----------------------------
 
@@ -423,14 +438,22 @@ class DedupTiers:
         self,
         target_keys: Iterable[Hashable | None],
         blobs: Iterable[bytes],
+        audit: bool = False,
     ) -> "list[bytes | Mapping[str, Any] | None]":
         """The blob tier's answer to each (target, blob) of a batch, ONE
         locked lookup; a None target key is an uncacheable row and comes
-        back None, uncounted."""
-        return self.blob.get_many(
-            None if tkey is None else (tkey, blob)
-            for tkey, blob in zip(target_keys, blobs)
+        back None, uncounted. An ``audit`` batch's hits are the lane's
+        (module docstring)."""
+        rows = self.blob.get_many(
+            (
+                None if tkey is None else (tkey, blob)
+                for tkey, blob in zip(target_keys, blobs)
+            ),
+            counted=not audit,
         )
+        if audit:
+            self._count_audit(blob=sum(row is not None for row in rows))
+        return rows
 
     # -- the host fast path: one request ---------------------------------
 
@@ -481,6 +504,7 @@ class DedupTiers:
         target_keys: Sequence[Hashable],
         blobs: Sequence[bytes],
         live: bool = False,
+        audit: bool = False,
     ) -> ChunkPlan:
         """Lay out one encoded chunk: a (target, row) combo the row tier
         holds is a hit for every row that shows it, in-chunk duplicates
@@ -493,7 +517,8 @@ class DedupTiers:
         first slots of a compacted dispatch. ONE locked lookup and at
         most one locked back-fill; reads and writes the tiers and
         nothing else. A ``live`` chunk teaches the blob tier nothing and
-        counts its rows as ``blob_bypassed_rows``."""
+        counts its rows as ``blob_bypassed_rows``; an ``audit`` chunk's
+        row-tier hits and duplicates are the lane's (module docstring)."""
         n_wasm = len(wasm_pos)
         dedup_pos = np.flatnonzero(ok_mask)
         if n_wasm:
@@ -516,7 +541,8 @@ class DedupTiers:
                 for tid, at in zip(combo_tid.tolist(), combo_first.tolist())
             ]
             hits, hit_flags = self._row_tier_hits(
-                keys, combo_inverse, dedup_pos, None if live else blobs
+                keys, combo_inverse, dedup_pos, None if live else blobs,
+                audit,
             )
             miss_rows = np.flatnonzero(~hit_flags[combo_inverse])
         if miss_rows.size:
@@ -555,7 +581,9 @@ class DedupTiers:
                 )
             ]
         bypassed = int(dedup_pos.size) if live else 0
-        if dup_hits or bypassed:
+        if audit:
+            self._count_audit(row=len(hits), duplicate=dup_hits)
+        elif dup_hits or bypassed:
             with self._lock:
                 self._batch_dup_hits += dup_hits
                 self._blob_bypassed += bypassed
@@ -583,25 +611,28 @@ class DedupTiers:
         combo_inverse: np.ndarray,
         dedup_pos: np.ndarray,
         blobs: "Sequence[bytes] | None",
+        audit: bool = False,
     ) -> tuple[list, np.ndarray]:
         """ONE locked lookup of a chunk's combo keys: ``(position, cached
         row)`` of every row a held combo answers, and which combos were
-        held. Counts rows, and back-fills the blob tier with one
-        representative blob a hit combo (class docstring); ``blobs``
-        None (a live chunk) back-fills nothing."""
-        cached = self.row.get_many(keys)
+        held. Counts rows (an ``audit`` chunk's are the caller's to
+        count), and back-fills the blob tier with one representative
+        blob a hit combo (class docstring); ``blobs`` None (a live
+        chunk) back-fills nothing."""
+        cached = self.row.get_many(keys, counted=not audit)
         hit_flags = np.fromiter(
             (c is not None for c in cached), dtype=bool, count=len(cached)
         )
         hit_rows = np.flatnonzero(hit_flags[combo_inverse])
-        # get_many counted one hit/miss per combo KEY; the counters mean
-        # ROWS served from / missed by the row tier
-        n_hit_keys = int(hit_flags.sum())
-        self.row.adjust_counts(
-            hits=int(hit_rows.size) - n_hit_keys,
-            misses=(int(dedup_pos.size) - int(hit_rows.size))
-            - (len(cached) - n_hit_keys),
-        )
+        if not audit:
+            # get_many counted one hit/miss per combo KEY; the counters
+            # mean ROWS served from / missed by the row tier
+            n_hit_keys = int(hit_flags.sum())
+            self.row.adjust_counts(
+                hits=int(hit_rows.size) - n_hit_keys,
+                misses=(int(dedup_pos.size) - int(hit_rows.size))
+                - (len(cached) - n_hit_keys),
+            )
         hits = []
         backfill: dict[int, tuple] = {}  # the first row of each hit combo
         for pos, k in zip(
@@ -646,6 +677,12 @@ class DedupTiers:
         with self._lock:
             self._blob_bypassed += n
 
+    def _count_audit(self, **by_tier: int) -> None:
+        if any(by_tier.values()):
+            with self._lock:
+                for tier, n in by_tier.items():
+                    self._audit_hits[tier] += n
+
     @property
     def batch_dup_hits(self) -> int:
         with self._lock:
@@ -659,7 +696,7 @@ class DedupTiers:
 
     def stats(self) -> dict[str, int]:
         """``cache_*`` keys are the row tier (legacy names), ``blob_*``
-        the blob tier."""
+        the blob tier, ``audit_tier_hits_<tier>`` the lane's hits."""
         stats = self.row.stats()
         for k, v in self.blob.stats().items():
             stats["blob_" + k] = v
@@ -667,6 +704,8 @@ class DedupTiers:
             stats["batch_dup_hits"] = self._batch_dup_hits
             stats["fragment_hits"] = self._fragment_hits
             stats["blob_bypassed_rows"] = self._blob_bypassed
+            for tier, n in self._audit_hits.items():
+                stats["audit_tier_hits_" + tier] = n
         return stats
 
     @classmethod

@@ -447,8 +447,10 @@ class MicroBatcher:
         # -- audit lane counters (round 10; /metrics surface) -------------
         # best-effort audit batches actually dispatched
         self.audit_batches_dispatched = 0  # guarded-by: _stats_lock
-        # rows those batches carried
-        self.audit_rows_dispatched = 0  # guarded-by: _stats_lock
+        # rows those batches carried, every one the environment judged for
+        # the scanner, whoever produced it: the device, a dedup tier, the
+        # host oracle (the device's share is the environment's audit_rows)
+        self.audit_rows_judged = 0  # guarded-by: _stats_lock
         # -- lookup-admission counters (round 23; /metrics surface) -------
         # requests answered from the verdict matrix without dispatch
         self.matrix_lookup_hits = 0  # guarded-by: _stats_lock
@@ -617,7 +619,7 @@ class MicroBatcher:
                 "bulk_submits": self.bulk_submits,
                 "bulk_submitted_rows": self.bulk_submitted_rows,
                 "audit_batches_dispatched": self.audit_batches_dispatched,
-                "audit_rows_dispatched": self.audit_rows_dispatched,
+                "audit_rows_judged": self.audit_rows_judged,
                 "audit_preemptions": self.audit_preemptions,
                 "audit_observe_ns": self.audit_observe_ns,
                 "matrix_lookup_hits": self.matrix_lookup_hits,
@@ -1289,7 +1291,7 @@ class MicroBatcher:
                     return
                 with self._stats_lock:
                     self.audit_batches_dispatched += 1
-                    self.audit_rows_dispatched += len(job.pairs)
+                    self.audit_rows_judged += len(job.pairs)
                 job.future.set_result(results)
             finally:
                 if granted:
@@ -1301,12 +1303,14 @@ class MicroBatcher:
     def _dispatch_audit(self, pairs: list) -> list:
         """One audit batch through the environment: raw verdicts
         (audit-origin semantics: constraints never applied); audit=True —
-        its rows are the lane's to count (audit_rows_dispatched), not a
-        live answer's. Pre-evaluation hooks run as they do for a live
-        request: a hook is not only latency, the signature policy's is
-        what verifies an image, and a row judged without it reports every
-        image no live request had verified yet as unsigned (PR 38: the
-        reports differed from the plain reference exactly there).
+        its rows are the lane's to count (audit_rows_judged; the device's
+        share is the environment's audit_rows, a tier's the lane's tier
+        hits), not a live answer's. Pre-evaluation hooks run as they do
+        for a live request: a hook is not only latency, the signature
+        policy's is what verifies an image, and a row judged without it
+        reports every image no live request had verified yet as unsigned
+        (the reports once differed from the plain reference exactly
+        there).
 
         It goes a live-sized slice at a time, as _audit_slack_ok prices
         it: --audit-batch-size may exceed --max-batch-size, and a launch

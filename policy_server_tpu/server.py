@@ -30,6 +30,7 @@ from policy_server_tpu.evaluation.environment import (
     EvaluationEnvironmentBuilder,
 )
 from policy_server_tpu.evaluation.precompiled import PolicyModule
+from policy_server_tpu.evaluation.verdict_cache import AUDIT_TIERS
 from policy_server_tpu.runtime.batcher import MicroBatcher
 from policy_server_tpu.telemetry import setup_metrics
 from policy_server_tpu.telemetry import metrics as metrics_names
@@ -1082,9 +1083,31 @@ class PolicyServer:
             )
             yield (
                 metrics_names.AUDIT_ROWS_DISPATCHED, "counter",
-                "Rows of the audit-lane batches dispatched: they answer "
-                "no request and count under no answer source",
-                bstats["audit_rows_dispatched"],
+                "Rows the device evaluated for the audit lane: they "
+                "answer no request and count under no answer source",
+                profile.get("audit_rows", 0),
+            )
+            yield (
+                metrics_names.AUDIT_ROWS_JUDGED, "counter",
+                "Audit-lane rows judged for the scanner, whoever produced "
+                "them (device, dedup tier, host oracle)",
+                bstats["audit_rows_judged"],
+            )
+            audit_tiers = [
+                (tier, dedup.get("audit_tier_hits_" + tier, 0))
+                for tier in AUDIT_TIERS
+            ]
+            yield (
+                metrics_names.AUDIT_TIER_HITS, "counter",
+                "Audit-lane rows a dedup tier answered: the lane's, never "
+                "an answer source",
+                sum(n for _tier, n in audit_tiers),
+            )
+            yield (
+                metrics_names.AUDIT_TIER_HITS_BY_TIER, "counter",
+                "Audit-lane rows a dedup tier answered, by tier",
+                [((tier,), n) for tier, n in audit_tiers],
+                ("tier",),
             )
             yield (
                 metrics_names.AUDIT_PREEMPTIONS, "counter",

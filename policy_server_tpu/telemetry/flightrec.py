@@ -102,6 +102,7 @@ PH_MATERIALIZE = "materialize"            # outputs → AdmissionResponse rows
 PH_BOOKKEEPING = "bookkeeping"            # row dedup tiers + slot/LRU bookkeeping
 PH_HOST_EVAL = "host_eval"                # host fast path: a batch's whole item loop
 PH_AUDIT_DISPATCH = "audit_dispatch"      # one audit-lane job, all its slices
+PH_AUDIT_BOOKKEEPING = "audit_bookkeeping"  # the dedup tiers' plan of a lane chunk
 PH_DELIVER = "deliver"                    # phase-3 post-process + completion fan-out
 PH_NATIVE_SERIALIZE = "native_serialize"  # verdict bulk fill to the native frontend
 PH_GC = "gc"                              # one collector pass (GIL held; batch -1)
@@ -137,6 +138,7 @@ PHASES = (
     PH_BOOKKEEPING,
     PH_HOST_EVAL,
     PH_AUDIT_DISPATCH,
+    PH_AUDIT_BOOKKEEPING,
     PH_DELIVER,
     PH_NATIVE_SERIALIZE,
     PH_GC,

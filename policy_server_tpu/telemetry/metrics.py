@@ -113,6 +113,12 @@ AUDIT_SNAPSHOT_BYTES = "policy_server_audit_snapshot_bytes"
 # count (an audit row answers nobody: it is counted here and under no
 # answer source)
 AUDIT_ROWS_DISPATCHED = "policy_server_audit_rows_dispatched"
+# every lane row the environment judged, whoever produced it; of those, the
+# rows a dedup tier answered (the lane's, no answer source: one unlabelled
+# total, and the same by tier, "row" | "blob" | "duplicate")
+AUDIT_ROWS_JUDGED = "policy_server_audit_rows_judged"
+AUDIT_TIER_HITS = "policy_server_audit_tier_hits"
+AUDIT_TIER_HITS_BY_TIER = "policy_server_audit_tier_hits_by_tier"
 AUDIT_OBSERVE_SECONDS = "policy_server_audit_observe_seconds_total"
 AUDIT_SNAPSHOT_EVICTIONS = "policy_server_audit_snapshot_evictions"
 AUDIT_OBJECTS_UNJUDGED = "policy_server_audit_objects_unjudged"

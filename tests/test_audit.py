@@ -221,7 +221,7 @@ def test_audit_lane_dispatches_raw_verdicts_when_idle(env):
         assert results[1].allowed is True
         snap = batcher.stats_snapshot()
         assert snap["audit_batches_dispatched"] == 1
-        assert snap["audit_rows_dispatched"] == 2
+        assert snap["audit_rows_judged"] == 2
         assert batcher.audit_lane_depth() == 0
     finally:
         batcher.shutdown()

@@ -91,6 +91,8 @@ CELL = "flagship32-audit.unique-saturate"
 # asked for beside it by ISSUE 38, measured by the builder, and left out as
 # the issue rules: its runs spread wider than a new cell may (PERF.md §7)
 TRICKLE = "flagship32-audit.unique-trickle"
+# the default install with the scanner on, under that mix: a later cell
+DEFAULTS_CELL = "flagship32-audit-defaults.unique-trickle"
 NEW_METRICS = ("audit_rows_per_live_row", "audit_rows_per_batch",
                "audit_preemptions_per_batch", "audit_dispatch_ms_mean",
                "audit_observe_us_per_req",
@@ -351,7 +353,7 @@ def test_a_live_batch_goes_first_and_the_audit_job_is_handed_back(
         assert order[0] == "live" and order[-1] == "audit"
         stats = batcher.stats_snapshot()
         assert stats["audit_batches_dispatched"] == 1
-        assert stats["audit_rows_dispatched"] == 256
+        assert stats["audit_rows_judged"] == 256
         assert stats["requests_dispatched"] == 32
     finally:
         batcher.shutdown()
@@ -388,7 +390,7 @@ def test_an_audit_job_goes_out_in_live_sized_slices(env, policies):
     assert after["audit_rows"] - before["audit_rows"] == 256 + 96
     # the direct calls above are nobody's audit: they count as dispatched
     assert after["dispatched_rows"] - before["dispatched_rows"] == 256 + 96
-    assert batcher.stats_snapshot()["audit_rows_dispatched"] == 256 + 96
+    assert batcher.stats_snapshot()["audit_rows_judged"] == 256 + 96
 
 
 # -- the lane's phase -----------------------------------------------------------------
@@ -493,7 +495,9 @@ def test_the_file_differs_from_flagship32_in_the_listed_keys_only():
     assert check_manifest.problems(MANIFEST, ROOT) == []
     entry = next(c for c in MANIFEST["configs"]
                  if c["name"] == "flagship32-audit")
-    assert entry is MANIFEST["configs"][-1]
+    # last of the configurations this deployment's cell found; later
+    # ones come after it
+    assert MANIFEST["configs"].index(entry) == 4
     assert entry["file"] == "benchmarks/configs/flagship32-audit.json"
     assert entry["reduced"] == [] and len(entry["source"]) <= 200
     assert entry["source"] == CONFIG["source"] and "Audit Scanner" in (
@@ -526,17 +530,20 @@ def test_the_file_differs_from_flagship32_in_the_listed_keys_only():
 
 
 def test_the_cell_is_the_issues_letter_for_letter():
-    entry = MANIFEST["workloads"][-1]
+    # last of the cells when it was added; later ones come after it
+    entry = MANIFEST["workloads"][6]
     assert {k: entry[k] for k in ("name", "config", "traffic", "chips")} == {
         "name": CELL, "config": "flagship32-audit",
         "traffic": "unique-saturate", "chips": 1}
     assert len(entry["why"]) <= 200
-    assert len(MANIFEST["workloads"]) == 7
+    assert [w["config"] for w in MANIFEST["workloads"]].count(
+        "flagship32-audit") == 1
     assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) == 1
-    # the trickle cell is in no list; its mix waits where unlisted mixes
-    # do, unique-saturate.json with 32 connections and nothing else changed
+    # this deployment's trickle cell is in no list; its mix waits where
+    # unlisted mixes do, unique-saturate.json with 32 connections and
+    # nothing else changed (a copy runs the default install's cell,
+    # flagship32-audit-defaults.unique-trickle)
     assert TRICKLE not in json.dumps(MANIFEST)
-    assert not (BENCH / "traffic" / "unique-trickle.json").exists()
     mixes = {name: json.loads((BENCH / where / f"{name}.json").read_text())
              for where, name in (("pending", "unique-trickle"),
                                  ("traffic", "unique-saturate"))}
@@ -558,7 +565,7 @@ def test_the_cell_is_the_issues_letter_for_letter():
     assert MIXED <= sibling and sibling - MIXED <= reported
     assert not MIXED & reported
     # and the configuration's entry says how much of the lane this cell sees
-    assert "8% of device rows" in MANIFEST["configs"][-1]["why"]
+    assert "8% of device rows" in MANIFEST["configs"][4]["why"]
 
 
 def test_the_new_entries_come_last_and_share_a_layer():
@@ -572,7 +579,8 @@ def test_the_new_entries_come_last_and_share_a_layer():
     assert len({m["layer"] for m in last}) == 1
     for m in last:
         assert m["moves"] == "reviews_per_s"
-        assert m["workloads"] == [CELL]
+        # and the default install's cell, which runs the same lane
+        assert m["workloads"] == [CELL, DEFAULTS_CELL]
         spec = json.loads(
             (BENCH / "layer_metrics" / f"{m['name']}.json").read_text())
         assert spec["reader"] in ("counter_ratio", "counter_delta")

@@ -75,6 +75,10 @@ CELLS = ("flagship32-cached.rollout-saturate",
 # PR 35's cell runs the same tiers under the documented routing defaults
 # and reports their metrics too (tests/test_defaults_deployment.py)
 TIER_CELLS = [*CELLS, "flagship32-defaults.unique-saturate"]
+# and the default install with the scanner on, beside its audit lane, all
+# but the two that would fold the lane's launches in with the live ones
+LANE_CELLS = [*TIER_CELLS, "flagship32-audit-defaults.unique-trickle"]
+NOT_ON_THE_LANE = ("launched_batch_share", "bookkeeping_ms_mean")
 NEW_METRICS = ("device_answered_share", "row_tier_hit_share",
                "batch_duplicate_share", "launched_batch_share",
                "bookkeeping_ms_mean", "cache_evictions_in_window",
@@ -457,7 +461,7 @@ def test_the_manifest_with_the_bytes_per_put_metric_is_sound():
         "source": "program_counter",
         "layer": "dedup tiers in front of the device (evaluation/"
                  "verdict_cache.py, environment.py _native_schema_pass)",
-        "moves": "reviews_per_s", "workloads": TIER_CELLS}
+        "moves": "reviews_per_s", "workloads": LANE_CELLS}
     spec = json.loads(
         (BENCH / "layer_metrics" / "cache_bytes_per_put.json").read_text())
     assert spec["reader"] == "counter_ratio" and "module" not in spec
@@ -562,7 +566,8 @@ def test_a_new_layer_metric_reads_the_programs_counters(name, want):
     assert reduce.read_layer_metric(name, ctx) == pytest.approx(want)
     entry = next(m for m in MANIFEST["per_layer"] if m["name"] == name)
     assert entry["moves"] == "reviews_per_s"
-    assert entry["workloads"] == TIER_CELLS
+    assert entry["workloads"] == (
+        TIER_CELLS if name in NOT_ON_THE_LANE else LANE_CELLS)
     # a program without the counters (the eviction counter: the parent)
     # gives nothing to read, and nothing is raised
     assert reduce.read_layer_metric(name, {"before": {}, "after": {}}) is None

@@ -159,7 +159,8 @@ def _counts(env) -> dict[str, int]:
             "batch_duplicate": dedup["batch_dup_hits"],
             "blob_misses": dedup["blob_cache_misses"],
             "blob_puts": dedup["blob_cache_puts"],
-            "bypassed": dedup["blob_bypassed_rows"]}
+            "bypassed": dedup["blob_bypassed_rows"],
+            "lane_blob": dedup["audit_tier_hits_blob"]}
 
 
 def _bodies(responses) -> list[bytes]:
@@ -244,9 +245,15 @@ def test_a_replay_still_hits_the_blob_tier(envs, replay):
         c2 = _counts(env)
     finally:
         batcher.shutdown()
-    assert c1["blob_misses"] - c0["blob_misses"] == len(items)
+    lane = replay == "audit-lane"
+    # both ask the blob tier and fill it; a lane row answers nobody, so
+    # its lookups are the lane's to count, its hits the lane's tier hits
+    assert c1["blob_misses"] - c0["blob_misses"] == (0 if lane else len(items))
     assert c1["blob_puts"] > c0["blob_puts"]
-    assert c2["blob_tier"] - c1["blob_tier"] >= 1
+    hits = "lane_blob" if lane else "blob_tier"
+    assert c2[hits] - c1[hits] >= 1
+    assert c2["blob_tier" if lane else "lane_blob"] == c0[
+        "blob_tier" if lane else "lane_blob"]
     # a replay is no live request: the bypass counter stays
     assert c2["bypassed"] == c0["bypassed"]
     assert [r.to_dict() for r in second] == [r.to_dict() for r in first]
@@ -281,11 +288,17 @@ def test_the_bypass_share_reads_the_programs_counter():
 
 
 def test_the_bypass_share_comes_last_in_the_tiers_layer():
-    entry = MANIFEST["per_layer"][-1]
+    names = [m["name"] for m in MANIFEST["per_layer"]]
+    # last of the tiers' metrics when it was added; the default install's
+    # scanner metrics, appended later, come after it
+    assert names.index(METRIC) == names.index("audit_tier_answered_share") - 1
+    entry = MANIFEST["per_layer"][names.index(METRIC)]
     tiers = next(m for m in MANIFEST["per_layer"]
                  if m["name"] == "row_tier_hit_share")
+    # the default install with the scanner on reports both, appended
+    cells = [*TIER_CELLS, "flagship32-audit-defaults.unique-trickle"]
     assert entry == {
         "name": METRIC, "unit": "%", "better": "higher",
         "source": "program_counter", "layer": tiers["layer"],
-        "moves": "reviews_per_s", "workloads": TIER_CELLS}
-    assert tiers["workloads"] == TIER_CELLS
+        "moves": "reviews_per_s", "workloads": cells}
+    assert tiers["workloads"] == cells
