@@ -62,6 +62,7 @@ from policy_server_tpu.evaluation.verdict_cache import (
     DedupTiers,
     OutputLayout,
     PackedRow,
+    row_bytes,
 )
 from policy_server_tpu.models import (
     AdmissionResponse,
@@ -111,11 +112,12 @@ WASM_BITS_KEY = "__wasm_bits__"
 DEFAULT_VERDICT_CACHE_SIZE = 256 * 1024 * 1024
 
 
-# -- pre-serialized cache-hit fragments (round 19) ---------------------------
-# A hit row of a fragment-eligible target answers as uid + FragTemplate;
-# the hit loops splice it instead of rebuilding AdmissionResponse rows per
-# hit. The templates live in the environment (_frag_of), one per target
-# and verdict, and not on the cached rows, which are immutable. A target's
+# -- pre-serialized verdict fragments (round 19) -----------------------------
+# An output row of a fragment-eligible target, a tier's hit or a slot the
+# device answered, answers as uid + FragTemplate (_answer_rows): the
+# batcher splices it instead of an AdmissionResponse built per row. The
+# templates live in the environment (_frag_of), one per target and
+# verdict, and not on the rows, which are immutable. A target's
 # memo that outgrows this many verdicts starts again (a group of many
 # members has many member-verdict combinations; traffic shows a handful).
 _FRAG_MEMO_MAX = 4096
@@ -129,8 +131,8 @@ _frag_scope = threading.local()
 
 
 class fragment_responses:
-    """Context manager arming the cache-hit fragment fast lane on this
-    thread (see _frag_of). Entered by the batcher around the fused
+    """Context manager arming the fragment lane on this thread (see
+    _answer_rows). Entered by the batcher around the fused
     encode→device→fetch chain."""
 
     __slots__ = ("_prev",)
@@ -960,6 +962,8 @@ class EvaluationEnvironment:
             "bookkeeping_rows": 0,
             "dispatch_wait_ns": 0,   # blocked in device_get at materialize
             "dispatched_rows": 0,    # unique rows actually shipped
+            # requests a dispatched slot answered as a fragment
+            "device_fragment_rows": 0,
             "audit_rows": 0,         # ... by the audit lane, for nobody
             "dispatched_chunks": 0,
             # -- columnar transport (round 12) ----------------------------
@@ -1364,8 +1368,8 @@ class EvaluationEnvironment:
         bits) is a pure function of (target, row) plus the request uid,
         AND the service layer's post_evaluate constraints are provably
         the identity on it — the conditions under which a pre-built
-        FragTemplate may answer cache hits with zero per-row
-        materialization:
+        FragTemplate may answer its rows (tier hits and dispatched
+        slots alike) with zero per-row materialization:
 
         * protect mode (monitor mode logs + rewrites every response);
         * no mutators and no wasm anywhere in the target (patches and
@@ -1405,13 +1409,13 @@ class EvaluationEnvironment:
     def _frag_of(
         self, target: "BoundPolicy | BoundGroup", row: "bytes | Mapping[str, Any]"
     ) -> "FragTemplate | None":
-        """The FragTemplate of a cached row for ``target`` — built on the
-        FIRST hit of that target with that verdict (one
+        """The FragTemplate of an output row for ``target`` — built on the
+        FIRST row of that target with that verdict (one
         materialize-equivalent pass) and kept in the target's memo, keyed
         by the target's OWN slice of the row: for an eligible target the
         response is a pure function of the outputs its materializer reads
-        (_frag_eligible), so every later hit of any cached row with the
-        same slice costs one lookup, and no cached row is written to.
+        (_frag_eligible), so every later row with the same slice costs
+        one lookup, and no row is written to.
         Packed rows (the device's) and dict rows (the host fast path's)
         are keyed apart — their slices are raw bytes and decoded scalars.
         Dict stores are GIL-atomic and racing builders produce identical
@@ -1480,6 +1484,31 @@ class EvaluationEnvironment:
             # serializes it fine, a raised batch would not
             return False
 
+    def _answer_rows(
+        self,
+        items: list[tuple[str, ValidateRequest]],
+        targets: list[Any],
+        results: list,
+        rows: "Iterable[tuple[int, bytes | Mapping[str, Any]]]",
+    ) -> int:
+        """Answer each ``(item index, output row)``: a tier's hit or a slot
+        the device answered, one rule for both. Under the batcher's
+        fragment scope (round 19) an eligible target's row answers as uid
+        + pre-built template: its responses differ ONLY in uid, so the
+        AdmissionResponse/ValidationStatus construction happens once per
+        target and verdict, not once per row. Every other row
+        materializes. Returns how many answered as fragments."""
+        frag_on = _fragments_enabled()
+        n_frag = 0
+        for i, row in rows:
+            tmpl = self._frag_of(targets[i], row) if frag_on else None
+            if tmpl is not None:
+                results[i] = FragVerdict(items[i][1].uid(), tmpl)
+                n_frag += 1
+            else:
+                results[i] = self._materialize(targets[i], items[i][1], row)
+        return n_frag
+
     def _answer_hits(
         self,
         items: list[tuple[str, ValidateRequest]],
@@ -1487,21 +1516,9 @@ class EvaluationEnvironment:
         results: list,
         hits: "Iterable[tuple[int, bytes | Mapping[str, Any]]]",
     ) -> None:
-        """Answer each ``(item index, cached row)`` a tier served. Under
-        the batcher's fragment scope (round 19) an eligible target's hit
-        answers as uid + pre-built template: its responses differ ONLY in
-        uid, so the AdmissionResponse/ValidationStatus construction
-        happens once per target and verdict, not once per hit. Every
-        other hit materializes from the row."""
-        frag_on = _fragments_enabled()
-        n_frag = 0
-        for i, row in hits:
-            tmpl = self._frag_of(targets[i], row) if frag_on else None
-            if tmpl is not None:
-                results[i] = FragVerdict(items[i][1].uid(), tmpl)
-                n_frag += 1
-            else:
-                results[i] = self._materialize(targets[i], items[i][1], row)
+        """Answer each ``(item index, cached row)`` a tier served
+        (_answer_rows); ``fragment_hits`` counts those spliced."""
+        n_frag = self._answer_rows(items, targets, results, hits)
         if n_frag:
             self._tiers.count_fragment_hits(n_frag)
 
@@ -3439,20 +3456,34 @@ class EvaluationEnvironment:
         bid: int,
     ) -> None:
         """Step 4: block on a launched chunk's fetch, teach the tiers its
-        rows, materialize the responses of the rows that rode it."""
+        rows, answer the requests that rode it. A slot lands as its bytes
+        (one copy of the batch, a slice a slot) and is answered as a
+        tier's hit is (_answer_rows); only a chunk with a wasm stash,
+        whose bits ride beside the rows and are no function of their
+        bytes, is read through the columns of the whole batch."""
         t0 = time.perf_counter_ns()
         raw = np.asarray(fetch.result())
         t1 = time.perf_counter_ns()
-        self._profile_add(dispatch_wait_ns=t1 - t0)
+        rows = row_bytes(raw)
         if self._tiers is not None:
-            self._tiers.learn(plan, raw)
-        outputs = self._unpack(raw)
-        outputs.update(stash)
-        for slot, pos in plan.slot_rows:
-            i = chunk[pos]
-            results[i] = self._materialize(
-                targets[i], items[i][1], _RowView(outputs, slot)
+            self._tiers.learn(plan, rows)
+        n_frag = 0
+        if stash:
+            outputs = self._unpack(raw)
+            outputs.update(stash)
+            for slot, pos in plan.slot_rows:
+                i = chunk[pos]
+                results[i] = self._materialize(
+                    targets[i], items[i][1], _RowView(outputs, slot)
+                )
+        else:
+            n_frag = self._answer_rows(
+                items, targets, results,
+                ((chunk[pos], rows[slot]) for slot, pos in plan.slot_rows),
             )
+        self._profile_add(
+            dispatch_wait_ns=t1 - t0, device_fragment_rows=n_frag
+        )
         if rec is not None:
             t2 = time.perf_counter_ns()
             rec.record_phase(
@@ -3472,8 +3503,9 @@ class EvaluationEnvironment:
         request: ValidateRequest,
         outputs: "bytes | Mapping[str, Any]",
     ) -> AdmissionResponse:
-        """``outputs``: a view of a dispatched row, or a cached row in
-        either of its forms (verdict_cache.py)."""
+        """``outputs``: an output row in either of its forms
+        (verdict_cache.py), the device's bytes or the host oracle's dict,
+        or the view of a dispatched row beside a wasm stash."""
         uid = request.uid()
         outputs = self._row_face(outputs)
         # payload materializes LAZILY: most verdicts (allowed, or rejected

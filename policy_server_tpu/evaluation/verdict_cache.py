@@ -186,6 +186,15 @@ class PackedRow:
         return self[key] if key in self._layout.index else default
 
 
+def row_bytes(fetched: np.ndarray) -> list[bytes]:
+    """A fetched ``[batch, width]`` output array as one ``bytes`` a row:
+    one copy of the batch, a slice a row. What a dispatched slot is
+    answered from and what ``DedupTiers.learn`` files."""
+    width = fetched.shape[1] * fetched.itemsize
+    whole = fetched.tobytes()
+    return [whole[at : at + width] for at in range(0, len(whole), width)]
+
+
 def entry_cost(key: Hashable, row: "bytes | Mapping[str, Any]") -> int:
     """Accounted resident bytes of one cache entry (key + row). Constant
     time for a packed row; one pass over a dict row's items."""
@@ -645,17 +654,12 @@ class DedupTiers:
             self.blob.put_many(backfill.values())
         return hits, hit_flags
 
-    def learn(self, plan: ChunkPlan, fetched: np.ndarray) -> None:
-        """File the fetched output rows of a planned dispatch under both
-        tiers' keys (a live plan's: the row tier's alone). A dispatched
-        row's entry IS its bytes of the fetched array: one copy of the
-        batch, a slice a slot, the same object under every key of both
-        tiers."""
-        if not (plan.row_puts or plan.blob_puts):
-            return
-        width = fetched.shape[1] * fetched.itemsize
-        whole = fetched.tobytes()
-        rows = [whole[at : at + width] for at in range(0, len(whole), width)]
+    def learn(self, plan: ChunkPlan, rows: Sequence[bytes]) -> None:
+        """File the output rows of a planned dispatch (``rows[slot]``, the
+        fetched batch as ``row_bytes`` cuts it) under both tiers' keys (a
+        live plan's: the row tier's alone). A dispatched row's entry IS
+        its slice of the one copy of the batch: the same object under
+        every key of both tiers, and the bytes its answer was made from."""
         if plan.row_puts:
             self.row.put_many(
                 [(key, rows[slot]) for key, slot in plan.row_puts]

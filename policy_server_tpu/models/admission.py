@@ -328,10 +328,11 @@ class FragTemplate:
 
 
 class FragVerdict:
-    """One cache-hit row's verdict: the request uid plus a shared
-    FragTemplate. This is what the environment's blob/row-tier hit
-    loops return (under environment.fragment_responses()) instead of a
-    materialized AdmissionResponse; the batcher's phase 3 recognizes it
+    """One output row's verdict: the request uid plus a shared
+    FragTemplate. This is what the environment answers an eligible
+    target's row with, a tier's hit or a dispatched slot (under
+    environment.fragment_responses()), instead of a materialized
+    AdmissionResponse; the batcher's phase 3 recognizes it
     — metrics from the template fields, constraints skipped (eligibility
     proved them identity) — and the native completion sink splices
     uid + template bytes straight into the bulk verdict record."""

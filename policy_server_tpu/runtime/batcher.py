@@ -2062,7 +2062,7 @@ class MicroBatcher:
                 continue
             try:
                 if type(result) is FragVerdict:
-                    # pre-serialized cache-hit lane (round 19): fragment
+                    # pre-serialized fragment lane (round 19): fragment
                     # eligibility proved the service-layer constraints
                     # are the identity on this shape, so post_evaluate's
                     # per-row object work collapses to one memoized
@@ -2195,9 +2195,10 @@ class MicroBatcher:
         native pipeline's host half (validate_batch_begin) and device
         half (validate_batch_finish) run back-to-back on the SAME
         pipeline thread — no pool hop, no future-wake between them —
-        and the cache-hit fast lane is armed (fragment_responses) so
-        blob/row-tier hits come back as pre-serialized FragVerdicts
-        instead of per-row AdmissionResponse construction. Environments
+        and the fragment lane is armed (fragment_responses) so an
+        eligible target's rows, tier hits and dispatched slots alike,
+        come back as pre-serialized FragVerdicts instead of per-row
+        AdmissionResponse construction. Environments
         without the native split (oracle backend, sharded evaluators,
         tripped breakers declining the pipeline) fall through to plain
         validate_batch with identical semantics. Both say ``live``: these

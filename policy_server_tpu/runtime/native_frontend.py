@@ -991,11 +991,11 @@ def pack_verdict_record(req_id: int, r: Any, raw_shape: bool) -> bytes | None:
 def pack_frag_record(
     req_id: int, frag: Any, raw_shape: bool
 ) -> bytes | None:
-    """pack_verdict_record's cache-hit fast lane: a FragVerdict's
-    template already carries pre-encoded message/cause bytes, so a hit
-    row packs as one header + uid + the template's memoized tail — no
-    per-row string encoding beyond the uid. The tail is cached on the
-    template (native_tail) the first time a hit ships."""
+    """pack_verdict_record's fragment lane: a FragVerdict's template
+    already carries pre-encoded message/cause bytes, so its row packs
+    as one header + uid + the template's memoized tail — no per-row
+    string encoding beyond the uid. The tail is cached on the template
+    (native_tail) the first time it ships."""
     t = frag.tmpl
     try:
         uid_b = frag.uid.encode()

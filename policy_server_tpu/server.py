@@ -966,6 +966,13 @@ class PolicyServer:
                 "Unique rows actually shipped to the device",
                 profile.get("dispatched_rows", 0),
             )
+            yield (
+                metrics_names.DEVICE_FRAGMENT_ROWS, "counter",
+                "Requests a dispatched row answered as a pre-serialized "
+                "fragment (a fragment-eligible target's verdict spliced "
+                "from its template, as a tier hit's is)",
+                profile.get("device_fragment_rows", 0),
+            )
             # Resilience surface (round 7): shedding, deadline drops,
             # breaker state/transitions, degraded answers, fetch retries
             yield (

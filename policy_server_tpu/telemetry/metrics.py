@@ -147,6 +147,9 @@ HOST_ENCODE_MIRROR_ENTRIES = "policy_server_host_encode_mirror_entries"
 HOST_BOOKKEEPING_SECONDS = "policy_server_host_bookkeeping_seconds_total"
 DISPATCH_WAIT_SECONDS = "policy_server_dispatch_wait_seconds_total"
 DISPATCHED_ROWS = "policy_server_dispatched_rows_total"
+# requests a dispatched slot answered as a pre-serialized fragment (an
+# eligible target's row; FRAGMENT_HITS counts tier hits)
+DEVICE_FRAGMENT_ROWS = "policy_server_device_fragment_rows_total"
 # round 12 — array-at-a-time serving path + columnar device transport
 # (runtime/batcher.py submit_many, evaluation/environment.py planes):
 # bulk admission volume, wire bytes shipped vs the packed-transport

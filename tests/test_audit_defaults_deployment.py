@@ -318,7 +318,12 @@ def test_the_manifest_gains_one_configuration_one_cell_three_metrics():
     assert {k: cell[k] for k in ("name", "config", "traffic", "chips")} == {
         "name": CELL, "config": NAME, "traffic": "unique-trickle", "chips": 1}
     assert len(cell["why"]) <= 200
-    last = MANIFEST["per_layer"][-len(NEW_METRICS):]
+    # one block, appended after what the benchmark had (later PRs append
+    # their own metrics after it)
+    names = [m["name"] for m in MANIFEST["per_layer"]]
+    at = names.index(NEW_METRICS[0])
+    assert names[at - 1] == "blob_tier_bypassed_share"
+    last = MANIFEST["per_layer"][at : at + len(NEW_METRICS)]
     assert tuple(m["name"] for m in last) == NEW_METRICS
     for m in last:
         assert m["moves"] == "reviews_per_s" and m["workloads"] == [CELL]

@@ -259,13 +259,32 @@ def test_hits_answer_from_packed_entries_through_both_lanes(
         256 + len(key[1]) + width for key, _row in entries)
     assert not env._tiers.blob._data and out["moved"]["blob_tier"] == 0
     # no template hangs on a row: they are in the environment, at most one
-    # per target and verdict
+    # per target and verdict (the streams before this one filled them too,
+    # with the device's rows of every shape they met)
     lanes = [lane for lane in env._frag_lanes.values() if lane]
-    assert lanes and all(len(memo) <= 4 for lane in lanes for _f, memo in lane)
+    assert lanes
+    for pid in policies:
+        lane = env._frag_lanes.get(id(env._fast_target(pid)))
+        for _face, memo in lane or ():
+            assert len(memo) <= _verdicts(env, pid), pid
     row_keys = [len(key[1]) for key in env._tiers.row._data]
     per_entry = stats["cache_bytes"] / stats["cache_entries"]
     assert per_entry == 256 + row_keys[0] + width
     assert (DEFAULT_VERDICT_CACHE_SIZE // 2) // per_entry >= 90_000
+
+
+def _verdicts(env, pid: str) -> int:
+    """How many responses a target's own outputs can spell: allowed or
+    rejected by one of its rules; a group's, its own flag and each
+    member's evaluated flag and verdict."""
+    group = env._group_mat.get(pid)
+    if group is None:
+        return 1 + len(env._fast_target(pid).precompiled.program.rules)
+    _allowed_key, members, _risky = group
+    n = 2
+    for member in members:
+        n *= 2 * (1 + len(member[1].precompiled.program.rules))
+    return n
 
 
 # -- a cache too small for its traffic -----------------------------------------

@@ -35,7 +35,7 @@ import pytest
 
 from policy_server_tpu.api.service import RequestOrigin
 from policy_server_tpu.evaluation.environment import EvaluationEnvironmentBuilder
-from policy_server_tpu.evaluation.verdict_cache import DedupTiers
+from policy_server_tpu.evaluation.verdict_cache import DedupTiers, row_bytes
 from policy_server_tpu.models import AdmissionReviewResponse
 from policy_server_tpu.runtime.batcher import MicroBatcher
 from policy_server_tpu.telemetry import metrics as metrics_mod
@@ -95,7 +95,7 @@ def test_a_live_plan_is_a_replays_plan_without_the_blob_tier(
     # learn files the row tier alone
     fetched = np.zeros((16, 5), np.uint8)
     puts = tiers.row.puts
-    tiers.learn(live, fetched)
+    tiers.learn(live, row_bytes(fetched))
     assert tiers.row.puts - puts == len(live.row_puts)
     assert tiers.blob.puts == 0 and len(tiers.blob) == 0
 

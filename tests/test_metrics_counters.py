@@ -213,6 +213,44 @@ def test_launch_h2d_arrays_on_the_pull_endpoint(server):
     )
 
 
+def test_device_fragment_rows_on_the_pull_endpoint(server):
+    """A dispatched row of a fragment-eligible target is answered from its
+    template, counted where the benchmark reads it: one more for such a
+    request the device answered, none for a tier's hit (that is
+    ``fragment_hits``), and the family's value is the environment's own
+    count."""
+    env = server.server.environment
+    assert env._frag_eligible(env._fast_target("pod-privileged"))
+
+    def post(uid: str) -> tuple[int, int, int]:
+        before = env.host_profile
+        hits = env.dedup_stats["fragment_hits"]
+        r = requests.post(
+            server.url("/validate/pod-privileged"),
+            data=_review_body(uid, True),
+            headers={"Content-Type": "application/json"}, timeout=30,
+        )
+        assert r.status_code == 200
+        assert r.json()["response"]["uid"] == uid
+        time.sleep(0.1)
+        after = env.host_profile
+        return (
+            after["dispatched_rows"] - before["dispatched_rows"],
+            after["device_fragment_rows"] - before["device_fragment_rows"],
+            env.dedup_stats["fragment_hits"] - hits,
+        )
+
+    env.reset_verdict_cache()
+    assert post("u-frag-1") == (1, 1, 0)  # the device's row, spliced
+    assert post("u-frag-2") == (0, 0, 1)  # the row tier's hit, spliced
+    m = _scrape(server)
+    assert (
+        m[metrics_mod.DEVICE_FRAGMENT_ROWS]
+        == env.host_profile["device_fragment_rows"]
+        >= 1
+    )
+
+
 def test_launch_native_wire_on_the_pull_endpoint(server):
     """The launches whose wire buffer the encode call wrote, counted
     where the benchmark reads them: one more for a launch of a settled

@@ -25,6 +25,7 @@ from policy_server_tpu.evaluation.verdict_cache import (
     PackedRow,
     VerdictCache,
     entry_cost,
+    row_bytes,
 )
 from policy_server_tpu.models import AdmissionReviewRequest, ValidateRequest
 from policy_server_tpu.models.policy import parse_policy_entry
@@ -675,8 +676,8 @@ def test_the_plan_of_a_chunk_against_a_per_row_reference(scenario, targets):
     # what the dispatch will teach: each missed key once, on the slot
     # that carries its bytes; one representative blob a non-wasm slot
     assert sorted(key for key, _slot in plan.row_puts) == sorted(missed_keys)
-    for (_tkey, row_bytes), slot in plan.row_puts:
-        assert shipped[slot].tobytes() == row_bytes
+    for (_tkey, packed_row), slot in plan.row_puts:
+        assert shipped[slot].tobytes() == packed_row
     blob_slots = [slot for _key, slot in plan.blob_puts]
     assert sorted(blob_slots) == sorted(slots - wasm_slots)
     for (tkey, blob), slot in plan.blob_puts:
@@ -695,7 +696,7 @@ def test_the_plan_of_a_chunk_against_a_per_row_reference(scenario, targets):
     # learn, then the same chunk again: every plain row is a hit of the
     # row its slot fetched, both tiers hold the one object
     fetched = np.arange(shipped.shape[0] * 5, dtype=np.uint8).reshape(-1, 5)
-    tiers.learn(plan, fetched)
+    tiers.learn(plan, row_bytes(fetched))
     again = tiers.plan(packed, ok_mask, wasm_pos, tids, tkeys, blobs)
     assert sorted(pos for pos, _row in again.hits) == sorted(
         [*want_hits, *want_miss])
