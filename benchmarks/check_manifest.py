@@ -9,12 +9,17 @@ before it is sent.
 
 from __future__ import annotations
 
+import ast
+import importlib
+import importlib.util
 import json
 import re
 import sys
 from pathlib import Path
+from types import ModuleType
 
-ROOT = Path(__file__).resolve().parent.parent
+HERE = Path(__file__).resolve().parent
+ROOT = HERE.parent
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_.\-/%]{1,16}$")
 PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
@@ -22,6 +27,22 @@ SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 TOP_KEYS = {"command", "paths", "run_seconds", "configs", "workloads",
             "end_to_end", "per_layer"}
 TRAFFIC_SUFFIXES = (".json", ".jsonl", ".toml", ".txt", ".csv")
+# what a configuration's "reference" or a mix's "generator" names: a module
+# under this directory of the benchmark, which defines these names; the
+# benchmark's own (reference.py, traffic.py) where none is named or where a
+# mix names "pod_reviews"
+PLUGINS = {
+    "reference": ("references", ("MODULES", "review_response",
+                                 "http_response")),
+    "generator": ("generators", ("Traffic", "uid_of")),
+}
+OWN = {"reference": (None, "reference"), "generator": ("pod_reviews", "traffic")}
+# what the harness calls on a generator's Traffic, built from (mix, seed,
+# policy ids)
+TRAFFIC_METHODS = ("request", "policy_of", "shape_of")
+PROGRAM = "policy_server_tpu"
+WARM_KEYS = ("why", "pause_phase", "pause_quiet_s", "open_after_pause_s",
+             "chunk_requests")
 
 
 class ManifestError(Exception):
@@ -32,6 +53,119 @@ def _line(value: object) -> bool:
     """1 to 200 printable ASCII characters on one line, no tab."""
     return (isinstance(value, str) and 1 <= len(value) <= 200
             and all(32 <= ord(ch) < 127 for ch in value))
+
+
+def plugin_file(kind: str, name: str | None, bench: Path = HERE) -> Path:
+    """The module a configuration's ``reference`` (``kind`` "reference") or
+    a mix's ``generator`` names, under ``bench``; the benchmark's own
+    beside this file where it names none of its own."""
+    own, module = OWN[kind]
+    if name is None or name == own:
+        return HERE / f"{module}.py"
+    return bench / PLUGINS[kind][0] / f"{name}.py"
+
+
+def _imports(tree: ast.AST) -> list[str]:
+    """Every module a file imports, by statement or by a call with the
+    module's name written in."""
+    out = []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            out += [alias.name for alias in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            out.append(node.module or "")
+        elif (isinstance(node, ast.Call) and node.args
+              and isinstance(node.args[0], ast.Constant)
+              and isinstance(node.args[0].value, str)
+              and getattr(node.func, "attr", getattr(node.func, "id", None))
+              in ("import_module", "__import__")):
+            out.append(node.args[0].value)
+    return out
+
+
+def module_at(file: str | Path) -> ModuleType:
+    """The module in ``file``: the benchmark's own by its import name (this
+    directory is on the path of every process of a run), any other loaded
+    from its file."""
+    file = Path(file).resolve()
+    if file.parent == HERE:
+        return importlib.import_module(file.stem)
+    spec = importlib.util.spec_from_file_location(
+        f"_{file.parent.name}_{file.stem}", file)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def _plugin(kind: str, name: object,
+            bench: Path) -> tuple[ModuleType | None, list[str]]:
+    """The module a name resolves to, or why it cannot stand in the
+    yardstick: it is missing, imports anything of the program, or lacks a
+    name the harness calls (for a generator's ``Traffic`` also a method;
+    the judge reads its ``reviews`` and ``policy_ids`` as well)."""
+    if name is not None and not (isinstance(name, str) and NAME.match(name)):
+        return None, [f"{kind} {name!r} must match {NAME.pattern}"]
+    file = plugin_file(kind, name, bench)
+    if not file.is_file():
+        return None, [f"{kind} {name!r}: no module "
+                      f"{PLUGINS[kind][0]}/{name}.py"]
+    try:
+        tree = ast.parse(file.read_text(encoding="utf-8"), str(file))
+    except SyntaxError as e:
+        return None, [f"{kind} {name!r} does not parse: {e}"]
+    bad = [f"{kind} {name!r} imports {module}: the yardstick imports "
+           "nothing of the program"
+           for module in _imports(tree)
+           if module == PROGRAM or module.startswith(PROGRAM + ".")]
+    if bad:
+        return None, bad
+    try:
+        module = module_at(file)
+    except Exception as e:  # noqa: BLE001 - any failure is the module's
+        return None, [f"{kind} {name!r} cannot be loaded: {e!r}"]
+    bad = [f"{kind} {name!r} defines no {want}"
+           for want in PLUGINS[kind][1] if not hasattr(module, want)]
+    if kind == "generator" and not bad:
+        bad = [f"generator {name!r}: its Traffic has no {want}"
+               for want in TRAFFIC_METHODS
+               if not callable(getattr(module.Traffic, want, None))]
+    return (None if bad else module), bad
+
+
+def plugin_problems(kind: str, name: object, bench: Path = HERE) -> list[str]:
+    """Why the module a configuration's ``reference`` or a mix's
+    ``generator`` names cannot stand in the yardstick; empty when it can."""
+    return _plugin(kind, name, bench)[1]
+
+
+def plugin(kind: str, name: str | None, bench: Path = HERE) -> ModuleType:
+    """The checked module a configuration's ``reference`` or a mix's
+    ``generator`` names; raises ManifestError with every reason."""
+    module, bad = _plugin(kind, name, bench)
+    if bad:
+        raise ManifestError("; ".join(bad))
+    return module
+
+
+def warm_problems(doc: object) -> list[str]:
+    """A mix's warm state (``warm/<mix>.json``, read by ``run.py
+    Rig._until_paused``): the ring phase of the server's background work
+    whose pause the window waits for, how long a pause is, how long after
+    its start the window may open, and the chunk of traffic sent
+    meanwhile."""
+    if not isinstance(doc, dict) or set(doc) != set(WARM_KEYS):
+        return [f"a warm state holds {list(WARM_KEYS)}"]
+    bad = []
+    if not (isinstance(doc["pause_phase"], str)
+            and NAME.match(doc["pause_phase"])):
+        bad.append("warm state: pause_phase must be a phase's name")
+    if not (isinstance(doc["chunk_requests"], int)
+            and doc["chunk_requests"] > 0):
+        bad.append("warm state: chunk_requests must be a whole number over 0")
+    bad += [f"warm state: {key} must be seconds over 0"
+            for key in ("pause_quiet_s", "open_after_pause_s")
+            if not (isinstance(doc[key], (int, float)) and doc[key] > 0)]
+    return bad
 
 
 def answers_from_problems(doc: dict, bench: Path) -> list[str]:
@@ -126,6 +260,10 @@ def problems(manifest: dict, root: Path = ROOT) -> list[str]:
                            "differs from the manifest's")
             bad += [f"config {c.get('name')}: {reason}" for reason in
                     answers_from_problems(doc, root / paths[0])]
+            if "reference" in doc:
+                bad += [f"config {c.get('name')}: {reason}" for reason in
+                        plugin_problems("reference", doc["reference"],
+                                        root / paths[0])]
         files.add(file)
         reduced = c.get("reduced")
         if not isinstance(reduced, list) or len(reduced) > 16:
@@ -164,6 +302,16 @@ def problems(manifest: dict, root: Path = ROOT) -> list[str]:
                    for suffix in TRAFFIC_SUFFIXES):
             bad.append(f"workload {w.get('name')}: no traffic file "
                        f"{paths[0]}/traffic/{w.get('traffic')}.json")
+        mix = root / paths[0] / "traffic" / f"{w.get('traffic')}.json"
+        if mix.is_file():
+            generator = json.loads(mix.read_text(encoding="utf-8")).get(
+                "generator")
+            bad += [f"workload {w.get('name')}: {reason}" for reason in
+                    plugin_problems("generator", generator, root / paths[0])]
+        warm = root / paths[0] / "warm" / f"{w.get('traffic')}.json"
+        if warm.is_file():
+            bad += [f"workload {w.get('name')}: {reason}" for reason in
+                    warm_problems(json.loads(warm.read_text(encoding="utf-8")))]
     if not 1 <= len(cells) <= 24:
         bad.append("1 to 24 workloads")
     four = sum(1 for w in cells.values() if w.get("chips") == 4)

@@ -8,7 +8,8 @@ sends its slice: request numbers ``base + k, base + k + K, ...``.
 
 The parent speaks lines on stdin; each gets one answer on stdout:
 
-* first line: the JSON spec (port, cafile, seed, mix, policy ids, k, K);
+* first line: the JSON spec (port, cafile, seed, mix, the file of its
+  generator, policy ids, k, K);
   answered ``pooled`` once the pool is drawn and serialised;
 * ``connect`` (once the server listens): ``ready`` once the first
   connections are open;
@@ -45,7 +46,7 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
-from traffic import Traffic  # noqa: E402
+import check_manifest  # noqa: E402
 
 _LENGTH = re.compile(rb"\r\ncontent-length:\s*(\d+)", re.IGNORECASE)
 now = time.monotonic
@@ -120,7 +121,8 @@ class Runner:
         self.spec = spec
         self.mix = spec["mix"]
         self.k, self.K = spec["k"], spec["K"]
-        self.traffic = Traffic(self.mix, spec["seed"], spec["policy_ids"])
+        self.traffic = check_manifest.module_at(spec["generator"]).Traffic(
+            self.mix, spec["seed"], spec["policy_ids"])
         self.traffic.request(0)  # serialise the pool now, not in a window
         self.tls = None
         if spec.get("cafile"):

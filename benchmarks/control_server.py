@@ -30,14 +30,15 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
-import reference  # noqa: E402
+import check_manifest  # noqa: E402
 
 FAULTS = ("first-container", "stale-uid", "alter-answer", "none")
 _LENGTH = re.compile(rb"\r\ncontent-length:\s*(\d+)", re.IGNORECASE)
 
 
 class Control:
-    def __init__(self, config: dict, fault: str) -> None:
+    def __init__(self, config: dict, fault: str, reference) -> None:
+        self.reference = reference
         self.policies = config["policies"]
         self.signed = set(config["signing"]["signed_images"])
         self.head = config["response_head"]
@@ -56,12 +57,12 @@ class Control:
                    request["namespace"])
             if key in self.cache:
                 return self.cache[key]
-        response = reference.review_response(
+        response = self.reference.review_response(
             self.policies[policy_id], request, self.signed)
         self.count += 1
         if self.fault == "alter-answer" and self.count % 64 == 0:
             response = dict(response, allowed=not response["allowed"])
-        raw = reference.http_response(self.head, uid, response)
+        raw = self.reference.http_response(self.head, uid, response)
         if self.fault == "stale-uid":
             self.cache[key] = raw
         return raw
@@ -93,7 +94,8 @@ async def _ready(reader: asyncio.StreamReader,
 
 async def serve(args: argparse.Namespace) -> None:
     config = json.loads(Path(args.config).read_text(encoding="utf-8"))
-    control = Control(config, args.fault)
+    control = Control(config, args.fault,
+                      check_manifest.module_at(args.reference))
     tls = ssl.create_default_context(ssl.Purpose.CLIENT_AUTH)
     tls.load_cert_chain(args.cert_file, args.key_file)
     api = await asyncio.start_server(
@@ -107,6 +109,8 @@ async def serve(args: argparse.Namespace) -> None:
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--config", required=True)
+    ap.add_argument("--reference", required=True,
+                    help="the configuration's plain reference module")
     ap.add_argument("--break", dest="fault", choices=FAULTS, required=True)
     ap.add_argument("--policies")  # the run's filled-in file; not needed
     ap.add_argument("--cert-file", required=True)

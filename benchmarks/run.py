@@ -12,8 +12,12 @@ traffic from ``--seed``; boots the server under test through
 ``benchmarks/serve.py``; requires the platform and the chip count the cell
 asks for (``--platform cpu`` is an explicit rehearsal and says so on an
 earlier line — a missing chip is a failure, never a fallback); warms until
-a whole pass compiled nothing; drives the window; frees the chip; and only
-then runs the plain reference over every answer of the window.
+a whole pass compiled nothing (and, where the mix has a warm state, until
+the server's background work stands where it says); drives the window;
+frees the chip; and only then runs the plain reference over every answer of
+the window. A configuration may
+name its own plain reference and a mix its own generator
+(``check_manifest.plugin``).
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1``
@@ -47,6 +51,7 @@ import threading  # noqa: E402
 import urllib.error  # noqa: E402
 import urllib.request  # noqa: E402
 from pathlib import Path  # noqa: E402
+from types import ModuleType  # noqa: E402
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
@@ -57,13 +62,17 @@ import numpy as np  # noqa: E402
 import check_manifest  # noqa: E402
 import host_spans  # noqa: E402
 import reduce  # noqa: E402
-import reference  # noqa: E402
-from traffic import Traffic, uid_of  # noqa: E402
 
 COMPILE_COUNTERS = (
     "policy_server_xla_programs_compiled",
     "policy_server_plane_program_compiles",
 )
+# how often a warm state's pause is looked for through the warm traffic,
+# each look reaching this far back into the previous one; and how long it
+# may take, well inside a run's 360 s
+PAUSE_POLL_S = 0.5
+PAUSE_OVERLAP_NS = 200_000_000
+PAUSE_WAIT_S = 150.0
 # in-band answers under HTTP 200 that are a request failed, not a verdict
 FAILED_CODES = (429, 500, 503, 504)
 IMAGE_SIGNATURE_TYPE = "cosign container image signature"
@@ -233,6 +242,59 @@ class Clients:
         self._log.close()
 
 
+class Pauses(threading.Thread):
+    """Reads the flight recorder's ring every ``PAUSE_POLL_S`` and finds
+    the first pause of a phase of the server's background work that began
+    ``back_s`` before the watch started or later: ``quiet_s`` or more from
+    the end of one of its stamps to the start of the next. ``began`` is
+    when the pause began, on the ring's clock (``time.monotonic``); a pause
+    is known once the phase is stamped again, so a long stamp is never
+    taken for one. The ring answers a short interval with a few hundred
+    events, where the exposition of ``/metrics`` is megabytes."""
+
+    def __init__(self, server: Server, phase: str, quiet_s: float,
+                 back_s: float) -> None:
+        super().__init__(name="benchmark-pauses", daemon=True)
+        self.server, self.phase, self.quiet_ns = server, phase, quiet_s * 1e9
+        self.began: float | None = None
+        self.error: Exception | None = None
+        self._last_end: float | None = None  # of the phase's latest stamp
+        self._since = time.monotonic_ns() - int(back_s * 1e9)
+        self._over = threading.Event()
+
+    def look(self) -> None:
+        until = time.monotonic_ns()
+        stamps = sorted(
+            (start, end) for name, start, end, _batch in host_spans.ring(
+                host_spans.fetch_timeline(self.server.ready_port,
+                                          self._since, until))
+            if name == self.phase)
+        self._since = until - PAUSE_OVERLAP_NS  # a stamp written late
+        for start, end in stamps:
+            if (self._last_end is not None
+                    and start - self._last_end >= self.quiet_ns):
+                self.began = self._last_end / 1e9
+                return
+            self._last_end = max(self._last_end or end, end)
+
+    def run(self) -> None:
+        try:
+            while self.began is None and not self._over.wait(PAUSE_POLL_S):
+                self.look()
+        except Exception as e:  # noqa: BLE001 - the supervisor reports it
+            self.error = e
+
+    def age(self) -> float | None:
+        """Seconds since the first pause began; None before one was seen."""
+        if self.error is not None:
+            raise RunFailure(f"reading the ring: {self.error}")
+        return None if self.began is None else time.monotonic() - self.began
+
+    def stop(self) -> None:
+        self._over.set()
+        self.join()
+
+
 class Sleeper(threading.Thread):
     """Sleeps 10 ms at a time through a window and keeps the longest it
     overslept. The supervisor does nothing else meanwhile, so what it
@@ -325,9 +387,12 @@ def fill(value, marks: dict[str, str]):
 # -- judging --------------------------------------------------------------------
 
 
-def judge_answers(records: list[tuple], traffic: Traffic, policies: dict,
+def judge_answers(records: list[tuple], generator: ModuleType,
+                  traffic: object, reference: ModuleType, policies: dict,
                   signed: set, head: list[str]) -> dict[str, int]:
-    """Every answer of the window against the plain reference."""
+    """Every answer of the window against the plain reference: the
+    configuration's (``reference.py`` where it names none), over the
+    requests of the mix's generator (``traffic.py`` for ``pod_reviews``)."""
     entries = [policies[pid] for pid in traffic.policy_ids]
     unanswered = inband = mismatched = 0
     first = None
@@ -337,7 +402,7 @@ def judge_answers(records: list[tuple], traffic: Traffic, policies: dict,
             continue
         request = traffic.reviews[traffic.shape_of(n)]["request"]
         want = reference.http_response(
-            head, uid_of(n),
+            head, generator.uid_of(n),
             reference.review_response(entries[traffic.policy_of(n)],
                                       request, signed))
         if raw == want:
@@ -395,6 +460,12 @@ class Rig:
                           if c["name"] == self.cell["config"])
         self.config = load_json(ROOT / self.entry["file"])
         self.mix = load_json(HERE / "traffic" / f"{self.cell['traffic']}.json")
+        warm = HERE / "warm" / f"{self.cell['traffic']}.json"
+        self.warm_state = load_json(warm) if warm.is_file() else {}
+        self.generator = check_manifest.plugin(
+            "generator", self.mix.get("generator"))
+        self.reference = check_manifest.plugin(
+            "reference", self.config.get("reference"))
         self.rehearsal = args.platform == "cpu"
         self.info: dict[str, str] = {}
         self.sent = 0  # request numbers used so far
@@ -426,6 +497,7 @@ class Rig:
         if args.control:
             command = [sys.executable, str(HERE / "control_server.py"),
                        "--config", str(ROOT / self.entry["file"]),
+                       "--reference", self.reference.__file__,
                        "--break", args.control, *common]
         else:
             command = [sys.executable, str(HERE / "serve.py"),
@@ -436,6 +508,7 @@ class Rig:
         self.clients = Clients({
             "port": self.server.api_port, "cafile": str(cert),
             "seed": args.seed, "mix": self.mix,
+            "generator": self.generator.__file__,
             "policy_ids": self.policy_ids,
         }, int(self.mix["client_processes"]), work / "clients.log")
         self.children.append(self.clients)
@@ -460,14 +533,16 @@ class Rig:
         self._warm(boot)
 
     def _warm(self, snap: reduce.Samples) -> None:
-        """Passes of the cell's own traffic until one compiled nothing."""
+        """Passes of the cell's own traffic until one compiled nothing.
+
+        A mix may have a warm state, ``warm/<mix>.json``, for a server whose
+        background work comes in rounds with pauses between them: traffic
+        then goes on after the passes until the window can open at the same
+        point of a round (``_until_paused``). Every other mix warms as it
+        always did."""
         args, count = self.args, int(self.mix["warm_requests"])
         for nth in range(args.warm_passes):
-            done, failed = self.clients.warm(self.sent, count)
-            self.sent += count
-            if failed:
-                raise RunFailure(
-                    f"{failed} of {done} warm requests got no answer")
+            self._send(count)
             if args.control:
                 return
             deadline = time.monotonic() + args.ready_timeout
@@ -484,9 +559,42 @@ class Rig:
                 reduce.delta(snap, after, c) for c in COMPILE_COUNTERS)
             snap = after
             if compiled == 0 and nth > 0:
-                return
-        raise RunFailure(f"{args.warm_passes} warm passes and the last "
-                         "still compiled a program")
+                break
+        else:
+            raise RunFailure(f"{args.warm_passes} warm passes and the last "
+                             "still compiled a program")
+        if self.warm_state:
+            self._until_paused(self.warm_state)
+
+    def _until_paused(self, state: dict) -> None:
+        """Chunks of ``chunk_requests`` warm requests until a pause of the
+        ring phase ``pause_phase`` (``Pauses``) that began no earlier than
+        ``open_after_pause_s`` before the passes ended began that long
+        ago."""
+        after_s = float(state["open_after_pause_s"])
+        pauses = Pauses(self.server, state["pause_phase"],
+                        float(state["pause_quiet_s"]), after_s)
+        pauses.start()
+        try:
+            deadline = time.monotonic() + PAUSE_WAIT_S
+            chunks = 0
+            while (age := pauses.age()) is None or age < after_s:
+                if time.monotonic() > deadline:
+                    raise RunFailure(f"no pause of {pauses.phase} in "
+                                     f"{PAUSE_WAIT_S:.0f}s of warm traffic")
+                self._send(int(state["chunk_requests"]))
+                chunks += 1
+        finally:
+            pauses.stop()
+        say(f"warm: {self.sent} requests, {chunks} chunks after the passes; "
+            f"{pauses.phase} paused {age:.2f} s ago")
+
+    def _send(self, count: int) -> None:
+        done, failed = self.clients.warm(self.sent, count)
+        self.sent += count
+        if failed:
+            raise RunFailure(
+                f"{failed} of {done} warm requests got no answer")
 
     def window(self, seconds: float, trace: bool = False,
                rate: float | None = None) -> dict:
@@ -576,11 +684,11 @@ def run(args: argparse.Namespace, rig: Rig) -> dict:
     manifest, cell, config = rig.manifest, rig.cell, rig.config
     records, rec, stats = win["records"], win["rec"], win["stats"]
     before, after, traced = win["before"], win["after"], win["traced"]
-    traffic = Traffic(rig.mix, args.seed, rig.policy_ids)
+    traffic = rig.generator.Traffic(rig.mix, args.seed, rig.policy_ids)
     t_ref = time.monotonic()
     compared = {
         name: [value, 0] for name, value in judge_answers(
-            records, traffic, rig.policies,
+            records, rig.generator, traffic, rig.reference, rig.policies,
             set(config["signing"]["signed_images"]),
             config["response_head"]).items()
     }

@@ -169,6 +169,17 @@ def _in_config_file(path: list, value):
     return edit
 
 
+def _four_chips_over_half():
+    """One cell more on four chips than half of the cells, rounded down:
+    the first ones, whatever the manifest's count and its own four-chip
+    cells."""
+    def edit(m: dict, _root: Path | None = None) -> None:
+        cells = m["workloads"]
+        for w in cells[:len(cells) // 2 + 1]:
+            w["chips"] = 4
+    return edit
+
+
 DEVICE = {"device": "policy_server_dispatched_rows"}
 
 
@@ -183,7 +194,7 @@ DEVICE = {"device": "policy_server_dispatched_rows"}
     (_set(["per_layer", 0, "moves"], "nothing"), "is no end-to-end metric"),
     (_set(["per_layer", 0, "workloads"], ["no-such-cell"]), "no workload"),
     (_set(["workloads", 0, "config"], "no-such-config"), "no config"),
-    (_set(["workloads", 0, "chips"], 4), "ask for 4 chips"),
+    (_four_chips_over_half(), "ask for 4 chips"),
     (lambda m, _root: m["per_layer"][0].update(
         workloads=[c["name"] for c in m["workloads"]]),
      "not all of its workloads"),  # a steady-only metric, moved by all cells

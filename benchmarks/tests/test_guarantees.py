@@ -22,7 +22,7 @@ import reduce  # noqa: E402
 SOURCES = reduce.answer_sources()
 DEVICE_ALONE = {"guarantees": {"answers_from": {
     "device": "policy_server_dispatched_rows"}}}
-CACHED = json.loads((BENCH / "pending" / "flagship32-cached.json").read_text())
+CACHED = json.loads((BENCH / "configs" / "flagship32-cached.json").read_text())
 # what the parent's run.py summed under answered_off_device (46b6308..217edeb)
 PARENTS_MUST_STAY_ZERO = (
     "policy_server_host_fastpath_requests",
